@@ -62,16 +62,26 @@ def fleet_probe(quick: bool, gnn_params) -> Dict:
     method×seed grid run three ways —
 
         serial-cold  one fresh worker process per campaign, no shared
-                     caches: what the grid costs when each campaign is an
-                     independent cold job (the pre-fleet deployment shape);
+                     eval cache: what the grid costs when each campaign is
+                     an independent job (the pre-fleet deployment shape);
         fleet-cold   `workers` persistent processes sharing the on-disk
-                     eval cache and the XLA compilation cache;
+                     eval cache;
         fleet-warm   the same fleet re-run against the now-populated
                      persistent caches (fresh checkpoints, so every
                      campaign genuinely re-evaluates) — measures the
                      cross-campaign eval-cache hit-rate.
+
+    Every process keeps its XLA compiles in the persistent compilation
+    cache (`repro.compile_cache`). Fleet workers need a parent that holds
+    no accelerator: this process trains the GNN first, so on a TPU host the
+    probe is not run and says so.
     """
-    from repro.explore.fleet import FleetSpec, run_fleet
+    from repro.explore.fleet import FleetSpec, parent_platform, run_fleet
+
+    held = parent_platform()
+    if held not in (None, "cpu"):
+        return {"skipped": f"this process holds the {held} devices; fleet "
+                           "workers spawned from it could not get them"}
 
     wl = GPT_BENCHMARKS[0]
     seeds = (0,) if quick else (0, 1, 2)
@@ -110,7 +120,6 @@ def fleet_probe(quick: bool, gnn_params) -> Dict:
             name="fig8-fleet", campaigns=tuple(campaigns), workers=workers,
             host_devices=2,          # shard eval batches across 2 XLA lanes
             cache_dir=os.path.join(root, "evalcache"),
-            compile_cache_dir=os.path.join(root, "xlacache"),
             checkpoint_dir=os.path.join(root, "ck"), checkpoint_every=2)
         cold = run_fleet(fs)
         if cold.errors:
@@ -261,6 +270,9 @@ def run(quick: bool = False) -> Dict:
         print(f"eval cache [{stage}]: {sc['hits']}/{sc['hits']+sc['misses']}"
               f" hits ({100*sc['hit_rate']:.0f}%)")
     fl = out["fleet"]
+    if "skipped" in fl:
+        print(f"fleet probe not run: {fl['skipped']}")
+        return out
     print(f"fleet [{fl['workers']} workers, {fl['n_campaigns']} campaigns]: "
           f"serial-cold {fl['serial_cold_wall_s']:.0f}s -> fleet "
           f"{fl['fleet_wall_s']:.0f}s ({fl['fleet_speedup']:.1f}x, "

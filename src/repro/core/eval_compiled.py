@@ -18,7 +18,7 @@ Bit-exactness contract: every jnp expression mirrors its NumPy oracle
 `row_allgather_comm_cycles`, `row_allgather_byte_hops`,
 `feasible_strategy_arrays` — retained verbatim and re-exported as `*_ref`)
 operation for operation, in the same association order, in float64 under a
-scoped `jax.experimental.enable_x64` (the rest of the process stays f32 —
+scoped `jax.enable_x64` (the rest of the process stays f32 —
 the GP/EHVI programs are untouched). The analytical path uses only
 exactly-rounded ops (+ - * / min max and integer arithmetic; the one log2
 is the ±1-ulp-corrected exact `floor_log2`), so XLA CPU reproduces the
@@ -35,9 +35,17 @@ Static-shape conventions (the PR 6 capacity-bucket idiom):
     vmapped-searchsorted gather (identical rows, identical order, same
     Strategy(1,1,1,1) fallback as `feasible_strategy_arrays`).
 
-When `host_devices` XLA host-platform lanes are exposed
+Placement: every program here runs on the host CPU devices, whatever the
+process's default backend is (DESIGN.md §12). A TPU has no native float64,
+and the emulated-f64 program takes minutes per bucket to compile for a
+v5e; on XLA:CPU it compiles in seconds and keeps the NumPy bit-exactness.
+Inputs are committed to `host_devices()[0]`, so the jitted programs follow
+them there; the fused entry points copy the acquire scan's pick indices
+from the accelerator to the host before the gather.
+
+When several XLA host-platform lanes are exposed
 (`--xla_force_host_platform_device_count`, see explore/fleet.py), the
-padded design axis is sharded across the lanes with `pmap`; per-design
+padded design axis is sharded across them with `pmap`; per-design
 math is embarrassingly parallel, so sharding cannot change results.
 `lane_stats()` reports per-lane row counts for the fleet probe.
 
@@ -62,6 +70,12 @@ _ENV = "REPRO_COMPILED_EVAL"
 
 def enabled() -> bool:
     return os.environ.get(_ENV, "1").lower() not in ("0", "false", "off")
+
+
+def host_devices() -> list:
+    """The CPU devices the evaluator's programs run on: its lanes."""
+    import jax
+    return jax.local_devices(backend="cpu")
 
 
 def _pow2(n: int) -> int:
@@ -133,8 +147,7 @@ _PROGRAMS_MAX = 16
 
 
 def _program_for(wl: LLMWorkload, max_strategies: int) -> "_EvalProgram":
-    import jax
-    lanes = jax.local_device_count()
+    lanes = len(host_devices())
     key = (wl, max_strategies, lanes)
     prog = _PROGRAMS.get(key)
     if prog is None:
@@ -161,6 +174,8 @@ class _EvalProgram:
         self.wl = wl
         self.K = int(max_strategies)
         self.lanes = int(lanes)
+        self.devices = host_devices()[:self.lanes]
+        self.host = self.devices[0]
 
         g = _strategy_grid(wl)
         order = g["order"]
@@ -192,9 +207,12 @@ class _EvalProgram:
         self._p_exp = wl.expert_params_bytes()
         self._kvtot_num = wl.kv_bytes_per_layer() * wl.n_layers
         self._e_mac = wl.flops_per_step() / 2.0 * C.ENERGY.mac * 1e-12
+        self._zc_np = tuple(np.float64(c) for c in (
+            0.0, self._bwd, C.CLOCK_HZ, wl.d_model, 1e-12, wl.n_layers))
 
         self._jit = jax.jit(self._body)
-        self._pfn = (jax.pmap(self._body, in_axes=(0, 0, None))
+        self._pfn = (jax.pmap(self._body, in_axes=(0, 0, None),
+                              devices=self.devices)
                      if lanes > 1 else None)
 
         def fused(arrs, nw, zc, js):
@@ -207,7 +225,8 @@ class _EvalProgram:
         # no grid selection — the strategy arrays come in as inputs
         self._jit_pinned = jax.jit(self._body_pinned)
         self._pfn_pinned = (jax.pmap(self._body_pinned,
-                                     in_axes=(0, 0, None, 0))
+                                     in_axes=(0, 0, None, 0),
+                                     devices=self.devices)
                             if lanes > 1 else None)
 
         def fused_pinned(arrs, nw, zc, strat, js):
@@ -223,16 +242,12 @@ class _EvalProgram:
         algebraic simplifier folds adjacent constant factors into one —
         e.g. `/ CLOCK_HZ * bwd` into `* (bwd/CLOCK_HZ)` — which rounds
         once where the NumPy oracle rounds twice). Passing them as runtime
-        values pins the op-for-op association. Device constants: built
-        once, reused across dispatches."""
+        values pins the op-for-op association. Host-lane constants: built
+        once, reused across dispatches (the pmap paths take the NumPy
+        scalars of `_zc_np`)."""
         zc = getattr(self, "_zc_cached", None)
         if zc is None:
-            zc = self._zc_cached = (
-                _dev64(np.float64(0.0)), _dev64(np.float64(self._bwd)),
-                _dev64(np.float64(C.CLOCK_HZ)),
-                _dev64(np.float64(self.wl.d_model)),
-                _dev64(np.float64(1e-12)),
-                _dev64(np.float64(self.wl.n_layers)))
+            zc = self._zc_cached = tuple(self._put(c) for c in self._zc_np)
         return zc
 
     # -- the pipeline body (traced under enable_x64) ------------------------
@@ -597,6 +612,12 @@ class _EvalProgram:
 
     # -- host-side entry points --------------------------------------------
 
+    def _put(self, v):
+        """Commit `v` to host lane 0 in its traced dtype (call under
+        `jax.enable_x64`)."""
+        import jax
+        return jax.device_put(_np64(v), self.host)
+
     def _pad_rows(self, arrs: Dict[str, np.ndarray], nw: np.ndarray,
                   npad: int):
         n = len(nw)
@@ -616,26 +637,24 @@ class _EvalProgram:
                   ) -> Dict[str, np.ndarray]:
         """Evaluate N designs; returns winner arrays sliced back to N."""
         import jax
-        from jax.experimental import enable_x64
 
         n = len(nw)
         npad = self._bucket(n)
         arrs, nwp = self._pad_rows(arrs, nw, npad)
-        with enable_x64():
-            ja = {k: _dev64(v) for k, v in arrs.items()}
-            jn = _dev64(nwp)
-            jz = self._zc()
+        with jax.enable_x64(True):
             if self.lanes > 1 and npad % self.lanes == 0:
                 shp = (self.lanes, npad // self.lanes)
                 out = self._pfn(
-                    {k: v.reshape(shp + v.shape[1:]) for k, v in ja.items()},
-                    jn.reshape(shp), jz)
+                    {k: _np64(v).reshape(shp + v.shape[1:])
+                     for k, v in arrs.items()},
+                    _np64(nwp).reshape(shp), self._zc_np)
                 out = {k: np.asarray(v).reshape(npad) for k, v in out.items()}
                 _LANE_STATS["n_lanes"] = self.lanes
                 _LANE_STATS["sharded_calls"] += 1
                 _LANE_STATS["rows_sharded"] += npad
             else:
-                out = self._jit(ja, jn, jz)
+                out = self._jit({k: self._put(v) for k, v in arrs.items()},
+                                self._put(nwp), self._zc())
                 out = {k: np.asarray(v) for k, v in out.items()}
                 _LANE_STATS.setdefault("n_lanes", 1)
                 _LANE_STATS["n_lanes"] = max(_LANE_STATS["n_lanes"], 1)
@@ -649,15 +668,16 @@ class _EvalProgram:
         `js_dev` indices name, without waiting for the indices to reach the
         host (the acquire scan's output feeds the evaluator inside XLA).
         Returns a pending handle; extraction is one host transfer."""
-        from jax.experimental import enable_x64
+        import jax
 
         n = len(nw)
         npad = _pow2(max(n, 4))
         arrs, nwp = self._pad_rows(arrs, nw, npad)
-        with enable_x64():
-            ja = {k: _dev64(v) for k, v in arrs.items()}
-            jn = _dev64(nwp)
-            out = self._fused_jit(ja, jn, self._zc(), js_dev)
+        with jax.enable_x64(True):
+            ja = {k: self._put(v) for k, v in arrs.items()}
+            jn = self._put(nwp)
+            js = jax.device_put(js_dev, self.host)
+            out = self._fused_jit(ja, jn, self._zc(), js)
         _LANE_STATS["jit_calls"] += 1
         _LANE_STATS["rows_jit"] += int(js_dev.shape[0])
         return _PendingEval(self, out)
@@ -709,29 +729,29 @@ class _EvalProgram:
                          strat) -> Dict[str, np.ndarray]:
         """Evaluate N (design, strategy) pairs; `strat` is the
         (tp, pp, dp, mb, ep, recompute) array tuple."""
-        from jax.experimental import enable_x64
+        import jax
 
         n = len(nw)
         npad = self._bucket(n)
         arrs, nwp = self._pad_rows(arrs, nw, npad)
         strat = self._pad_strat(strat, npad)
-        with enable_x64():
-            ja = {k: _dev64(v) for k, v in arrs.items()}
-            jn = _dev64(nwp)
-            js = tuple(_dev64(s) for s in strat)
-            jz = self._zc()
+        with jax.enable_x64(True):
             if self.lanes > 1 and npad % self.lanes == 0:
                 shp = (self.lanes, npad // self.lanes)
                 out = self._pfn_pinned(
-                    {k: v.reshape(shp + v.shape[1:]) for k, v in ja.items()},
-                    jn.reshape(shp), jz,
-                    tuple(s.reshape(shp) for s in js))
+                    {k: _np64(v).reshape(shp + v.shape[1:])
+                     for k, v in arrs.items()},
+                    _np64(nwp).reshape(shp), self._zc_np,
+                    tuple(_np64(s).reshape(shp) for s in strat))
                 out = {k: np.asarray(v).reshape(npad) for k, v in out.items()}
                 _LANE_STATS["n_lanes"] = self.lanes
                 _LANE_STATS["sharded_calls"] += 1
                 _LANE_STATS["rows_sharded"] += npad
             else:
-                out = self._jit_pinned(ja, jn, jz, js)
+                out = self._jit_pinned(
+                    {k: self._put(v) for k, v in arrs.items()},
+                    self._put(nwp), self._zc(),
+                    tuple(self._put(s) for s in strat))
                 out = {k: np.asarray(v) for k, v in out.items()}
                 _LANE_STATS["jit_calls"] += 1
                 _LANE_STATS["rows_jit"] += npad
@@ -743,17 +763,18 @@ class _EvalProgram:
         """Fused gather + pinned evaluation of the joint-pool rows named by
         the device-resident `js_dev` indices (joint-mode counterpart of
         `dispatch_fused`)."""
-        from jax.experimental import enable_x64
+        import jax
 
         n = len(nw)
         npad = _pow2(max(n, 4))
         arrs, nwp = self._pad_rows(arrs, nw, npad)
         strat = self._pad_strat(strat, npad)
-        with enable_x64():
-            ja = {k: _dev64(v) for k, v in arrs.items()}
-            jn = _dev64(nwp)
-            js = tuple(_dev64(s) for s in strat)
-            out = self._fused_pinned_jit(ja, jn, self._zc(), js, js_dev)
+        with jax.enable_x64(True):
+            ja = {k: self._put(v) for k, v in arrs.items()}
+            jn = self._put(nwp)
+            js = tuple(self._put(s) for s in strat)
+            picks = jax.device_put(js_dev, self.host)
+            out = self._fused_pinned_jit(ja, jn, self._zc(), js, picks)
         _LANE_STATS["jit_calls"] += 1
         _LANE_STATS["rows_jit"] += int(js_dev.shape[0])
         return _PendingPinnedEval(self, out)
@@ -800,14 +821,14 @@ class _EvalProgram:
         return res
 
 
-def _dev64(v: np.ndarray):
-    jnp = _jnp()
+def _np64(v) -> np.ndarray:
+    """`v` as the int64 / float64 / bool array the programs trace."""
     a = np.asarray(v)
-    if a.dtype == np.bool_:
-        return jnp.asarray(a)
     if np.issubdtype(a.dtype, np.integer):
-        return jnp.asarray(a, jnp.int64)
-    return jnp.asarray(a, jnp.float64)
+        return a.astype(np.int64)
+    if a.dtype == np.bool_:
+        return a
+    return a.astype(np.float64)
 
 
 @dataclasses.dataclass
@@ -929,8 +950,6 @@ def warm_evaluator_kernels(wl: LLMWorkload, n_designs_max: int = 4,
     memoization; `force=True` re-warms). Returns buckets newly warmed."""
     if not enabled():
         return 0
-    from jax.experimental import enable_x64
-
     from repro.core.design_space import decode_batch
 
     prog = _program_for(wl, max_strategies)
@@ -961,8 +980,8 @@ def warm_evaluator_kernels(wl: LLMWorkload, n_designs_max: int = 4,
             npad, qp = b[1], b[2]
             arrs = {k: np.repeat(v, npad, axis=0) for k, v in arrs1.items()}
             nw = np.ones(npad, np.int64)
-            with enable_x64():
-                js = _jnp().arange(qp, dtype=_jnp().int64) % npad
+            # int32, the dtype of the acquire scan's pick indices
+            js = np.arange(qp, dtype=np.int32) % npad
             prog.dispatch_fused(arrs, nw, js).finish(nw, min(qp, npad))
     return warmed
 

@@ -129,14 +129,23 @@ def _fit_pair_jit(X, Y2, mask, n_real, lr, iters):
     return jax.vmap(one)(Y2)
 
 
+def _sum_rows(x):
+    """Sum over the buffer axis (axis 0) one row at a time, in index order.
+    Padded rows are zero and add exact zeros, so the result does not depend
+    on the buffer capacity; a vectorized reduction or dot associates by its
+    length, which on XLA:CPU moves the last bit between buckets."""
+    return jax.lax.fori_loop(0, x.shape[0], lambda j, acc: acc + x[j],
+                             jnp.zeros(x.shape[1:], x.dtype))
+
+
 @jax.jit
 def _predict_jit(Xs, X, mask, L, alpha, log_ls, log_sf, mean, std):
     ls = jnp.exp(log_ls)
     sf = jnp.exp(log_sf)
     Ks = _matern52(Xs, X, ls, sf) * mask[None, :]
-    mu = Ks @ alpha
+    mu = _sum_rows(Ks.T * alpha[:, None])
     v = jax.scipy.linalg.solve_triangular(L, Ks.T, lower=True)
-    var = jnp.maximum(sf - jnp.sum(v * v, axis=0), 1e-10)
+    var = jnp.maximum(sf - _sum_rows(v * v), 1e-10)
     return mu * std + mean, jnp.sqrt(var) * std
 
 

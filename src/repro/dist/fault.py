@@ -153,9 +153,11 @@ class TrainSupervisor:
                 entry = {"step": step}
                 for k, v in m.items():
                     entry[k] = float(np.asarray(v))  # blocks until step done
-            except Exception as e:
-                # real failure path (device fault, OOM, ...): same rollback
-                # as an injected one, bounded by max_restarts; repeated
+            except RuntimeError as e:
+                # real failure path (device fault, OOM, ... — JAX raises
+                # them as JaxRuntimeError); a tracing or programming error
+                # is not retried. Same rollback as an injected failure,
+                # bounded by max_restarts; repeated
                 # failure of the SAME step is deterministic, not transient —
                 # surface it instead of burning max_restarts retries
                 futile = futile + 1 if step == last_fail_step else 1

@@ -18,6 +18,7 @@ import argparse
 import sys
 from typing import List, Optional
 
+from repro.compile_cache import configure_compile_cache
 from repro.explore.campaign import Campaign, CampaignSpec
 
 
@@ -143,6 +144,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                   f"{cfg.total_evals()} evals, q={spec.q})")
         return 0
 
+    configure_compile_cache()
     if args.resume:
         if args.spec:
             ap.error("--resume continues the checkpoint's embedded spec; "

@@ -10,7 +10,7 @@ processes that share:
     - the persistent eval cache (`DiskSegmentEvalCache` on `cache_dir`,
       wired via `repro.core.evaluator.configure_eval_cache`) — concurrent
       workers and successive campaigns reuse each other's evaluations;
-    - the JAX persistent compilation cache (`compile_cache_dir`) — one
+    - the JAX persistent compilation cache (`repro.compile_cache`) — one
       worker's XLA compiles warm every later worker's cold start;
     - per-process memoized `warm_optimizer_kernels` — each worker warms
       each shape bucket at most once across all its campaigns.
@@ -23,6 +23,13 @@ checkpoint — workers always try `Campaign.resume` from the fleet's
 checkpoint directory before starting fresh. `host_devices > 1` exposes
 `--xla_force_host_platform_device_count` lanes to the workers (DESIGN.md
 §10's XLA host-lanes note).
+
+One process per accelerator host: a chip belongs to the process that first
+starts JAX's backend on it. So `run_fleet` refuses to start when its own
+process already holds a non-CPU backend, each worker reports the devices it
+got before it is sent work (a worker that cannot get one is reported, not
+respawned), and on a TPU host the fleet runs a single worker, which owns
+every local chip; asking for more is refused.
 
 CLI: ``python -m repro.explore fleet grid.json [--workers N] [--out F]``.
 """
@@ -60,7 +67,6 @@ class FleetSpec:
     campaigns: Tuple[CampaignSpec, ...]
     workers: int = 2
     cache_dir: Optional[str] = None          # shared persistent eval cache
-    compile_cache_dir: Optional[str] = None  # shared XLA compilation cache
     checkpoint_dir: Optional[str] = None     # per-campaign ckpts (resume)
     checkpoint_every: int = 2                # steps between worker ckpts
     host_devices: int = 1                    # XLA host-platform lanes
@@ -93,7 +99,7 @@ class FleetSpec:
              "warm_n_obs": self.warm_n_obs,
              "max_cache_entries": self.max_cache_entries,
              "campaigns": [c.to_dict() for c in self.campaigns]}
-        for k in ("cache_dir", "compile_cache_dir", "checkpoint_dir"):
+        for k in ("cache_dir", "checkpoint_dir"):
             if getattr(self, k) is not None:
                 d[k] = getattr(self, k)
         return d
@@ -171,11 +177,8 @@ def _worker_setup(cfg: Dict) -> None:
         want = f"--xla_force_host_platform_device_count={lanes}"
         if want not in flags:
             os.environ["XLA_FLAGS"] = (flags + " " + want).strip()
-    if cfg.get("compile_cache_dir"):
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          cfg["compile_cache_dir"])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if cfg.get("cache_dir"):
         from repro.core.evaluator import configure_eval_cache
         configure_eval_cache(cache_dir=cfg["cache_dir"],
@@ -255,10 +258,20 @@ def _run_one(cfg: Dict, spec_dict: Dict) -> Dict:
 
 
 def _fleet_worker(worker_id: int, cfg: Dict, conn) -> None:
-    """Worker loop: receive (idx, spec_dict) tasks over the pipe, run each
-    campaign (resuming its checkpoint if one exists), send (idx, result)
-    back. A `None` task shuts the worker down."""
-    _worker_setup(cfg)
+    """Worker loop: report the devices this process got ("ready",
+    platform, count) or why it got none ("no_device", error); then receive
+    (idx, spec_dict) tasks over the pipe, run each campaign (resuming its
+    checkpoint if one exists), send (idx, result) back. A `None` task shuts
+    the worker down."""
+    try:
+        _worker_setup(cfg)
+        import jax
+        devs = jax.local_devices()
+    except Exception as e:           # boundary: the scheduler reports it
+        conn.send(("no_device", f"{type(e).__name__}: {e}"))
+        conn.close()
+        return
+    conn.send(("ready", devs[0].platform, len(devs)))
     while True:
         task = conn.recv()
         if task is None:
@@ -312,6 +325,25 @@ class _Worker:
         child.close()                # parent keeps only its own end
         self.current: Optional[int] = None
 
+    def handshake(self, timeout: float = 600.0) -> str:
+        """Wait for the worker's device report; return its platform. A
+        worker that got no device, or died first, is an environment fault:
+        raise, never respawn."""
+        try:
+            if not self.conn.poll(timeout):
+                raise RuntimeError(f"fleet worker {self.id} reported no "
+                                   f"device within {timeout:.0f}s")
+            msg = self.conn.recv()
+        except (EOFError, OSError):
+            self.proc.join(timeout=5)
+            raise RuntimeError(f"fleet worker {self.id} died before "
+                               f"reporting a device (exit "
+                               f"{self.proc.exitcode})") from None
+        if msg[0] != "ready":
+            raise RuntimeError(f"fleet worker {self.id} could not get a "
+                               f"device: {msg[1]}")
+        return msg[1]
+
     def stop(self):
         try:
             if self.current is None and self.proc.is_alive():
@@ -334,15 +366,20 @@ def run_fleet(spec: FleetSpec, *, verbose: bool = False) -> FleetResult:
     import multiprocessing as mp
 
     spec.validate()
+    held = parent_platform()
+    if held not in (None, "cpu"):
+        raise RuntimeError(
+            f"run_fleet: this process already holds the {held} devices, so "
+            "its workers could not get them; call run_fleet from a process "
+            "that has not started a JAX backend")
     ctx = mp.get_context("spawn")    # fork would deadlock JAX's threadpool
     cfg = {"cache_dir": spec.cache_dir,
-           "compile_cache_dir": spec.compile_cache_dir,
            "checkpoint_dir": spec.checkpoint_dir,
            "checkpoint_every": spec.checkpoint_every,
            "warm_n_obs": spec.warm_n_obs,
            "host_devices": spec.host_devices,
            "max_cache_entries": spec.max_cache_entries}
-    for k in ("cache_dir", "compile_cache_dir", "checkpoint_dir"):
+    for k in ("cache_dir", "checkpoint_dir"):
         if cfg[k]:
             os.makedirs(cfg[k], exist_ok=True)
 
@@ -360,7 +397,18 @@ def run_fleet(spec: FleetSpec, *, verbose: bool = False) -> FleetResult:
     max_crashes = 3 * len(spec.campaigns) + n_workers
     workers: List[_Worker] = []
     try:
-        workers = [_Worker(ctx, w, cfg) for w in range(n_workers)]
+        # worker 0 reports its platform before any other worker starts:
+        # on an accelerator host it owns every local chip
+        workers = [_Worker(ctx, 0, cfg)]
+        platform = workers[0].handshake()
+        if platform != "cpu" and n_workers > 1:
+            raise RuntimeError(
+                f"fleet {spec.name!r} asks for {n_workers} workers on a "
+                f"{platform} host, where one process owns every local chip; "
+                "run it with workers=1")
+        workers += [_Worker(ctx, w, cfg) for w in range(1, n_workers)]
+        for w in workers[1:]:
+            w.handshake()
         while len(results) < len(spec.campaigns):
             for w in workers:
                 if w.current is None and pending:
@@ -418,6 +466,7 @@ def run_fleet(spec: FleetSpec, *, verbose: bool = False) -> FleetResult:
                     w.proc.join(timeout=5)     # reap the zombie
                     w.conn.close()
                     workers[i] = _Worker(ctx, w.id, cfg)
+                    workers[i].handshake()
                     progressed = True
             if not progressed:
                 time.sleep(0.01)
@@ -434,5 +483,18 @@ def run_fleet(spec: FleetSpec, *, verbose: bool = False) -> FleetResult:
         crashes=crashes, errors=errors)
 
 
+def parent_platform() -> Optional[str]:
+    """The platform of the JAX backend this process has started, or None
+    when it has started none (checked without starting one)."""
+    import sys
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+    return jax.default_backend()
+
+
 __all__ = ["FLEET_SPEC_VERSION", "FleetResult", "FleetSpec", "expand_grid",
-           "run_fleet"]
+           "parent_platform", "run_fleet"]

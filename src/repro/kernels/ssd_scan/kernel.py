@@ -39,14 +39,17 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
     cmat = c_ref[0].astype(jnp.float32)          # (Q, N)
 
     da = dt * a[0, 0]                            # (Q, 1) log-decay
-    cum = jnp.cumsum(da, axis=0)                 # (Q, 1) inclusive L_t
+    ti = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    tri = si <= ti
+    # inclusive L_t = sum_{s<=t} da_s as a masked row sum: the TPU lowering
+    # has no cumsum, and an MXU product would round the f32 decays
+    cum = jnp.sum(jnp.where(tri, da.reshape(1, Q), 0.0), axis=1,
+                  keepdims=True)                 # (Q, 1)
 
     # intra-chunk quadratic form: m[t,s] = (C_t.B_s) exp(L_t - L_s) dt_s, s<=t
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())))  # (Q, Q)
     seg = cum - cum.reshape(1, Q)                # L_t - L_s
-    ti = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    tri = si <= ti
     m = jnp.where(tri, cb * jnp.exp(seg) * dt.reshape(1, Q), 0.0)
     y_intra = jax.lax.dot_general(m, x, (((1,), (0,)), ((), ())))   # (Q, P)
 

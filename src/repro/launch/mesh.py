@@ -9,13 +9,16 @@ from __future__ import annotations
 import math
 from typing import Optional, Sequence, Tuple
 
-import numpy as np
-
 
 def make_mesh_shape(shape: Tuple[int, ...], axes: Tuple[str, ...],
                     devices: Optional[Sequence] = None):
+    """Mesh over the first prod(shape) devices. Every axis is `Auto`: the
+    sharding rules in `dist.sharding` place params and batches through
+    jit in/out shardings and GSPMD propagates the rest (an `Explicit` axis,
+    `jax.make_mesh`'s default, would demand an out-sharding on every gather
+    of a sharded table, e.g. the embedding lookup)."""
     import jax
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType
 
     n = math.prod(shape)
     devs = list(devices) if devices is not None else list(jax.devices())
@@ -24,11 +27,8 @@ def make_mesh_shape(shape: Tuple[int, ...], axes: Tuple[str, ...],
             f"mesh {shape} needs {n} devices, have {len(devs)} — the dry-run "
             "sets XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:n])
-    except TypeError:
-        arr = np.array(devs[:n]).reshape(shape)
-        return Mesh(arr, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False):

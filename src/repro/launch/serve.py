@@ -1,6 +1,7 @@
 """Serving launcher: loads (or initializes) a model, spins up the
 continuous-batching engine, runs a batch of synthetic requests and reports
-throughput/latency stats.
+throughput/latency stats. On a TPU, prefill attention runs on the Pallas
+flash kernel (SSD layers on the SSD kernel).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
         --requests 8 --slots 4
@@ -8,11 +9,13 @@ throughput/latency stats.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.models import model as M
 from repro.models.runtime import CPU_TEST, Runtime
@@ -32,13 +35,15 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if cfg.family in ("encdec", "vlm"):
         raise SystemExit("engine serves decoder-only families; use "
                          "serve_step.make_prefill_step/make_decode_step "
                          "directly for encdec/vlm")
-    rt = CPU_TEST if args.reduced else Runtime()
+    rt = dataclasses.replace(CPU_TEST if args.reduced else Runtime(),
+                             use_pallas=jax.default_backend() == "tpu")
     if args.ckpt_dir:
         restored = ckpt.restore_latest(args.ckpt_dir)
         if restored is None:

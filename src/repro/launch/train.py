@@ -2,6 +2,12 @@
 supervisor loop. On real TPU pods, run one process per host; on CPU this
 drives the same code path with a 1-device mesh.
 
+On a TPU the step computes in bfloat16 and attention runs on the Pallas
+flash kernel (SSD layers on the SSD kernel); elsewhere it computes in
+float32 on the jnp reference paths. The step is compiled before the
+supervisor loop starts, so a compile error fails the launch at once instead
+of entering the rollback-and-retry path.
+
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --steps 300 --batch 8 --seq 256 --ckpt-dir /tmp/ckpt
 """
@@ -14,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.dist import sharding as sh
 from repro.dist.fault import TrainSupervisor
@@ -45,6 +52,7 @@ def main(argv=None):
                     help="comma-separated step indices at which to inject a "
                          "node failure (fault-tolerance demo/smoke test)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     try:
         fail_at = {int(s) for s in args.fail_at.split(",") if s.strip()}
     except ValueError:
@@ -64,8 +72,9 @@ def main(argv=None):
         raise SystemExit("multi-process launch is not supported yet: run "
                          "one process with all local devices")
     mesh = make_mesh_shape((args.data, args.model), ("data", "model"))
-    rt = Runtime(compute_dtype=jnp.float32 if args.model * args.data == 1
-                 else jnp.bfloat16,
+    on_tpu = jax.default_backend() == "tpu"
+    rt = Runtime(compute_dtype=jnp.bfloat16 if on_tpu else jnp.float32,
+                 use_pallas=on_tpu,
                  remat="none" if args.reduced else "block",
                  mesh_axes={a: int(mesh.shape[a]) for a in mesh.axis_names}
                  if args.data * args.model > 1 else None)
@@ -79,33 +88,41 @@ def main(argv=None):
 
     rng = jax.random.PRNGKey(0)
 
-    def init_fn():
+    def init_state():
         params = M.init_params(rng, cfg)
         return params, init_opt_state(params)
 
     step_raw = make_train_step(cfg, rt, opt, microbatches=args.microbatches)
-    with mesh:
-        params_sds = jax.eval_shape(lambda k: M.init_params(k, cfg), rng)
-        p_spec = sh.param_specs(mesh, params_sds)
+    with jax.set_mesh(mesh):
+        state_sds = jax.eval_shape(init_state)
+        p_spec = sh.param_specs(mesh, state_sds[0])
         p_shard = sh.to_shardings(mesh, p_spec)
         o_shard = sh.to_shardings(
             mesh, sh.opt_state_specs(mesh, None, p_spec))
         batch_sds = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), ds.batch_at(0))
         b_shard = sh.to_shardings(mesh, sh.batch_specs(mesh, batch_sds))
+        # state is created directly in its sharded layout, never whole on
+        # one device
+        init_fn = jax.jit(init_state, out_shardings=(p_shard, o_shard))
         # out_shardings must pin params/opt to the same layout as the inputs:
         # the step's outputs are fed straight back in (donated), and GSPMD
         # would otherwise pick its own output layout and reject the next call
+        t0 = time.time()
         step_fn = jax.jit(step_raw, in_shardings=(p_shard, o_shard, b_shard),
                           out_shardings=(p_shard, o_shard, None),
-                          donate_argnums=(0, 1))
+                          donate_argnums=(0, 1)
+                          ).lower(*state_sds, batch_sds).compile()
+        print(f"[train] set-up: step compiled in {time.time() - t0:.1f}s "
+              f"on {jax.devices()[0].device_kind} (use_pallas="
+              f"{rt.use_pallas}, compute {jnp.dtype(rt.compute_dtype).name})",
+              flush=True)
 
         t_start = time.time()
         last = {"t": t_start, "step": 0, "seen": 0}
 
         def batches(step):
-            b = ds.batch_at(step)
-            return {k: jnp.asarray(v) for k, v in b.items()}
+            return jax.device_put(ds.batch_at(step), b_shard)
 
         def step_logged(params, opt_state, batch):
             t_before = time.time()
@@ -149,6 +166,7 @@ def main(argv=None):
              else "already complete (resumed at final checkpoint)")
     print(f"[train] done in {time.time()-t_start:.0f}s; {final}; "
           f"restarts {out['restarts']}; slow steps {out['slow_steps']}")
+    out["runtime"] = rt
     return out
 
 

@@ -17,7 +17,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import attention_ref, make_mask
 from repro.models.layers import dense_init, rope
-from repro.models.runtime import Runtime
+from repro.models.runtime import Runtime, kernel_shard_axes
 
 
 # ---------------------------------------------------------------------------
@@ -71,6 +71,24 @@ def _constrain_attn(x: jnp.ndarray, rt: Runtime, is_query: bool
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def _flash(q, k, v, cfg: ModelConfig, rt: Runtime, *, causal: bool,
+           window: Optional[int]) -> jnp.ndarray:
+    """Aligned self-attention on the Pallas flash kernel. Under a mesh the
+    kernel runs per shard (`kernel_shard_axes`): batch over the data axes,
+    heads over `model` when both the q and the kv head counts divide it."""
+    def attend(q, k, v):
+        return fa_ops.mha(q, k, v, None, None, causal=causal, window=window,
+                          use_pallas=True, interpret=rt.interpret)
+
+    if rt.mesh_axes is None:
+        return attend(q, k, v)
+    from jax.sharding import PartitionSpec as P
+    dp, head = kernel_shard_axes(rt, q.shape[0], (cfg.n_heads, cfg.n_kv))
+    spec = P(dp, None, head, None)
+    return jax.shard_map(attend, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def _proj_qkv(h, p, cfg: ModelConfig, rt: Runtime):
     B, S, _ = h.shape
     hd = cfg.hd()
@@ -109,10 +127,11 @@ def self_attention(
     if prefix_len > 0:
         # prefix-LM mask needs the general masked path
         out = _prefix_lm_attention(q, k, v, positions, prefix_len)
+    elif rt.use_pallas:
+        out = _flash(q, k, v, cfg, rt, causal=causal, window=window)
     else:
         out = fa_ops.mha(q, k, v, positions, positions, causal=causal,
-                         window=window, use_pallas=rt.use_pallas,
-                         interpret=rt.interpret)
+                         window=window)
     B, S = h.shape[:2]
     out = out.reshape(B, S, cfg.n_heads * cfg.hd())
     return out @ p["wo"].astype(rt.compute_dtype)
@@ -273,9 +292,13 @@ def cached_attention(
     *,
     window: Optional[int] = None,
     prefix_len: int = 0,
+    fresh: bool = False,
 ) -> Tuple[jnp.ndarray, dict]:
     """Decode/chunked-prefill attention against a (possibly ring) cache.
-    `pos` may be a scalar or a per-slot (B,) vector."""
+    `pos` may be a scalar or a per-slot (B,) vector. `fresh` marks a
+    prefill from position 0 into an empty cache: the new tokens attend only
+    to each other, so with `rt.use_pallas` it runs as aligned
+    self-attention on the flash kernel."""
     B, S_new, _ = x.shape
     q, k, v = _proj_qkv(x, p, cfg, rt)
     pv = _pos_vector(pos, B)
@@ -295,7 +318,9 @@ def cached_attention(
         for a in ("pod", "data"):
             dp_size *= rt.mesh_axes.get(a, 1)
         seq_sharded = B % dp_size != 0 and W >= 65536
-    if (S_new == 1 and rt.mesh_axes is not None and rt.opt_cache_dus
+    if fresh and rt.use_pallas and prefix_len == 0:
+        out = _flash(q, k, v, cfg, rt, causal=True, window=window)
+    elif (S_new == 1 and rt.mesh_axes is not None and rt.opt_cache_dus
             and seq_sharded):
         out = _long_decode_attention(
             q, cache_l["k"], cache_l["v"], positions, cache_l["kv_pos"],

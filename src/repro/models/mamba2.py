@@ -12,7 +12,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.models.layers import causal_depthwise_conv, dense_init, gated_rmsnorm, rmsnorm
-from repro.models.runtime import Runtime
+from repro.models.runtime import Runtime, kernel_shard_axes
 
 
 def _dims(cfg: ModelConfig):
@@ -75,6 +75,25 @@ def _constrain_heads(xh: jnp.ndarray, rt: Runtime) -> jnp.ndarray:
     return jax.lax.with_sharding_constraint(xh, spec)
 
 
+def _ssd(xh, dtv, A, Bm, Cm, D, rt: Runtime):
+    """The chunked SSD scan. With `rt.use_pallas` under a mesh the kernel
+    runs per shard (`kernel_shard_axes`): batch over the data axes, heads
+    over `model` when they divide it."""
+    def scan(xh, dtv, A, Bm, Cm, D):
+        return ssd_ops.ssd(xh, dtv, A, Bm, Cm, D, chunk=rt.ssd_chunk,
+                           use_pallas=rt.use_pallas, interpret=rt.interpret)
+
+    if not rt.use_pallas or rt.mesh_axes is None:
+        return scan(xh, dtv, A, Bm, Cm, D)
+    from jax.sharding import PartitionSpec as P_
+    dp, h = kernel_shard_axes(rt, xh.shape[0], (xh.shape[2],))
+    in_specs = (P_(dp, None, h, None), P_(dp, None, h), P_(h),
+                P_(dp, None, None), P_(dp, None, None), P_(h))
+    out_specs = (P_(dp, None, h, None), P_(dp, h, None, None))
+    return jax.shard_map(scan, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)(xh, dtv, A, Bm, Cm, D)
+
+
 def ssm_block(x: jnp.ndarray, p: dict, cfg: ModelConfig, rt: Runtime
               ) -> jnp.ndarray:
     """Full-sequence forward. x (B, S, D) -> (B, S, D) residual added."""
@@ -93,8 +112,7 @@ def ssm_block(x: jnp.ndarray, p: dict, cfg: ModelConfig, rt: Runtime
     dtv = jax.nn.softplus(dt.astype(jnp.float32)
                           + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, _ = ssd_ops.ssd(xh, dtv, A, Bm, Cm, p["D"], chunk=rt.ssd_chunk,
-                       use_pallas=rt.use_pallas, interpret=rt.interpret)
+    y, _ = _ssd(xh, dtv, A, Bm, Cm, p["D"], rt)
     y = y.reshape(B, S, di)
     y = gated_rmsnorm(y, z, p["norm"], cfg.norm_eps)
     return x + y @ p["out_proj"].astype(rt.compute_dtype)
@@ -124,8 +142,7 @@ def ssm_block_prefill(x: jnp.ndarray, p: dict, cfg: ModelConfig, rt: Runtime,
     dtv = jax.nn.softplus(dt.astype(jnp.float32)
                           + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, hT = ssd_ops.ssd(xh, dtv, A, Bm, Cm, p["D"], chunk=rt.ssd_chunk,
-                        use_pallas=rt.use_pallas, interpret=rt.interpret)
+    y, hT = _ssd(xh, dtv, A, Bm, Cm, p["D"], rt)
     y = y.reshape(B, S, di)
     y = gated_rmsnorm(y, z, p["norm"], cfg.norm_eps)
     out = x + y @ p["out_proj"].astype(rt.compute_dtype)

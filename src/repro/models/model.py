@@ -179,7 +179,8 @@ def prefill(params, cfg: ModelConfig, rt: Runtime, batch: Dict, cache: Dict
         tokens = batch["tokens"]
         x = _embed(params, tokens, rt)
         x, attn_cache = decoder_stack_decode(
-            x, params["layers"], cfg, rt, cache["attn"], pos0, cfg.num_layers)
+            x, params["layers"], cfg, rt, cache["attn"], pos0, cfg.num_layers,
+            fresh=True)
         cache = {"attn": attn_cache}
     elif cfg.family == "vlm":
         tokens = batch["tokens"]
@@ -187,7 +188,7 @@ def prefill(params, cfg: ModelConfig, rt: Runtime, batch: Dict, cache: Dict
         x = jnp.concatenate([patches, _embed(params, tokens, rt)], axis=1)
         x, attn_cache = decoder_stack_decode(
             x, params["layers"], cfg, rt, cache["attn"], pos0,
-            cfg.num_layers, prefix_len=cfg.prefix_len)
+            cfg.num_layers, prefix_len=cfg.prefix_len, fresh=True)
         cache = {"attn": attn_cache}
     elif cfg.family == "ssm":
         tokens = batch["tokens"]

@@ -2,7 +2,8 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+import math
+from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -41,3 +42,19 @@ class Runtime:
 
 
 CPU_TEST = Runtime(compute_dtype=jnp.float32, remat="none")
+
+
+def kernel_shard_axes(rt: Runtime, batch: int, heads: Tuple[int, ...]
+                      ) -> Tuple[Optional[Tuple[str, ...]], Optional[str]]:
+    """Mesh axes a Pallas kernel call is split over under `shard_map`
+    (GSPMD cannot partition a Mosaic kernel): the batch over the data axes
+    when it divides them, and the heads over `model` when every head count
+    in `heads` divides it; otherwise that dim is replicated."""
+    axes = rt.mesh_axes
+    dp = tuple(a for a in ("pod", "data") if axes.get(a, 1) > 1)
+    dp_size = math.prod(axes[a] for a in dp)
+    model = axes.get("model", 1)
+    batch_axes = dp if dp and batch % dp_size == 0 else None
+    head_axis = ("model" if model > 1 and all(h % model == 0 for h in heads)
+                 else None)
+    return batch_axes, head_axis

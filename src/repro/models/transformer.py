@@ -125,22 +125,18 @@ def init_decoder_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def decoder_block_decode(x, p_l, cfg: ModelConfig, rt: Runtime, cache_l,
-                         pos, flag, prefix_len: int = 0
+                         pos, flag, prefix_len: int = 0, fresh: bool = False
                          ) -> Tuple[jnp.ndarray, dict, jnp.ndarray]:
     h = rmsnorm(x, p_l["ln1"], cfg.norm_eps)
+    attn = functools.partial(cached_attention, p=p_l["attn"], cfg=cfg, rt=rt,
+                             pos=pos, prefix_len=prefix_len, fresh=fresh)
     if flag is None:
-        a_out, cache_l = cached_attention(h, p_l["attn"], cfg, rt, cache_l,
-                                          pos, window=cfg.sliding_window,
-                                          prefix_len=prefix_len)
+        a_out, cache_l = attn(h, cache_l=cache_l, window=cfg.sliding_window)
     else:
         a_out, cache_l = jax.lax.cond(
             flag,
-            lambda hh, cc: cached_attention(hh, p_l["attn"], cfg, rt, cc, pos,
-                                            window=None,
-                                            prefix_len=prefix_len),
-            lambda hh, cc: cached_attention(hh, p_l["attn"], cfg, rt, cc, pos,
-                                            window=cfg.sliding_window,
-                                            prefix_len=prefix_len),
+            lambda hh, cc: attn(hh, cache_l=cc, window=None),
+            lambda hh, cc: attn(hh, cache_l=cc, window=cfg.sliding_window),
             h, cache_l)
     x = x + a_out
     h = rmsnorm(x, p_l["ln2"], cfg.norm_eps)
@@ -153,7 +149,10 @@ def decoder_block_decode(x, p_l, cfg: ModelConfig, rt: Runtime, cache_l,
 
 def decoder_stack_decode(x, layers: dict, cfg: ModelConfig, rt: Runtime,
                          cache: dict, pos, n_layers: int,
-                         prefix_len: int = 0) -> Tuple[jnp.ndarray, dict]:
+                         prefix_len: int = 0, fresh: bool = False
+                         ) -> Tuple[jnp.ndarray, dict]:
+    """Scan the stack over per-layer caches; `fresh` is a prefill from
+    position 0 into an empty cache (see `cached_attention`)."""
     flags = global_flags(cfg, n_layers)
 
     def body(xc, inp):
@@ -163,7 +162,7 @@ def decoder_stack_decode(x, layers: dict, cfg: ModelConfig, rt: Runtime,
         else:
             p_l, cache_l, flag = inp
         xc, cache_l, _ = decoder_block_decode(xc, p_l, cfg, rt, cache_l, pos,
-                                              flag, prefix_len)
+                                              flag, prefix_len, fresh)
         return xc, cache_l
 
     xs = (layers, cache) if flags is None else (layers, cache, flags)

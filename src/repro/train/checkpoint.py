@@ -102,11 +102,12 @@ def to_device(tree, like=None, sharding_tree=None):
     """numpy tree -> jnp tree (optionally matching dtypes of `like` and
     shardings of `sharding_tree` for resharded/elastic restore)."""
     def put(path_val, like_val=None, shard=None):
-        arr = jnp.asarray(path_val,
-                          dtype=None if like_val is None else like_val.dtype)
+        dtype = None if like_val is None else like_val.dtype
         if shard is not None:
-            arr = jax.device_put(arr, shard)
-        return arr
+            # straight from host memory into the shards, never whole on the
+            # default device first
+            return jax.device_put(np.asarray(path_val, dtype=dtype), shard)
+        return jnp.asarray(path_val, dtype=dtype)
     if like is None and sharding_tree is None:
         return jax.tree.map(put, tree)
     if sharding_tree is None:

@@ -136,6 +136,14 @@ def test_warm_no_retrace_within_bucket():
         designs, geom, nw = _designs(n, n)
         eval_compiled.evaluate_batch_compiled(geom, wl, nw)
     assert prog._jit._cache_size() == before, "retrace inside warmed bucket"
+    # the fused program was warmed with the acquire scan's own index dtype
+    import jax.numpy as jnp
+
+    fused_before = prog._fused_jit._cache_size()
+    designs, geom, nw = _designs(16, 16)
+    eval_compiled.dispatch_fused_eval(
+        geom, wl, nw, jnp.arange(4, dtype=jnp.int32), 24).finish(nw, 2)
+    assert prog._fused_jit._cache_size() == fused_before, "fused retrace"
 
 
 def test_fused_dispatch_matches_batch_path():

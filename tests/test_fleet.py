@@ -9,6 +9,7 @@ import glob
 import os
 import pickle
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -375,3 +376,52 @@ def test_fleet_killed_worker_resumes_to_identical_front(tmp_path):
     assert c["n_evals"] == ref.n_evals
     assert [f["throughput"] for f in c["front"]] == [
         f["throughput"] for f in ref.front]
+
+
+def test_fleet_refuses_a_parent_holding_an_accelerator(monkeypatch):
+    """A parent that already started a TPU backend holds the chip its
+    workers would need: run_fleet refuses before spawning anything."""
+    import jax
+
+    import repro.explore.fleet as F
+
+    jax.devices()                            # this process runs on the CPU
+    assert F.parent_platform() == "cpu"
+    monkeypatch.setattr(F, "parent_platform", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="already holds the tpu"):
+        run_fleet(FleetSpec(name="t-held", campaigns=(quick_spec(),),
+                            workers=1))
+
+
+def test_fleet_worker_without_a_device_is_reported_not_respawned(
+        monkeypatch):
+    """A worker whose JAX backend cannot start reports it and the fleet
+    stops with that error at once, instead of respawning the worker until
+    the crash budget runs out."""
+    monkeypatch.setenv("JAX_PLATFORMS", "no-such-platform")
+    with pytest.raises(RuntimeError, match="worker 0 could not get a "
+                                           "device"):
+        run_fleet(FleetSpec(name="t-nodev", campaigns=(quick_spec(),),
+                            workers=2))
+
+
+def test_compile_cache_follows_the_environment(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper sets no directory of
+    its own; without it, the cache sits at the fixed path in the checkout."""
+    import jax
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(compile_cache.ENV)
+    try:
+        assert compile_cache.configure_compile_cache() == str(
+            compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(
+            compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent == Path(__file__).parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -118,3 +118,35 @@ def test_ring_cache_matches_full_cache(arch):
         # gemma3 keeps full-length caches in baseline mode for its global
         # layers; ring mode only legal when pattern is uniform — shapes only
         assert ring.shape == full.shape
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "mamba2-370m"])
+def test_pallas_runtime_matches_reference(arch):
+    """`Runtime(use_pallas=True)` (kernels in interpret mode here) gives the
+    reference path's loss and gradients, and — for attention archs — its
+    prefill logits, where the fresh-cache prefill runs the flash kernel."""
+    import dataclasses
+
+    cfg = reduced_config(arch)
+    rng = jax.random.PRNGKey(3)
+    params = M.init_params(rng, cfg)
+    batch = _batch(cfg, rng, B=2, S=32)
+    rt_k = dataclasses.replace(RT, use_pallas=True, interpret=True)
+
+    def loss(rt):
+        return jax.value_and_grad(
+            lambda p: M.loss_fn(p, cfg, rt, batch)[0])(params)
+
+    (l_ref, g_ref), (l_k, g_k) = loss(RT), loss(rt_k)
+    np.testing.assert_allclose(float(l_k), float(l_ref), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_k), jax.tree.leaves(g_ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+    def prefill(rt):
+        cache = M.init_cache(cfg, rt, 2, 48)
+        return M.prefill(params, cfg, rt, {"tokens": batch["tokens"]},
+                         cache)[0]
+
+    np.testing.assert_allclose(np.asarray(prefill(rt_k)),
+                               np.asarray(prefill(RT)), rtol=1e-4, atol=1e-4)
