@@ -26,11 +26,11 @@ its own cache namespace automatically.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.compiler import compile_chunk
 from repro.core.design_space import WSCDesign
 from repro.core.noc_gnn import LinkGraph, TrainHistory, featurize_transfer, train_gnn
@@ -137,16 +137,19 @@ class GNNCalibrator:
 
     def on_handover(self, designs: Sequence[WSCDesign],
                     ys: Sequence[Tuple[float, float]]) -> None:
-        picked = pareto_neighborhood(designs, ys, self.n_designs)
-        if not picked:
-            return
-        dataset = build_calibration_set(picked, self.wl)
-        if not dataset:
-            return
-        t0 = time.time()
-        self.params, hist = train_gnn(
-            self.params, dataset, epochs=self.epochs, lr=self.lr,
-            seed=self.seed, val_frac=self.val_frac, patience=self.patience)
-        self.records.append(CalibrationRecord(
-            n_designs=len(picked), n_graphs=len(dataset),
-            train_s=time.time() - t0, history=hist))
+        with tm.span("calibrate"):
+            picked = pareto_neighborhood(designs, ys, self.n_designs)
+            if not picked:
+                return
+            with tm.span("calibrate.label", items=len(picked)):
+                dataset = build_calibration_set(picked, self.wl)
+            if not dataset:
+                return
+            with tm.span("calibrate.train", items=len(dataset)) as sp:
+                self.params, hist = train_gnn(
+                    self.params, dataset, epochs=self.epochs, lr=self.lr,
+                    seed=self.seed, val_frac=self.val_frac,
+                    patience=self.patience)
+            self.records.append(CalibrationRecord(
+                n_designs=len(picked), n_graphs=len(dataset),
+                train_s=sp.seconds, history=hist))

@@ -9,9 +9,9 @@ compiled MFMOBO proposal program and every evaluation. This module ports
 that whole pipeline to jitted JAX with static shapes so analytical
 `FidelityBackend.evaluate_batch` is ONE compiled program per
 (workload, max_strategies) — and exposes a fused gather+evaluate entry
-point that consumes the device-resident candidate indices
-`mfmobo._acquire_scan_jit` produces, so a synchronous MFMOBO f1 iteration
-never leaves XLA between proposal and evaluation.
+point that consumes the candidate indices `mfmobo._acquire_scan_jit`
+produces, so a synchronous MFMOBO f1 iteration evaluates its picks in one
+dispatch.
 
 Bit-exactness contract: every jnp expression mirrors its NumPy oracle
 (`evaluate_tile_batch`, `evaluate_step_batch`,
@@ -664,10 +664,9 @@ class _EvalProgram:
 
     def dispatch_fused(self, arrs: Dict[str, np.ndarray], nw: np.ndarray,
                        js_dev) -> "_PendingEval":
-        """Gather + evaluate the candidate-pool rows the device-resident
-        `js_dev` indices name, without waiting for the indices to reach the
-        host (the acquire scan's output feeds the evaluator inside XLA).
-        Returns a pending handle; extraction is one host transfer."""
+        """Gather + evaluate the candidate-pool rows the pick indices
+        `js_dev` name, in one program on host lane 0. Returns a pending
+        handle; extraction is one host transfer."""
         import jax
 
         n = len(nw)
@@ -761,7 +760,7 @@ class _EvalProgram:
                               nw: np.ndarray, strat, js_dev
                               ) -> "_PendingPinnedEval":
         """Fused gather + pinned evaluation of the joint-pool rows named by
-        the device-resident `js_dev` indices (joint-mode counterpart of
+        the pick indices `js_dev` (joint-mode counterpart of
         `dispatch_fused`)."""
         import jax
 
@@ -914,8 +913,8 @@ def dispatch_fused_eval_pinned(pool_geom: DesignBatch, wl: LLMWorkload,
                                max_strategies: int = 24
                                ) -> _PendingPinnedEval:
     """Joint-mode fused propose→evaluate: gather the pool rows named by
-    the device-resident `js_dev` indices together with their pinned
-    strategy columns, evaluate without a host round-trip."""
+    the pick indices `js_dev` together with their pinned strategy
+    columns, and evaluate them in one program."""
     prog = _program_for(wl, max_strategies)
     return prog.dispatch_fused_pinned(geom_arrays(pool_geom),
                                       np.asarray(nw_pool, np.int64),
@@ -926,8 +925,8 @@ def dispatch_fused_eval(pool_geom: DesignBatch, wl: LLMWorkload,
                         nw_pool: np.ndarray, js_dev,
                         max_strategies: int = 24) -> _PendingEval:
     """Fused propose→evaluate: evaluate the pool rows selected by the
-    device-resident indices `js_dev` (the `_acquire_scan_jit` output)
-    without a host round-trip between acquisition and evaluation."""
+    pick indices `js_dev` (the `_acquire_scan_jit` output) in one
+    program."""
     prog = _program_for(wl, max_strategies)
     return prog.dispatch_fused(geom_arrays(pool_geom),
                                np.asarray(nw_pool, np.int64), js_dev)

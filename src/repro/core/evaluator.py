@@ -32,6 +32,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core import components as C
 from repro.core.evalcache import (
     DiskSegmentEvalCache,
@@ -279,26 +280,34 @@ def evaluate_design_batch(designs: Sequence[WSCDesign], wl: LLMWorkload,
     designs = list(designs)
     if not designs:
         return []
+    layer = "evaluate." + backend.name
 
-    geom0 = DesignBatch.from_designs(designs)
-    if n_wafers is None:
-        nw = _wafers_for_budget_batch(geom0, wl)
-    else:
-        nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
-                             (len(designs),)).copy()
+    with tm.span(layer + ".prepare", items=len(designs)):
+        geom0 = DesignBatch.from_designs(designs)
+        if n_wafers is None:
+            nw = _wafers_for_budget_batch(geom0, wl)
+        else:
+            nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
+                                 (len(designs),)).copy()
 
-    keys = [_cache_key(d, wl, backend.name, int(nw[i]), max_strategies,
-                       gnn_params)
-            for i, d in enumerate(designs)]
-    results: List[Optional[EvalResult]] = [_BACKEND.get(k) for k in keys]
-    todo = [i for i, r in enumerate(results) if r is None]
+    with tm.span(layer + ".cache", items=len(designs)):
+        keys = [_cache_key(d, wl, backend.name, int(nw[i]), max_strategies,
+                           gnn_params)
+                for i, d in enumerate(designs)]
+        results: List[Optional[EvalResult]] = [_BACKEND.get(k)
+                                               for k in keys]
+        todo = [i for i, r in enumerate(results) if r is None]
     if todo:
-        fresh = backend.evaluate_batch(geom0.take(np.asarray(todo)), wl,
-                                       nw[todo], max_strategies, gnn_params)
-        for i, r in zip(todo, fresh):
-            results[i] = r
-        # one batched cache write (single segment append on disk backends)
-        _BACKEND.set_many([(keys[i], results[i]) for i in todo])
+        with tm.span(layer + ".run", items=len(todo)):
+            fresh = backend.evaluate_batch(geom0.take(np.asarray(todo)), wl,
+                                           nw[todo], max_strategies,
+                                           gnn_params)
+        with tm.span(layer + ".cache"):
+            for i, r in zip(todo, fresh):
+                results[i] = r
+            # one batched cache write (single segment append on disk
+            # backends)
+            _BACKEND.set_many([(keys[i], results[i]) for i in todo])
     return results            # type: ignore[return-value]
 
 
@@ -311,10 +320,11 @@ def evaluate_pool_fused(pool_designs: Sequence[WSCDesign], wl: LLMWorkload,
     """Fused propose→evaluate for the analytical fidelity (DESIGN.md §12):
     `js_dev` is the device-resident padded index vector the compiled
     q-EHVI scan produced (`mfmobo._acquire_batch_device`); the compiled
-    evaluator gathers those candidate-pool rows and scores them inside the
-    same XLA dispatch chain, so the host never synchronizes between
-    proposal and evaluation. Returns (first q_eff pick indices, their
-    EvalResults).
+    evaluator gathers those candidate-pool rows and scores them in one
+    dispatch. The evaluator runs on the host's CPU devices (DESIGN.md
+    §12), so on an accelerator host the picks reach it through the host:
+    the one read of the iteration (`sync.picks`). Returns (first q_eff
+    pick indices, their EvalResults).
 
     Cache protocol (same counters as `evaluate_design_batch`): one `get`
     per pick — hits keep the cached result, misses take the fused
@@ -327,21 +337,29 @@ def evaluate_pool_fused(pool_designs: Sequence[WSCDesign], wl: LLMWorkload,
     from repro.core import eval_compiled
 
     pool = list(pool_designs)
-    geom = DesignBatch.from_designs(pool)
-    if n_wafers is None:
-        nw = _wafers_for_budget_batch(geom, wl)
-    else:
-        nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
-                             (len(pool),)).copy()
-    pending = eval_compiled.dispatch_fused_eval(
-        geom, wl, nw, js_dev, max_strategies=max_strategies)
-    # one host sync for the indices — the fused evaluation is already
-    # enqueued behind the acquire scan by the time this completes
-    js_all = np.asarray(js_dev)
-    js = [int(j) for j in js_all[:q_eff]]
-    fresh = pending.finish(nw[js_all], q_eff)
-    keys = [_cache_key(pool[j], wl, "analytical", int(nw[j]),
-                       max_strategies, gnn_params) for j in js]
+    with tm.span("evaluate.analytical.prepare", items=len(pool)):
+        geom = DesignBatch.from_designs(pool)
+        if n_wafers is None:
+            nw = _wafers_for_budget_batch(geom, wl)
+        else:
+            nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
+                                 (len(pool),)).copy()
+    with tm.span("evaluate.analytical.run", items=q_eff):
+        js_all = tm.to_host(js_dev, "picks")
+        pending = eval_compiled.dispatch_fused_eval(
+            geom, wl, nw, js_all, max_strategies=max_strategies)
+        js = [int(j) for j in js_all[:q_eff]]
+        fresh = pending.finish(nw[js_all], q_eff)
+    with tm.span("evaluate.analytical.cache", items=q_eff):
+        results = _cache_fused(
+            [_cache_key(pool[j], wl, "analytical", int(nw[j]),
+                        max_strategies, gnn_params) for j in js], fresh)
+    return js, results
+
+
+def _cache_fused(keys, fresh) -> List[EvalResult]:
+    """The fused paths' cache protocol: one `get` per pick (a hit keeps
+    the cached result), then one batched `set_many` for the misses."""
     results: List[EvalResult] = []
     new = []
     for k, r in zip(keys, fresh):
@@ -353,7 +371,7 @@ def evaluate_pool_fused(pool_designs: Sequence[WSCDesign], wl: LLMWorkload,
             results.append(hit)
     if new:
         _BACKEND.set_many(new)
-    return js, results
+    return results
 
 
 # ---------------------------------------------------------------------------
@@ -416,37 +434,30 @@ def evaluate_pool_fused_joint(pool_points, wl: LLMWorkload,
     points = list(pool_points)
     designs = [p.design for p in points]
     strategies = [p.strategy for p in points]
-    geom = DesignBatch.from_designs(designs)
-    if n_wafers is None:
-        nw = _wafers_for_budget_batch(geom, wl)
-    else:
-        nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
-                             (len(points),)).copy()
-    pending = eval_compiled.dispatch_fused_eval_pinned(
-        geom, wl, nw, strategies, js_dev, max_strategies=max_strategies)
-    js_all = np.asarray(js_dev)
-    js = [int(j) for j in js_all[:q_eff]]
-    # grid resource-fit gate over the pool, gathered to the pick order —
-    # the same host-computed mask the batch pinned path applies
-    cols = eval_compiled.strategy_arrays(strategies)
-    res_ok = compiler_pinned_resource_ok(wl, geom, nw, cols[0], cols[1],
-                                         cols[2], cols[3])[js_all]
-    fresh = pending.finish(nw[js_all], [strategies[j] for j in js_all],
-                           q_eff, res_ok=res_ok)
-    keys = [_cache_key(designs[j], wl, "analytical", int(nw[j]),
-                       max_strategies, gnn_params,
-                       strategy=strategies[j]) for j in js]
-    results: List[EvalResult] = []
-    new = []
-    for k, r in zip(keys, fresh):
-        hit = _BACKEND.get(k)
-        if hit is None:
-            results.append(r)
-            new.append((k, r))
+    with tm.span("evaluate.analytical.prepare", items=len(points)):
+        geom = DesignBatch.from_designs(designs)
+        if n_wafers is None:
+            nw = _wafers_for_budget_batch(geom, wl)
         else:
-            results.append(hit)
-    if new:
-        _BACKEND.set_many(new)
+            nw = np.broadcast_to(np.asarray(n_wafers, np.int64),
+                                 (len(points),)).copy()
+    with tm.span("evaluate.analytical.run", items=q_eff):
+        js_all = tm.to_host(js_dev, "picks")
+        pending = eval_compiled.dispatch_fused_eval_pinned(
+            geom, wl, nw, strategies, js_all, max_strategies=max_strategies)
+        js = [int(j) for j in js_all[:q_eff]]
+        # grid resource-fit gate over the pool, gathered to the pick order
+        # — the same host-computed mask the batch pinned path applies
+        cols = eval_compiled.strategy_arrays(strategies)
+        res_ok = compiler_pinned_resource_ok(wl, geom, nw, cols[0], cols[1],
+                                             cols[2], cols[3])[js_all]
+        fresh = pending.finish(nw[js_all], [strategies[j] for j in js_all],
+                               q_eff, res_ok=res_ok)
+    with tm.span("evaluate.analytical.cache", items=q_eff):
+        results = _cache_fused(
+            [_cache_key(designs[j], wl, "analytical", int(nw[j]),
+                        max_strategies, gnn_params, strategy=strategies[j])
+             for j in js], fresh)
     return js, results
 
 

@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Protocol, Tuple, Union
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.chunk_eval import (
     StepResult,
     evaluate_step_batch,
@@ -369,26 +370,28 @@ def _gnn_lane_makespans(params: Dict, b: _GridLanes) -> np.ndarray:
     (flits, dur, noc_bw) — lanes sharing that triple (common across designs
     and strategies) are collapsed before the XLA call."""
     pat = b.pattern
-    fkey = np.stack([b.flits, b.dur, b.noc_bw], axis=1)
-    uniq, uinv = np.unique(fkey, axis=0, return_inverse=True)
-    ub = _GridLanes(pattern=pat, u_lane=np.zeros(0), flits=uniq[:, 0],
-                    interval=np.zeros(len(uniq)), dur=uniq[:, 1],
-                    noc_bw=uniq[:, 2])
-    node_x, edge_x = _pattern_features(ub)
-    F, E = len(uniq), len(pat.links)
-    Fp = next_pow2(F)               # bounded set of jit shapes per pattern
-    if Fp > F:
-        node_x = np.concatenate(
-            [node_x, np.zeros((Fp - F,) + node_x.shape[1:], np.float32)])
-        edge_x = np.concatenate(
-            [edge_x, np.zeros((Fp - F,) + edge_x.shape[1:], np.float32)])
-    batch = LinkGraphBatch(
-        node_x=node_x, edge_x=edge_x,
-        senders=np.broadcast_to(pat.senders, (Fp, E)),
-        receivers=np.broadcast_to(pat.receivers, (Fp, E)),
-        edge_mask=np.ones((Fp, E), np.float32),
-        n_nodes=pat.n_cores, n_edges_real=np.full(Fp, E, np.int64))
-    wait = gnn_forward_batch(params, batch)[:F].astype(np.float64)
+    with tm.span("evaluate.gnn.features", items=len(b.flits)):
+        fkey = np.stack([b.flits, b.dur, b.noc_bw], axis=1)
+        uniq, uinv = np.unique(fkey, axis=0, return_inverse=True)
+        ub = _GridLanes(pattern=pat, u_lane=np.zeros(0), flits=uniq[:, 0],
+                        interval=np.zeros(len(uniq)), dur=uniq[:, 1],
+                        noc_bw=uniq[:, 2])
+        node_x, edge_x = _pattern_features(ub)
+        F, E = len(uniq), len(pat.links)
+        Fp = next_pow2(F)           # bounded set of jit shapes per pattern
+        if Fp > F:
+            node_x = np.concatenate(
+                [node_x, np.zeros((Fp - F,) + node_x.shape[1:], np.float32)])
+            edge_x = np.concatenate(
+                [edge_x, np.zeros((Fp - F,) + edge_x.shape[1:], np.float32)])
+        batch = LinkGraphBatch(
+            node_x=node_x, edge_x=edge_x,
+            senders=np.broadcast_to(pat.senders, (Fp, E)),
+            receivers=np.broadcast_to(pat.receivers, (Fp, E)),
+            edge_mask=np.ones((Fp, E), np.float32),
+            n_nodes=pat.n_cores, n_edges_real=np.full(Fp, E, np.int64))
+    with tm.span("evaluate.gnn.forward", items=Fp):
+        wait = gnn_forward_batch(params, batch)[:F].astype(np.float64)
     wait_pad = np.concatenate([wait, np.zeros((F, 1))], axis=1)
     pkt_wait = wait_pad[:, pat.route_eids].sum(axis=2)          # (F, P)
     t = uniq[:, 0][:, None] + pat.route_len[None, :] + pkt_wait

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry as tm
+
 MIN_BUCKET = 8
 
 
@@ -112,6 +114,7 @@ def _posterior(raw, X, y, mask):
 
 
 @partial(jax.jit, static_argnames=("iters",))
+@jax.named_scope("propose.fit")
 def _fit_one_jit(X, y, mask, n_real, lr, iters):
     raw = _adam_scan(X, y, mask, n_real, lr, iters)
     L, alpha = _posterior(raw, X, y, mask)
@@ -119,6 +122,7 @@ def _fit_one_jit(X, y, mask, n_real, lr, iters):
 
 
 @partial(jax.jit, static_argnames=("iters",))
+@jax.named_scope("propose.fit")
 def _fit_pair_jit(X, Y2, mask, n_real, lr, iters):
     """Both objective GPs share X: vmap the whole fit over the target axis
     so one XLA program refits the (throughput, power) pair."""
@@ -139,6 +143,7 @@ def _sum_rows(x):
 
 
 @jax.jit
+@jax.named_scope("propose.fantasize")
 def _predict_jit(Xs, X, mask, L, alpha, log_ls, log_sf, mean, std):
     ls = jnp.exp(log_ls)
     sf = jnp.exp(log_sf)
@@ -150,6 +155,7 @@ def _predict_jit(Xs, X, mask, L, alpha, log_ls, log_sf, mean, std):
 
 
 @jax.jit
+@jax.named_scope("propose.fantasize")
 def _rank1_jit(X, y, mask, L, log_ls, log_sf, log_noise, n, x_new, y_norm):
     """Append (x_new, y_norm) at traced row n of the padded buffer: one
     masked kernel row, one triangular solve for the new Cholesky row, two
@@ -213,7 +219,7 @@ class GP:
         Xp, yp, mask = GP._pad(X, yn, bucket_size(len(X)), dtype)
         raw, L, alpha = _fit_one_jit(Xp, yp, mask, jnp.asarray(len(X), dtype),
                                      jnp.asarray(lr, dtype), iters)
-        return GP(Xp, yp, jax.tree.map(np.asarray, raw), mean, std, L, alpha,
+        return GP(Xp, yp, tm.to_host(raw, "gp_params"), mean, std, L, alpha,
                   mask, len(X))
 
     @staticmethod
@@ -233,6 +239,7 @@ class GP:
         raw, L, alpha = _fit_pair_jit(Xp, jnp.asarray(Yp), mask,
                                       jnp.asarray(len(X), dtype),
                                       jnp.asarray(lr, dtype), iters)
+        raw = tm.to_host(raw, "gp_params")
         out = []
         for i, (m, s) in enumerate(stats):
             params = {k: np.asarray(v[i]) for k, v in raw.items()}
@@ -261,17 +268,19 @@ class GP:
         B0 = self.capacity
         if capacity <= B0:
             return self
-        d = self.X.shape[1]
+        X, y, mask, chol, alpha = tm.to_host(
+            (self.X, self.y, self.mask, self.chol, self.alpha), "gp_repad")
+        d = X.shape[1]
         X2 = np.zeros((capacity, d), self.dtype)
-        X2[:B0] = np.asarray(self.X)
+        X2[:B0] = X
         y2 = np.zeros(capacity, self.dtype)
-        y2[:B0] = np.asarray(self.y)
+        y2[:B0] = y
         m2 = np.zeros(capacity, self.dtype)
-        m2[:B0] = np.asarray(self.mask)
+        m2[:B0] = mask
         L2 = np.eye(capacity, dtype=self.dtype)
-        L2[:B0, :B0] = np.asarray(self.chol)
+        L2[:B0, :B0] = chol
         a2 = np.zeros(capacity, self.dtype)
-        a2[:B0] = np.asarray(self.alpha)
+        a2[:B0] = alpha
         return GP(jnp.asarray(X2), jnp.asarray(y2), self.params, self.mean,
                   self.std, jnp.asarray(L2), jnp.asarray(a2),
                   jnp.asarray(m2), self.n)
@@ -284,6 +293,7 @@ class GP:
             jnp.asarray(self.params["log_sf"]),
             jnp.asarray(self.mean, self.dtype),
             jnp.asarray(self.std, self.dtype))
+        mu, sd = tm.to_host((mu, sd), "gp_predict")
         return np.asarray(mu, np.float64), np.asarray(sd, np.float64)
 
     def condition_on(self, x: np.ndarray, y: float) -> "GP":

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.design_space import WSCDesign, decode_batch, sample
 from repro.core.ehvi import ehvi_padded
 from repro.core.gp import GP, _predict_jit, _rank1_jit, bucket_size
@@ -53,7 +54,6 @@ class Trace:
     designs: List[WSCDesign]
     ys: List[Tuple[float, float]]         # (throughput, power)
     hv: List[float]                       # hypervolume after each evaluation
-    wall_s: List[float]
     n_evals: int = 0                      # total evals incl. f1-only points
     # per-fidelity-stage eval-cache traffic ({"f0"/"f1": {hits, misses,
     # entries_added}}), recorded by the exploration loop so the cost of the
@@ -97,15 +97,18 @@ def _valid_candidates(rng: np.random.Generator, n: int,
     the acquisition a short (or empty) candidate set."""
     xs, ds = [], []
     n_drawn = 0
-    for _ in range(max_tries):
-        us = sample(rng, n)
-        n_drawn += len(us)
-        for u, r in zip(us, validate_batch(decode_batch(us))):
-            if r.ok:
-                xs.append(u)
-                ds.append(r.design)
-            if len(xs) >= n:
-                return np.array(xs), ds
+    with tm.span("candidates", items=n):
+        for _ in range(max_tries):
+            us = sample(rng, n)
+            n_drawn += len(us)
+            with tm.span("candidates.validate", items=len(us)):
+                rs = validate_batch(decode_batch(us))
+            for u, r in zip(us, rs):
+                if r.ok:
+                    xs.append(u)
+                    ds.append(r.design)
+                if len(xs) >= n:
+                    return np.array(xs), ds
     rate = len(xs) / max(n_drawn, 1)
     raise RuntimeError(
         f"design-space sampling produced only {len(xs)}/{n} valid "
@@ -174,24 +177,27 @@ def _valid_candidates_joint(rng: np.random.Generator, n: int, space, wl,
     nd = len(DIMS)
     xs, pts = [], []
     n_drawn = 0
-    for _ in range(max_tries):
-        us = sample_joint(rng, n, space)
-        n_drawn += len(us)
-        batch = decode_joint_batch(us, space)
-        seeded = list(range(0, len(batch), 2))
-        enc, found = _grid_seed_strategies(
-            [batch[i].design for i in seeded], wl, space)
-        for j, i in enumerate(seeded):
-            if found[j]:
-                us[i, nd:] = enc[j]
-                batch[i] = JointDesign(
-                    batch[i].design, space.decode_strategy(us[i, nd:]))
-        for u, p, r in zip(us, batch, validate_joint_batch(batch, wl)):
-            if r.ok:
-                xs.append(u)
-                pts.append(JointDesign(r.design, p.strategy))
-            if len(xs) >= n:
-                return np.array(xs), pts
+    with tm.span("candidates", items=n):
+        for _ in range(max_tries):
+            us = sample_joint(rng, n, space)
+            n_drawn += len(us)
+            with tm.span("candidates.validate", items=len(us)):
+                batch = decode_joint_batch(us, space)
+                seeded = list(range(0, len(batch), 2))
+                enc, found = _grid_seed_strategies(
+                    [batch[i].design for i in seeded], wl, space)
+                for j, i in enumerate(seeded):
+                    if found[j]:
+                        us[i, nd:] = enc[j]
+                        batch[i] = JointDesign(
+                            batch[i].design, space.decode_strategy(us[i, nd:]))
+                rs = validate_joint_batch(batch, wl)
+            for u, p, r in zip(us, batch, rs):
+                if r.ok:
+                    xs.append(u)
+                    pts.append(JointDesign(r.design, p.strategy))
+                if len(xs) >= n:
+                    return np.array(xs), pts
     rate = len(xs) / max(n_drawn, 1)
     raise RuntimeError(
         f"joint-space sampling produced only {len(xs)}/{n} valid "
@@ -202,11 +208,13 @@ def _valid_candidates_joint(rng: np.random.Generator, n: int, space, wl,
 
 def _fit_models(X: np.ndarray, Y: np.ndarray) -> Tuple[GP, GP]:
     # one vmapped XLA call refits both objective surrogates on the shared X
-    return GP.fit_pair(X, (np.log1p(np.maximum(Y[:, 0], 0.0)),
-                           -np.log(np.maximum(Y[:, 1], 1.0))))
+    with tm.span("propose.fit", items=len(X)):
+        return GP.fit_pair(X, (np.log1p(np.maximum(Y[:, 0], 0.0)),
+                               -np.log(np.maximum(Y[:, 1], 1.0))))
 
 
 @partial(jax.jit, static_argnames=("q",))
+@jax.named_scope("propose.acquire")
 def _acquire_scan_jit(X, mask, n0, yt, Lt, at, ls_t, sf_t, noise_t, mt, st,
                       yp, Lp, ap, ls_p, sf_p, noise_p, mp, sp,
                       cand, fant, fant_mask, nf0, ref, q):
@@ -244,15 +252,10 @@ def _acquire_scan_jit(X, mask, n0, yt, Lt, at, ls_t, sf_t, noise_t, mt, st,
     return js
 
 
-def _acquire_batch_device(models: Tuple[GP, GP], cand_x: np.ndarray,
-                          evaluated: np.ndarray, ref: np.ndarray,
-                          q: int = 1):
-    """`_acquire_batch` without the host sync: returns the padded device
-    index vector straight from `_acquire_scan_jit` (the first q entries
-    are the picks). The fused analytical evaluator
-    (`repro.core.eval_compiled.dispatch_fused_eval`) consumes it on
-    device, so a synchronous f1 iteration never waits on the proposal
-    before dispatching the evaluation."""
+def _acquire_scan(models: Tuple[GP, GP], cand_x: np.ndarray,
+                  evaluated: np.ndarray, ref: np.ndarray, q: int):
+    """Pad the proposal's inputs to their buckets and dispatch
+    `_acquire_scan_jit`; returns its device index vector."""
     g_t, g_p = models
     if g_t.n != g_p.n:
         raise ValueError("objective GPs must share the training set")
@@ -273,7 +276,7 @@ def _acquire_batch_device(models: Tuple[GP, GP], cand_x: np.ndarray,
     fmask = np.zeros(Bf, dt)
     fmask[:len(fantasy)] = 1.0
     p_t, p_p = g_t.params, g_p.params
-    js = _acquire_scan_jit(
+    return _acquire_scan_jit(
         g_t.X, g_t.mask, jnp.asarray(g_t.n),
         g_t.y, g_t.chol, g_t.alpha, jnp.asarray(p_t["log_ls"]),
         jnp.asarray(p_t["log_sf"]), jnp.asarray(p_t["log_noise"]),
@@ -284,7 +287,17 @@ def _acquire_batch_device(models: Tuple[GP, GP], cand_x: np.ndarray,
         jnp.asarray(np.asarray(cand_x, dt)), jnp.asarray(fant),
         jnp.asarray(fmask), jnp.asarray(len(fantasy)),
         jnp.asarray(np.asarray(ref, dt)), qpad)
-    return js
+
+
+def _acquire_batch_device(models: Tuple[GP, GP], cand_x: np.ndarray,
+                          evaluated: np.ndarray, ref: np.ndarray,
+                          q: int = 1):
+    """`_acquire_batch` without the host sync: returns the padded device
+    index vector straight from `_acquire_scan_jit` (the first q entries
+    are the picks) for the fused analytical evaluator
+    (`repro.core.evaluator.evaluate_pool_fused`), which reads it once."""
+    with tm.span("propose.acquire", items=q):
+        return _acquire_scan(models, cand_x, evaluated, ref, q)
 
 
 def _acquire_batch(models: Tuple[GP, GP], cand_x: np.ndarray,
@@ -295,8 +308,10 @@ def _acquire_batch(models: Tuple[GP, GP], cand_x: np.ndarray,
     The NumPy reference loop lives in `repro.core.gp_ref.acquire_batch_ref`
     (property-tested equivalent)."""
     q = max(1, min(q, len(cand_x)))
-    js = _acquire_batch_device(models, cand_x, evaluated, ref, q=q)
-    return [int(j) for j in np.asarray(js)[:q]]
+    with tm.span("propose.acquire", items=q):
+        js = tm.to_host(_acquire_scan(models, cand_x, evaluated, ref, q),
+                        "picks")
+    return [int(j) for j in js[:q]]
 
 
 def _acquire(models: Tuple[GP, GP], cand_x: np.ndarray,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.compiler import ChunkGraph, _xy_route
 from repro.core.design_space import WSCDesign
 from repro.core.noc_sim import packets_for_transfer, simulate
@@ -218,6 +219,7 @@ def pad_link_graphs(graphs: Sequence[LinkGraph],
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes",))
+@jax.named_scope("evaluate.gnn.forward")
 def _forward_batch_jit(params, node_x, edge_x, senders, receivers, edge_mask,
                        *, n_nodes):
     def one(nx, ex, s, r, m):
@@ -233,7 +235,7 @@ def gnn_forward_batch(params: Dict, batch: LinkGraphBatch) -> np.ndarray:
         jnp.asarray(batch.edge_x), jnp.asarray(batch.senders),
         jnp.asarray(batch.receivers), jnp.asarray(batch.edge_mask),
         n_nodes=int(batch.n_nodes))
-    return np.asarray(out)
+    return tm.to_host(out, "gnn_out")
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes",))
@@ -307,6 +309,7 @@ def _val_metrics(params: Dict, batch: LinkGraphBatch) -> Tuple[float, float]:
         jnp.asarray(batch.edge_x), jnp.asarray(batch.senders),
         jnp.asarray(batch.receivers), jnp.asarray(batch.edge_mask),
         jnp.asarray(batch.target), n_nodes=int(batch.n_nodes))
+    losses, zs = tm.to_host((losses, zs), "gnn_val")
     real = np.asarray(batch.edge_mask) > 0
     # rank what the deployed predictor actually outputs: gnn_forward applies
     # expm1(clip(relu(z))), so negative logits collapse to tied zero waits —
@@ -317,6 +320,7 @@ def _val_metrics(params: Dict, batch: LinkGraphBatch) -> Tuple[float, float]:
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes",))
+@jax.named_scope("calibrate.train")
 def _train_step_jit(params, m, v, step, lr, node_x, edge_x, senders,
                     receivers, edge_mask, target, *, n_nodes):
     """One fused (grad + Adam) update on a padded graph: masked-mean MSE in
@@ -394,7 +398,7 @@ def train_gnn(params: Dict, dataset: List[LinkGraph], epochs: int = 60,
     step = 0
     for ep in range(epochs):
         order = rng.permutation(len(train))
-        ep_loss = 0.0
+        lvals = []
         for gi in order:
             arrs = padded.get(id(train[gi]))
             if arrs is None:
@@ -403,6 +407,10 @@ def train_gnn(params: Dict, dataset: List[LinkGraph], epochs: int = 60,
             params, m, v, lval = _train_step_jit(
                 params, m, v, jnp.asarray(float(step)),
                 jnp.asarray(lr, jnp.float32), *arrs[:6], n_nodes=arrs[6])
+            lvals.append(lval)
+        # one read of the epoch's losses, summed in step order
+        ep_loss = 0.0
+        for lval in tm.to_host(lvals, "gnn_train_loss"):
             ep_loss += float(lval)
         hist.train_loss.append(ep_loss / max(len(train), 1))
         if val_batch is not None:

@@ -51,6 +51,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.design_space import WSCDesign
 from repro.core.fidelity import FidelityBackend
 from repro.core.serving import ServingSLO
@@ -918,17 +919,20 @@ def sample_policy_candidates(rng: np.random.Generator, n: int,
                          f"(got {policies})")
     xs, ds = [], []
     n_drawn = 0
-    for _ in range(max_tries):
-        us = sample(rng, n)
-        up = rng.random((n, 1))
-        n_drawn += len(us)
-        for u, p, r in zip(us, up[:, 0], validate_batch(decode_batch(us))):
-            if r.ok:
-                xs.append(np.concatenate([u, [p]]))
-                k = min(int(p * len(policies)), len(policies) - 1)
-                ds.append(PolicyDesign(r.design, policies[k]))
-            if len(xs) >= n:
-                return np.array(xs), ds
+    with tm.span("candidates", items=n):
+        for _ in range(max_tries):
+            us = sample(rng, n)
+            up = rng.random((n, 1))
+            n_drawn += len(us)
+            with tm.span("candidates.validate", items=len(us)):
+                rs = validate_batch(decode_batch(us))
+            for u, p, r in zip(us, up[:, 0], rs):
+                if r.ok:
+                    xs.append(np.concatenate([u, [p]]))
+                    k = min(int(p * len(policies)), len(policies) - 1)
+                    ds.append(PolicyDesign(r.design, policies[k]))
+                if len(xs) >= n:
+                    return np.array(xs), ds
     rate = len(xs) / max(n_drawn, 1)
     raise RuntimeError(
         f"policy-space sampling produced only {len(xs)}/{n} valid "
@@ -1008,6 +1012,7 @@ _SCHED_CACHE: Dict[Tuple, TraceSchedule] = {}
 def _schedule_cached(trace: RequestTrace, slots: int,
                      policy: str) -> TraceSchedule:
     key = (trace, slots, policy)
+    tm.count("schedule.hit" if key in _SCHED_CACHE else "schedule.miss")
     if key not in _SCHED_CACHE:
         if len(_SCHED_CACHE) > 64:
             _SCHED_CACHE.clear()
@@ -1029,7 +1034,6 @@ def evaluate_trace_serving_batch(
     `trace_serving_metrics` over the candidate axis; "disaggregated"
     routes through `heterogeneity.evaluate_hetero_trace_serving`'s coupled
     prefill/decode-split model (reticle granularity, `prefill_ratio`)."""
-    from repro.core.evaluator import evaluate_design_batch
     from repro.core.fidelity import get_backend
 
     backend = get_backend(fidelity)
@@ -1056,70 +1060,74 @@ def evaluate_trace_serving_batch(
     if dis:
         from repro.core.heterogeneity import evaluate_hetero_trace_serving
         for i in dis:
-            results[i] = evaluate_hetero_trace_serving(
-                raw[i], raw[i], wl_base, "reticle", prefill_ratio, trace,
-                slots=slots, window_steps=window_steps, n_wafers=n_wafers,
-                fidelity=backend, gnn_params=gnn_params)
+            with tm.span("evaluate.trace.disaggregated", items=1):
+                results[i] = evaluate_hetero_trace_serving(
+                    raw[i], raw[i], wl_base, "reticle", prefill_ratio, trace,
+                    slots=slots, window_steps=window_steps,
+                    n_wafers=n_wafers, fidelity=backend,
+                    gnn_params=gnn_params)
 
     # ---- pool candidates: shared schedule per policy, broadcast math ---
     pool = [i for i, p in enumerate(pols) if p != "disaggregated"]
     if not pool:
         return results                      # type: ignore[return-value]
-    wl_p, wl_d, p_ref = trace_serving_workloads(wl_base, trace, slots)
-    rps = evaluate_design_batch([raw[i] for i in pool], wl_p,
-                                fidelity=backend, gnn_params=gnn_params,
-                                n_wafers=n_wafers,
-                                max_strategies=max_strategies)
-    rds = evaluate_design_batch([raw[i] for i in pool], wl_d,
-                                fidelity=backend, gnn_params=gnn_params,
-                                n_wafers=n_wafers,
-                                max_strategies=max_strategies)
-    for pol in sorted({pols[i] for i in pool}):
-        grp = [j for j, i in enumerate(pool) if pols[i] == pol]
-        feas = [j for j in grp if rps[j].feasible and rds[j].feasible]
-        for j in grp:
-            if j not in feas:
-                reason = ("prefill_" if not rps[j].feasible else
-                          "decode_") + "infeasible"
-                results[pool[j]] = _infeasible(pol, rps[j].n_wafers, reason)
-        if not feas:
-            continue
-        sched = _schedule_cached(trace, slots, pol)
-        t_p = np.array([rps[j].step.step_time_s for j in feas])
-        t_d = np.array([rds[j].step.step_time_s for j in feas])
-        e_p = np.array([rps[j].step.energy_j for j in feas])
-        e_d = np.array([rds[j].step.energy_j for j in feas])
-        m = trace_serving_metrics(sched, trace, t_p, p_ref, t_d,
-                                  window_steps=window_steps)
-        # energy: each prefill event costs its context-scaled share of the
-        # reference prefill step; each decode tick costs the batched
-        # decode step (idle ticks cost wall-clock only)
-        ctx_sum = float(np.sum(sched.event_ctx))
-        energy = e_p * ctx_sum / p_ref + e_d * sched.n_decode_steps
-        power = energy / np.maximum(m["total_time"], 1e-12)
-        for c, j in enumerate(feas):
-            results[pool[j]] = TraceServingResult(
-                feasible=True, policy=pol,
-                goodput_tok_s=float(m["goodput"][c]),
-                interactive_goodput_tok_s=float(
-                    m["interactive_goodput"][c]),
-                worst_window_goodput_tok_s=float(
-                    m["worst_window_goodput"][c]),
-                throughput_tok_s=float(m["throughput"][c]),
-                ttft_s=float(m["ttft"][c].mean()),
-                ttft_max_s=float(m["ttft"][c].max()),
-                tpot_s=float(m["tpot"][c].mean()),
-                tpot_max_s=float(m["tpot"][c].max()),
-                slo_attainment=float(m["slo_attainment"][c]),
-                total_time_s=float(m["total_time"][c]),
-                n_steps=sched.n_steps,
-                n_decode_steps=sched.n_decode_steps,
-                n_preemptions=sched.n_preemptions,
-                power_w=float(power[c]), energy_j=float(energy[c]),
-                n_wafers=rds[j].n_wafers,
-                per_tenant=_per_tenant(trace, m["met"][c], m["ttft"][c],
-                                       m["tpot"][c],
-                                       float(m["total_time"][c])))
+    from repro.core.evaluator import evaluate_design_batch
+    with tm.span("evaluate.trace.pool", items=len(pool)):
+        wl_p, wl_d, p_ref = trace_serving_workloads(wl_base, trace, slots)
+        with tm.span("evaluate.trace.steps", items=len(pool)):
+            kw = dict(fidelity=backend, gnn_params=gnn_params,
+                      n_wafers=n_wafers, max_strategies=max_strategies)
+            rps = evaluate_design_batch([raw[i] for i in pool], wl_p, **kw)
+            rds = evaluate_design_batch([raw[i] for i in pool], wl_d, **kw)
+        for pol in sorted({pols[i] for i in pool}):
+            grp = [j for j, i in enumerate(pool) if pols[i] == pol]
+            feas = [j for j in grp if rps[j].feasible and rds[j].feasible]
+            for j in grp:
+                if j not in feas:
+                    reason = ("prefill_" if not rps[j].feasible else
+                              "decode_") + "infeasible"
+                    results[pool[j]] = _infeasible(pol, rps[j].n_wafers,
+                                                   reason)
+            if not feas:
+                continue
+            with tm.span("evaluate.trace.schedule"):
+                sched = _schedule_cached(trace, slots, pol)
+            t_p = np.array([rps[j].step.step_time_s for j in feas])
+            t_d = np.array([rds[j].step.step_time_s for j in feas])
+            e_p = np.array([rps[j].step.energy_j for j in feas])
+            e_d = np.array([rds[j].step.energy_j for j in feas])
+            with tm.span("evaluate.trace.metrics", items=len(feas)):
+                m = trace_serving_metrics(sched, trace, t_p, p_ref, t_d,
+                                          window_steps=window_steps)
+            # energy: each prefill event costs its context-scaled share of the
+            # reference prefill step; each decode tick costs the batched
+            # decode step (idle ticks cost wall-clock only)
+            ctx_sum = float(np.sum(sched.event_ctx))
+            energy = e_p * ctx_sum / p_ref + e_d * sched.n_decode_steps
+            power = energy / np.maximum(m["total_time"], 1e-12)
+            for c, j in enumerate(feas):
+                results[pool[j]] = TraceServingResult(
+                    feasible=True, policy=pol,
+                    goodput_tok_s=float(m["goodput"][c]),
+                    interactive_goodput_tok_s=float(
+                        m["interactive_goodput"][c]),
+                    worst_window_goodput_tok_s=float(
+                        m["worst_window_goodput"][c]),
+                    throughput_tok_s=float(m["throughput"][c]),
+                    ttft_s=float(m["ttft"][c].mean()),
+                    ttft_max_s=float(m["ttft"][c].max()),
+                    tpot_s=float(m["tpot"][c].mean()),
+                    tpot_max_s=float(m["tpot"][c].max()),
+                    slo_attainment=float(m["slo_attainment"][c]),
+                    total_time_s=float(m["total_time"][c]),
+                    n_steps=sched.n_steps,
+                    n_decode_steps=sched.n_decode_steps,
+                    n_preemptions=sched.n_preemptions,
+                    power_w=float(power[c]), energy_j=float(energy[c]),
+                    n_wafers=rds[j].n_wafers,
+                    per_tenant=_per_tenant(trace, m["met"][c], m["ttft"][c],
+                                           m["tpot"][c],
+                                           float(m["total_time"][c])))
     return results                          # type: ignore[return-value]
 
 

@@ -53,6 +53,24 @@ def _summarize(result) -> None:
         print(f"  front: {y0}={p[y0]:.1f}  "
               f"{spec.objectives[1].name}={p[spec.objectives[1].name]:.1f}  "
               f"{p['describe']}")
+    _time_by_span(result.telemetry)
+
+
+def _time_by_span(tel, top: int = 6) -> None:
+    """The spans with the most self time, host syncs a step, compiles."""
+    spans = tel.get("spans", {})
+    if not spans:
+        return
+    print("time by span (self time, total, count):")
+    for name, e in sorted(spans.items(), key=lambda kv: -kv[1]["self_s"]
+                          )[:top]:
+        print(f"  {name:<30} {1e3 * e['self_s']:9.1f} ms "
+              f"{1e3 * e['s']:9.1f} ms  x{e['count']}")
+    c = tel.get("counters", {})
+    steps = max(spans.get("step", {}).get("count", 0), 1)
+    print(f"host syncs: {c.get('host_syncs', 0) / steps:.2f} a step; "
+          f"compiles: {int(c.get('compiles', 0))} "
+          f"({c.get('compile_s', 0.0):.2f}s)")
 
 
 def _fleet_main(argv: List[str]) -> int:

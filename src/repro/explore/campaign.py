@@ -521,6 +521,9 @@ class CampaignResult:
     stage_cache: Dict[str, Dict]
     objective_stats: Dict
     calibration: List[Dict]
+    # the loop's spans by name and its counters (`Telemetry.to_dict`),
+    # cumulative across resumes
+    telemetry: Dict = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> Dict:
         return {
@@ -535,6 +538,7 @@ class CampaignResult:
             "stage_cache": self.stage_cache,
             "objective_stats": self.objective_stats,
             "calibration": self.calibration,
+            "telemetry": self.telemetry,
         }
 
     def save(self, path: str) -> str:
@@ -746,7 +750,8 @@ class Campaign:
             hv_final=tr.hv[-1] if tr.hv else 0.0,
             front=_front_records(self.spec, tr),
             stage_cache=stage_cache, objective_stats=stats,
-            calibration=calibration)
+            calibration=calibration,
+            telemetry=self.loop.state.telemetry.to_dict())
 
 
 def run_campaign(spec: CampaignSpec, **kw) -> CampaignResult:

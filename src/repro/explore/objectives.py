@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core import components as C
 from repro.core.design_space import WSCDesign
 from repro.core.fidelity import FidelityBackend, get_backend
@@ -143,6 +144,11 @@ class Objective:
 
     batched = True            # legacy marker (pre-protocol sniffers)
     fidelity: Optional[str] = None
+
+    @property
+    def layer(self) -> str:
+        """The evaluation layer, the tag of its `evaluate` span."""
+        return self.fidelity or "other"
 
     def __init__(self, objectives: Optional[Sequence[ObjectiveSpec]] = None,
                  constraints: Sequence[ConstraintSpec] = (),
@@ -301,19 +307,16 @@ class EvaluatorObjective(Objective):
         `js_dev` (the compiled acquire scan's output) through the fused
         gather+evaluate program; returns (pick indices, folded ys) —
         bit-identical to `eval_many([pool_designs[j] for j in js])`."""
-        if self.strategy_mode == "joint":
-            from repro.core.evaluator import evaluate_pool_fused_joint
-            js, rs = evaluate_pool_fused_joint(
+        from repro.core.evaluator import (evaluate_pool_fused,
+                                          evaluate_pool_fused_joint)
+        fused = (evaluate_pool_fused_joint if self.strategy_mode == "joint"
+                 else evaluate_pool_fused)
+        with tm.span("evaluate", items=q_eff, tag=self.layer):
+            js, rs = fused(
                 list(pool_designs), self.wl, js_dev, q_eff,
                 gnn_params=self.gnn_params(), n_wafers=self.n_wafers,
                 max_strategies=self.max_strategies)
             return js, self.fold_metrics(self.metrics_from_results(rs))
-        from repro.core.evaluator import evaluate_pool_fused
-        js, rs = evaluate_pool_fused(
-            list(pool_designs), self.wl, js_dev, q_eff,
-            gnn_params=self.gnn_params(), n_wafers=self.n_wafers,
-            max_strategies=self.max_strategies)
-        return js, self.fold_metrics(self.metrics_from_results(rs))
 
 
 class ServingObjective(Objective):
@@ -428,6 +431,8 @@ class TraceServingObjective(Objective):
     `PolicyDesign`s (each carrying its own searched policy) or plain
     designs scored under `policy`; per-tenant goodput/attainment flow out
     as `tenant:<name>:*` metrics so constraints can pin a specific class."""
+
+    layer = "trace"
 
     def __init__(self, wl: LLMWorkload, trace, *, policy: str = "fifo",
                  slots: int = 8, window_steps: int = 64,

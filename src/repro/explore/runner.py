@@ -51,6 +51,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core.evalcache import attribute_cache_traffic
 from repro.core.mfmobo import (
     Trace,
@@ -68,7 +69,8 @@ from repro.explore.objectives import Objective, as_objective
 STRATEGIES = ("mfmobo", "mobo", "random")
 
 # v2: LoopState gained `inflight` + `dispatch_seq` (async proposal mode);
-# v1 checkpoints still load (the new fields default to empty)
+# v1 checkpoints still load (the new fields default to empty, as does
+# `telemetry` for checkpoints written before it)
 CHECKPOINT_VERSION = 2
 _READABLE_VERSIONS = (1, CHECKPOINT_VERSION)
 
@@ -159,26 +161,32 @@ class LoopState:
     wall_s: float = 0.0               # accumulated across run() segments
     inflight: List[PendingBatch] = dataclasses.field(default_factory=list)
     dispatch_seq: int = 0             # next PendingBatch.seq
+    # the campaign's spans and counters, cumulative across resumes
+    telemetry: tm.Telemetry = dataclasses.field(default_factory=tm.Telemetry)
 
 
 def _fresh_state(cfg: LoopConfig) -> LoopState:
-    tr = Trace([], [], [], [], [])
+    tr = Trace([], [], [], [])
     tr.stage_cache = {"f0": {"hits": 0, "misses": 0, "entries_added": 0},
                       "f1": {"hits": 0, "misses": 0, "entries_added": 0}}
     return LoopState(rng=np.random.default_rng(cfg.seed), trace=tr,
                      X0=[], Y0=[], X1=[], Y1=[], hist_d=[], hist_y=[])
 
 
-def _eval_attributed(obj: Objective, designs):
+def _eval_attributed(obj: Objective, designs,
+                     telemetry: Optional[tm.Telemetry] = None):
     """Evaluate a batch with this thread's eval-cache traffic captured.
     Runs on the caller's thread in sync mode and on pool threads in async
     mode — thread-local attribution is what keeps concurrent batches from
-    scribbling over each other's counters."""
-    with attribute_cache_traffic() as acc:
+    scribbling over each other's counters. Pool threads are handed the
+    campaign's `telemetry`; the caller's thread already has it active."""
+    designs = list(designs)
+    with tm.activate(telemetry or tm.current()), \
+            tm.span("evaluate", items=len(designs), tag=obj.layer), \
+            attribute_cache_traffic() as acc:
         # host-side floats only: whatever array scalars the objective hands
         # back must not leak device buffers into the picklable LoopState
-        ys = [(float(t), float(p))
-              for t, p in obj.eval_many(list(designs))]
+        ys = [(float(t), float(p)) for t, p in obj.eval_many(designs)]
     return ys, acc
 
 
@@ -252,7 +260,6 @@ class ExplorationLoop:
         tr.designs.append(d)
         tr.ys.append(y)
         tr.hv.append(hypervolume_2d(obj_space(tr.ys), self.ref))
-        tr.wall_s.append(time.time())
 
     def _fire_handover(self):
         self.state.handover_fired = True
@@ -284,7 +291,8 @@ class ExplorationLoop:
         st.dispatch_seq += 1
         st.inflight.append(pb)
         self._futures[pb.seq] = self._pool().submit(
-            _eval_attributed, self._objective(stage), pb.designs)
+            _eval_attributed, self._objective(stage), pb.designs,
+            telemetry=st.telemetry)
 
     def _redispatch_orphans(self) -> None:
         """Resubmit inflight batches without a live future — the resume
@@ -292,7 +300,8 @@ class ExplorationLoop:
         for pb in self.state.inflight:
             if pb.seq not in self._futures:
                 self._futures[pb.seq] = self._pool().submit(
-                    _eval_attributed, self._objective(pb.stage), pb.designs)
+                    _eval_attributed, self._objective(pb.stage), pb.designs,
+                    telemetry=self.state.telemetry)
 
     def _harvest_one(self) -> None:
         """Block on the OLDEST inflight batch and fold its results into the
@@ -308,17 +317,18 @@ class ExplorationLoop:
             ys, acc = fut.result()
         self._fold_traffic(pb.stage, acc)
         st.trace.n_evals += len(ys)
-        for x, d, y in zip(np.asarray(pb.xs), pb.designs, ys):
-            if cfg.strategy == "mfmobo":
-                st.hist_d.append(d)
-                st.hist_y.append(y)
-            if pb.stage == "f0":
-                st.X0.append(x)
-                st.Y0.append(y)
-                self._record(x, d, y)
-            else:
-                st.X1.append(x)
-                st.Y1.append(y)
+        with tm.span("fold", items=len(ys)):
+            for x, d, y in zip(np.asarray(pb.xs), pb.designs, ys):
+                if cfg.strategy == "mfmobo":
+                    st.hist_d.append(d)
+                    st.hist_y.append(y)
+                if pb.stage == "f0":
+                    st.X0.append(x)
+                    st.Y0.append(y)
+                    self._record(x, d, y)
+                else:
+                    st.X1.append(x)
+                    st.Y1.append(y)
 
     def _fantasize_inflight(self, models):
         """Condition both GPs on every inflight candidate at its posterior
@@ -327,13 +337,14 @@ class ExplorationLoop:
         the q-EHVI proposal accounts for work already in the pipeline."""
         g_t, g_p = models
         rows = []
-        for pb in self.state.inflight:
-            for x in np.asarray(pb.xs):
-                mu_t, _ = g_t.predict(x[None])
-                mu_p, _ = g_p.predict(x[None])
-                g_t = g_t.condition_on(x, float(mu_t[0]))
-                g_p = g_p.condition_on(x, float(mu_p[0]))
-                rows.append((float(mu_t[0]), float(mu_p[0])))
+        with tm.span("propose.fantasize"):
+            for pb in self.state.inflight:
+                for x in np.asarray(pb.xs):
+                    mu_t, _ = g_t.predict(x[None])
+                    mu_p, _ = g_p.predict(x[None])
+                    g_t = g_t.condition_on(x, float(mu_t[0]))
+                    g_p = g_p.condition_on(x, float(mu_p[0]))
+                    rows.append((float(mu_t[0]), float(mu_p[0])))
         return (g_t, g_p), np.array(rows, float).reshape(-1, 2)
 
     # -- step machine ------------------------------------------------------
@@ -351,6 +362,15 @@ class ExplorationLoop:
             return st.done >= cfg.N0 - cfg.d0
         return not st.pending                         # random
 
+    def _step_kind(self) -> str:
+        """The kind of step about to run: init | f1 | handover | f0."""
+        st, cfg = self.state, self.cfg
+        if not st.initialized:
+            return "init"
+        if cfg.strategy != "mfmobo" or st.done >= cfg.N1 - cfg.d1 + cfg.k:
+            return "f0"
+        return "f1" if st.done < cfg.N1 - cfg.d1 else "handover"
+
     def step(self) -> bool:
         """Advance one batch; returns False once the budget is spent."""
         if self.finished:
@@ -358,28 +378,31 @@ class ExplorationLoop:
         st, cfg = self.state, self.cfg
         use_async = cfg.async_depth > 0 and cfg.strategy in ("mfmobo",
                                                              "mobo")
-        if not st.initialized:
-            self._init_step()
-        elif cfg.strategy == "mfmobo":
-            self._mfmobo_step_async() if use_async else self._mfmobo_step()
-        elif cfg.strategy == "mobo":
-            self._mobo_step_async() if use_async else self._mobo_step()
-        else:
-            self._random_step()
+        with tm.activate(st.telemetry), tm.span("step",
+                                                tag=self._step_kind()):
+            if not st.initialized:
+                self._init_step()
+            elif cfg.strategy == "mfmobo":
+                (self._mfmobo_step_async() if use_async
+                 else self._mfmobo_step())
+            elif cfg.strategy == "mobo":
+                self._mobo_step_async() if use_async else self._mobo_step()
+            else:
+                self._random_step()
         st.steps += 1
         return True
 
     def run(self, *, max_steps: Optional[int] = None,
             checkpoint_every: int = 0,
             checkpoint_cb: Optional[Callable[[], None]] = None) -> Trace:
-        t0 = time.time()
+        t0 = time.perf_counter()
 
         def flush_wall():
             # fold the running segment into state *before* any checkpoint
             # is pickled, so a crash-resume doesn't under-report wall time
             # (and overstate candidates/sec)
             nonlocal t0
-            now = time.time()
+            now = time.perf_counter()
             self.state.wall_s += now - t0
             t0 = now
 
@@ -408,28 +431,31 @@ class ExplorationLoop:
         if cfg.strategy == "mfmobo":
             init_x, init_d = self._candidates(cfg.d0 + cfg.d1)
             ys1 = self._eval(self.f1, init_d[:cfg.d1], "f1")
-            for x, d, y in zip(init_x[:cfg.d1], init_d[:cfg.d1], ys1):
-                st.X1.append(x)
-                st.Y1.append(y)
-                st.hist_d.append(d)
-                st.hist_y.append(y)
+            with tm.span("fold", items=len(ys1)):
+                for x, d, y in zip(init_x[:cfg.d1], init_d[:cfg.d1], ys1):
+                    st.X1.append(x)
+                    st.Y1.append(y)
+                    st.hist_d.append(d)
+                    st.hist_y.append(y)
             if cfg.d0 > 0 and self.on_handover is not None:
                 self._fire_handover()
             ys0 = self._eval(self.f0, init_d[cfg.d1:cfg.d1 + cfg.d0], "f0")
-            for x, d, y in zip(init_x[cfg.d1:cfg.d1 + cfg.d0],
-                               init_d[cfg.d1:cfg.d1 + cfg.d0], ys0):
-                st.X0.append(x)
-                st.Y0.append(y)
-                st.hist_d.append(d)
-                st.hist_y.append(y)
-                self._record(x, d, y)
+            with tm.span("fold", items=len(ys0)):
+                for x, d, y in zip(init_x[cfg.d1:cfg.d1 + cfg.d0],
+                                   init_d[cfg.d1:cfg.d1 + cfg.d0], ys0):
+                    st.X0.append(x)
+                    st.Y0.append(y)
+                    st.hist_d.append(d)
+                    st.hist_y.append(y)
+                    self._record(x, d, y)
         elif cfg.strategy == "mobo":
             init_x, init_d = self._candidates(cfg.d0)
-            for x, d, y in zip(init_x, init_d,
-                               self._eval(self.f0, init_d, "f0")):
-                st.X0.append(x)
-                st.Y0.append(y)
-                self._record(x, d, y)
+            ys = self._eval(self.f0, init_d, "f0")
+            with tm.span("fold", items=len(ys)):
+                for x, d, y in zip(init_x, init_d, ys):
+                    st.X0.append(x)
+                    st.Y0.append(y)
+                    self._record(x, d, y)
         else:                                         # random
             xs, ds = self._candidates(cfg.N0)
             st.pending = [(x, d) for x, d in zip(xs, ds)]
@@ -466,16 +492,17 @@ class ExplorationLoop:
         else:
             js = _acquire_batch(models, cand_x, ev, self.ref, q=q_eff)
             ys = self._eval(obj, [cand_d[j] for j in js], stage)
-        for j, y in zip(js, ys):
-            st.hist_d.append(cand_d[j])
-            st.hist_y.append(y)
-            if use_f0:
-                st.X0.append(cand_x[j])
-                st.Y0.append(y)
-                self._record(cand_x[j], cand_d[j], y)
-            else:
-                st.X1.append(cand_x[j])
-                st.Y1.append(y)
+        with tm.span("fold", items=len(ys)):
+            for j, y in zip(js, ys):
+                st.hist_d.append(cand_d[j])
+                st.hist_y.append(y)
+                if use_f0:
+                    st.X0.append(cand_x[j])
+                    st.Y0.append(y)
+                    self._record(cand_x[j], cand_d[j], y)
+                else:
+                    st.X1.append(cand_x[j])
+                    st.Y1.append(y)
         st.done += len(js)
 
     def _mobo_step(self):
@@ -490,10 +517,11 @@ class ExplorationLoop:
         else:
             js = _acquire_batch(models, cand_x, ev, self.ref, q=q_eff)
             ys = self._eval(self.f0, [cand_d[j] for j in js], "f0")
-        for j, y in zip(js, ys):
-            st.X0.append(cand_x[j])
-            st.Y0.append(y)
-            self._record(cand_x[j], cand_d[j], y)
+        with tm.span("fold", items=len(ys)):
+            for j, y in zip(js, ys):
+                st.X0.append(cand_x[j])
+                st.Y0.append(y)
+                self._record(cand_x[j], cand_d[j], y)
         st.done += len(js)
 
     # -- async strategy bodies: propose with fantasized inflight batches,
@@ -561,8 +589,9 @@ class ExplorationLoop:
         batch = st.pending[:max(cfg.q, 1)]
         st.pending = st.pending[len(batch):]
         ys = self._eval(self.f0, [d for _, d in batch], "f0")
-        for (x, d), y in zip(batch, ys):
-            self._record(x, d, y)
+        with tm.span("fold", items=len(ys)):
+            for (x, d), y in zip(batch, ys):
+                self._record(x, d, y)
         st.done += len(batch)
 
     # -- checkpointing -----------------------------------------------------
@@ -609,6 +638,8 @@ class ExplorationLoop:
             st.inflight = []
         if not hasattr(st, "dispatch_seq"):
             st.dispatch_seq = 0
+        if not hasattr(st, "telemetry"):     # written before telemetry
+            st.telemetry = tm.Telemetry()
         return (LoopConfig(**blob["cfg"]), st, blob.get("extra", {}))
 
     @staticmethod
