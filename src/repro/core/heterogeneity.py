@@ -21,13 +21,18 @@ throughputs.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import components as C
 from repro.core.design_space import WSCDesign
-from repro.core.evaluator import Fidelity, evaluate_design, get_backend
+from repro.core.evaluator import (
+    Fidelity,
+    evaluate_design,
+    evaluate_design_batch,
+    get_backend,
+)
 from repro.core.serving import (
     RequestMix,
     ServingSLO,
@@ -220,6 +225,62 @@ def evaluate_hetero_serving(design_prefill: WSCDesign,
         granularity=granularity)
 
 
+#: Strategy cap of the disaggregated trace path's stage evaluations: the
+#: scalar `evaluate_design` default, so the stages score as they do on the
+#: scalar path. The campaign's `max_strategies` does not reach them
+#: (DESIGN.md §14).
+TRACE_STAGE_MAX_STRATEGIES = 24
+
+
+def evaluate_hetero_trace_serving_batch(
+        designs_prefill: Sequence[WSCDesign],
+        designs_decode: Sequence[WSCDesign], wl_base: LLMWorkload,
+        granularity: str, prefill_ratio: float, trace, slots: int = 8,
+        window_steps: int = 64, n_wafers: Optional[int] = None,
+        fidelity: Fidelity = "analytical",
+        gnn_params: Optional[Dict] = None) -> List:
+    """Timed-arrival, multi-tenant counterpart of `evaluate_hetero_serving`
+    for N (prefill design, decode design) pairs: the "disaggregated"
+    routing policy of a trace-serving campaign (DESIGN.md §14).
+
+    Stage scoring is batched: one `evaluate_design_batch` call scores
+    every prefill stage on the trace's prefill workload, one every decode
+    stage on its decode workload, at `TRACE_STAGE_MAX_STRATEGIES` (the
+    scalar path's cap) and with the resource split of
+    `evaluate_hetero_serving`. The coupled request model then runs per
+    pair (`_trace_coupled`): prompts prefill on their own stage in
+    priority-then-arrival order as they *arrive*, KV ships across the
+    stage boundary, and the decode pool admits by priority once the KV
+    lands (`traces.trace_disaggregated_metrics`). Returns
+    `traces.TraceServingResult`s in input order, so disaggregated points
+    score in the same frame as the shared-pool policies."""
+    from repro.core.traces import trace_serving_workloads
+
+    designs_prefill = list(designs_prefill)
+    designs_decode = list(designs_decode)
+    if len(designs_prefill) != len(designs_decode):
+        raise ValueError("one decode design per prefill design")
+    if not designs_prefill:
+        return []
+    fidelity = get_backend(fidelity)
+    wl_p, wl_d, p_ref = trace_serving_workloads(wl_base, trace, slots)
+
+    if granularity == "wafer":
+        nw_p, nw_d = wafer_split(n_wafers if n_wafers is not None else 2,
+                                 prefill_ratio)
+        scale_p = scale_d = 1.0
+    else:
+        nw_p = nw_d = n_wafers
+        scale_p, scale_d = prefill_ratio, 1.0 - prefill_ratio
+    kw = dict(fidelity=fidelity, gnn_params=gnn_params,
+              max_strategies=TRACE_STAGE_MAX_STRATEGIES)
+    rps = evaluate_design_batch(designs_prefill, wl_p, n_wafers=nw_p, **kw)
+    rds = evaluate_design_batch(designs_decode, wl_d, n_wafers=nw_d, **kw)
+    return [_trace_coupled(rp, rd, dd, wl_base, granularity, scale_p,
+                           scale_d, trace, slots, window_steps, p_ref)
+            for rp, rd, dd in zip(rps, rds, designs_decode)]
+
+
 def evaluate_hetero_trace_serving(design_prefill: WSCDesign,
                                   design_decode: WSCDesign,
                                   wl_base: LLMWorkload, granularity: str,
@@ -228,41 +289,28 @@ def evaluate_hetero_trace_serving(design_prefill: WSCDesign,
                                   n_wafers: Optional[int] = None,
                                   fidelity: Fidelity = "analytical",
                                   gnn_params: Optional[Dict] = None):
-    """Timed-arrival, multi-tenant counterpart of `evaluate_hetero_serving`:
-    the "disaggregated" routing policy of a trace-serving campaign
-    (DESIGN.md §14). Stage evaluation and the resource split are identical;
-    the coupled request model is `traces.trace_disaggregated_metrics` —
-    prompts prefill on their own stage in priority-then-arrival order as
-    they *arrive*, KV ships across the stage boundary, and the decode pool
-    admits by priority once the KV lands. Returns a
-    `traces.TraceServingResult` so disaggregated points score in the same
-    frame as the shared-pool policies."""
+    """One (prefill, decode) pair through
+    `evaluate_hetero_trace_serving_batch`: its stages are scored by the
+    batched evaluator at `TRACE_STAGE_MAX_STRATEGIES`."""
+    return evaluate_hetero_trace_serving_batch(
+        [design_prefill], [design_decode], wl_base, granularity,
+        prefill_ratio, trace, slots=slots, window_steps=window_steps,
+        n_wafers=n_wafers, fidelity=fidelity, gnn_params=gnn_params)[0]
+
+
+def _trace_coupled(rp, rd, design_decode: WSCDesign, wl_base: LLMWorkload,
+                   granularity: str, scale_p: float, scale_d: float, trace,
+                   slots: int, window_steps: int, p_ref: int):
+    """The coupled request model of one disaggregated pair, from its two
+    stage `EvalResult`s."""
     from repro.core.traces import (
         TraceServingResult,
+        _infeasible,
         _per_tenant,
         trace_disaggregated_metrics,
-        trace_serving_workloads,
     )
 
-    fidelity = get_backend(fidelity)
-    wl_p, wl_d, p_ref = trace_serving_workloads(wl_base, trace, slots)
-
-    if granularity == "wafer":
-        nw_p, nw_d = wafer_split(n_wafers if n_wafers is not None else 2,
-                                 prefill_ratio)
-        rp = evaluate_design(design_prefill, wl_p, fidelity, gnn_params,
-                             n_wafers=nw_p)
-        rd = evaluate_design(design_decode, wl_d, fidelity, gnn_params,
-                             n_wafers=nw_d)
-        scale_p = scale_d = 1.0
-    else:
-        rp = evaluate_design(design_prefill, wl_p, fidelity, gnn_params,
-                             n_wafers=n_wafers)
-        rd = evaluate_design(design_decode, wl_d, fidelity, gnn_params,
-                             n_wafers=n_wafers)
-        scale_p, scale_d = prefill_ratio, 1.0 - prefill_ratio
     if not (rp.feasible and rd.feasible):
-        from repro.core.traces import _infeasible
         return _infeasible("disaggregated", rd.n_wafers,
                            "prefill_infeasible" if not rp.feasible
                            else "decode_infeasible")

@@ -25,7 +25,8 @@ searchable object (DESIGN.md §14):
     the PR 4 decomposition. Admission/routing policies are explicit:
     FIFO, strict priority, preempt-batch-for-interactive, and
     prefill/decode-disaggregated routing (scored through
-    `heterogeneity.evaluate_hetero_trace_serving`'s coupled model).
+    `heterogeneity.evaluate_hetero_trace_serving_batch`'s coupled
+    model).
 
   * `evaluate_trace_serving_batch` — registry-batched per-step evals
     (prefill, decode) composed with the shared schedule into per-tenant
@@ -1031,9 +1032,13 @@ def evaluate_trace_serving_batch(
     `WSCDesign`s (scored under `policy`) or `PolicyDesign`s (each scored
     under its own policy — the searched axis). Pool policies share one
     design-independent `trace_schedule` per policy and broadcast
-    `trace_serving_metrics` over the candidate axis; "disaggregated"
-    routes through `heterogeneity.evaluate_hetero_trace_serving`'s coupled
-    prefill/decode-split model (reticle granularity, `prefill_ratio`)."""
+    `trace_serving_metrics` over the candidate axis. "Disaggregated"
+    candidates go, all in one call, through
+    `heterogeneity.evaluate_hetero_trace_serving_batch`'s coupled
+    prefill/decode-split model (reticle granularity, `prefill_ratio`):
+    their stages are scored by the batched evaluator at the scalar path's
+    cap of `TRACE_STAGE_MAX_STRATEGIES` (24), not at `max_strategies`,
+    which applies to the pool candidates. Results keep the input order."""
     from repro.core.fidelity import get_backend
 
     backend = get_backend(fidelity)
@@ -1055,17 +1060,20 @@ def evaluate_trace_serving_batch(
 
     results: List[Optional[TraceServingResult]] = [None] * len(designs)
 
-    # ---- disaggregated candidates: coupled split model, per design -----
+    # ---- disaggregated candidates: batched stages, coupled split model --
     dis = [i for i, p in enumerate(pols) if p == "disaggregated"]
     if dis:
-        from repro.core.heterogeneity import evaluate_hetero_trace_serving
-        for i in dis:
-            with tm.span("evaluate.trace.disaggregated", items=1):
-                results[i] = evaluate_hetero_trace_serving(
-                    raw[i], raw[i], wl_base, "reticle", prefill_ratio, trace,
-                    slots=slots, window_steps=window_steps,
-                    n_wafers=n_wafers, fidelity=backend,
-                    gnn_params=gnn_params)
+        from repro.core.heterogeneity import (
+            evaluate_hetero_trace_serving_batch,
+        )
+        with tm.span("evaluate.trace.disaggregated", items=len(dis)):
+            dis_designs = [raw[i] for i in dis]
+            rs = evaluate_hetero_trace_serving_batch(
+                dis_designs, dis_designs, wl_base, "reticle", prefill_ratio,
+                trace, slots=slots, window_steps=window_steps,
+                n_wafers=n_wafers, fidelity=backend, gnn_params=gnn_params)
+        for i, r in zip(dis, rs):
+            results[i] = r
 
     # ---- pool candidates: shared schedule per policy, broadcast math ---
     pool = [i for i, p in enumerate(pols) if p != "disaggregated"]

@@ -135,6 +135,37 @@ def test_telemetry_pickles():
     assert again.records[-1].id == 1          # ids go on after a reload
 
 
+def test_disaggregated_candidates_share_one_span():
+    """A trace-serving call with k disaggregated candidates opens one
+    `evaluate.trace.disaggregated` span with items k (no span a design);
+    the stage evaluations' spans nest under it, and their `.run` items
+    count the stage evaluations that missed the eval cache."""
+    from benchmarks.common import sample_valid_designs
+    from repro.core.traces import (
+        PolicyDesign,
+        evaluate_trace_serving_batch,
+        spike_trace,
+    )
+    from repro.core.workload import GPT_BENCHMARKS
+
+    designs = sample_valid_designs(3, seed=2)
+    cands = ([PolicyDesign(d, "disaggregated") for d in designs]
+             + [PolicyDesign(designs[0], "fifo")])
+    t = spike_trace(12, seed=1)
+    clear_eval_cache()
+    tel = tm.Telemetry()
+    with tm.activate(tel):
+        evaluate_trace_serving_batch(cands, GPT_BENCHMARKS[0], t, slots=4,
+                                     window_steps=16, max_strategies=6)
+    dis = [r for r in tel.records if r.name == "evaluate.trace.disaggregated"]
+    assert len(dis) == 1 and dis[0].items == len(designs)
+    runs = [r for r in tel.records if r.name == "evaluate.analytical.run"
+            and r.parent == dis[0].id]
+    assert len(runs) == 2                     # the prefill and decode stages
+    assert [r.items for r in runs] == [len(designs)] * 2
+    assert tel.summary()["evaluate.trace.pool"]["items"] == 1
+
+
 # ------------------------------- campaigns ---------------------------------
 
 

@@ -2,6 +2,7 @@
 generators, the event-skip scheduler vs its per-step reference, policy
 semantics, windowed goodput metrics, the searchable policy axis, and the
 ServeEngine cross-validation (ISSUE 10 satellites S1-S4)."""
+import dataclasses
 import json
 import time
 
@@ -270,6 +271,102 @@ def test_evaluate_trace_serving_batch_all_policies(small_pool):
                                          policy="priority",
                                          window_steps=16, max_strategies=8)
     assert plain[0].policy == "priority"
+
+
+def _hex(x):
+    """Every number of a result as `float.hex`, recursively."""
+    if dataclasses.is_dataclass(x):
+        return {f.name: _hex(getattr(x, f.name))
+                for f in dataclasses.fields(x)}
+    if isinstance(x, dict):
+        return {k: _hex(v) for k, v in x.items()}
+    if isinstance(x, (float, np.floating)):
+        return float(x).hex()
+    return x
+
+
+def _scalar_fed_disaggregated(d, wl, t, slots, window_steps, prefill_ratio,
+                              granularity="reticle", n_wafers=None):
+    """The coupled model fed by scalar `evaluate_design` stage results at
+    24 strategies: the path disaggregated designs took before their stages
+    were batched."""
+    from repro.core.evaluator import evaluate_design
+    from repro.core.heterogeneity import _trace_coupled, wafer_split
+    from repro.core.traces import trace_serving_workloads
+    wl_p, wl_d, p_ref = trace_serving_workloads(wl, t, slots)
+    if granularity == "wafer":
+        nw_p, nw_d = wafer_split(n_wafers, prefill_ratio)
+        scale_p = scale_d = 1.0
+    else:
+        nw_p = nw_d = n_wafers
+        scale_p, scale_d = prefill_ratio, 1.0 - prefill_ratio
+    rp = evaluate_design(d, wl_p, "analytical", n_wafers=nw_p,
+                         max_strategies=24)
+    rd = evaluate_design(d, wl_d, "analytical", n_wafers=nw_d,
+                         max_strategies=24)
+    return _trace_coupled(rp, rd, d, wl, granularity, scale_p, scale_d, t,
+                          slots, window_steps, p_ref)
+
+
+def test_disaggregated_batch_matches_scalar_stages_bitwise():
+    """Disaggregated candidates scored in one batched call equal, bit for
+    bit, the coupled model fed by the scalar per-design stage evaluations;
+    in a batch mixed with pool candidates the order of results is kept, and
+    an infeasible stage keeps its reason."""
+    from benchmarks.common import sample_valid_designs
+    from repro.core.evaluator import clear_eval_cache
+    wl = GPT_BENCHMARKS[7]
+    t = spike_trace(20, tenants=TWO_TENANTS, shares=(0.5, 0.5), seed=3)
+    designs = sample_valid_designs(8, seed=11)
+    # far over a wafer's power budget at prefill: an infeasible stage
+    hot = dataclasses.replace(designs[5], mac_num=4096, core_array=(16, 16),
+                              reticle_array=(20, 20))
+    dis = designs + [hot]
+    pools = [PolicyDesign(d, p) for d, p in zip(designs[:3], POOL_POLICIES)]
+    cands = ([pools[0]] + [PolicyDesign(d, "disaggregated") for d in dis[:5]]
+             + pools[1:] + [PolicyDesign(d, "disaggregated")
+                            for d in dis[5:]])
+    kw = dict(slots=4, window_steps=16, prefill_ratio=0.5, max_strategies=8)
+
+    clear_eval_cache()
+    got = evaluate_trace_serving_batch(cands, wl, t, **kw)
+    clear_eval_cache()
+    want_dis = [_scalar_fed_disaggregated(d, wl, t, 4, 16, 0.5) for d in dis]
+    clear_eval_cache()
+    want_pool = [evaluate_trace_serving_batch([c], wl, t, **kw)[0]
+                 for c in pools]
+
+    assert [r.policy for r in got] == [c.policy for c in cands]
+    got_dis = [r for r in got if r.policy == "disaggregated"]
+    got_pool = [r for r in got if r.policy != "disaggregated"]
+    assert [_hex(r) for r in got_dis] == [_hex(r) for r in want_dis]
+    assert [_hex(r) for r in got_pool] == [_hex(r) for r in want_pool]
+    assert sum(r.feasible for r in got_dis) >= 8
+    assert not got_dis[-1].feasible
+    assert got_dis[-1].reason in ("prefill_infeasible", "decode_infeasible")
+    assert got_dis[-1].reason == want_dis[-1].reason
+
+
+@pytest.mark.parametrize("granularity", ["core", "wafer"])
+def test_hetero_trace_serving_granularities_match_scalar_stages(granularity):
+    """The scalar `evaluate_hetero_trace_serving`, a batch of one, equals
+    the scalar-fed coupled model bit for bit at the granularities the
+    trace-serving batch does not use (wafer: each stage on its own share
+    of whole wafers)."""
+    from benchmarks.common import sample_valid_designs
+    from repro.core.evaluator import clear_eval_cache
+    from repro.core.heterogeneity import evaluate_hetero_trace_serving
+    wl = GPT_BENCHMARKS[0]
+    t = spike_trace(12, tenants=TWO_TENANTS, shares=(0.5, 0.5), seed=1)
+    for d in sample_valid_designs(2, seed=4):
+        clear_eval_cache()
+        got = evaluate_hetero_trace_serving(d, d, wl, granularity, 0.25, t,
+                                            slots=4, window_steps=16,
+                                            n_wafers=4)
+        clear_eval_cache()
+        want = _scalar_fed_disaggregated(d, wl, t, 4, 16, 0.25,
+                                         granularity, n_wafers=4)
+        assert got.feasible and _hex(got) == _hex(want)
 
 
 def test_sample_policy_candidates_axis():
