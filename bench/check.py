@@ -36,6 +36,16 @@ precision lower, in the program's place: objective pairs rounded to
 float32 for the float64 paths; the GNN in bfloat16 (weights, features,
 arithmetic); the GP pair and the EHVI scores in bfloat16 arithmetic, whose
 first choice at each pick is read by the float32 reference.
+
+The reference is the one the configuration names (`bench.reference
+.for_config`): the `bench/reference/` package by default, or the module
+`bench/reference/<name>.py` that a configuration file's `"reference":
+"<name>"` key brings (exporting `WORKLOAD_KEYS`, `workload`,
+`train_objectives` and `trace_objectives`; it may import the frozen modules
+beside it and changes none of them). Every number is read through it. The
+GNN control lowers `bench.reference.noc_gnn.DTYPE`, so a per-configuration
+module with a GNN path must score it through that module, or the control
+would not bite.
 """
 from __future__ import annotations
 
@@ -134,10 +144,11 @@ def readings(run, control: bool = False) -> Dict[str, float]:
     import jax
     import jax.numpy as jnp
 
-    from bench import reference as R
+    from bench import reference
     from bench.reference import noc_gnn as ref_gnn
 
     spec, config = run.traffic["spec"], run.config
+    R = reference.for_config(config)
     scenario = spec["scenario"]
     out: Dict[str, float] = {}
     ana: Tuple[List, List] = ([], [])

@@ -9,11 +9,23 @@ and take nothing it made except the question itself: the designs a campaign
 evaluated, and for the GNN fidelity the parameters it evaluated them with.
 The workload comes from the configuration file, the trace from the traffic
 file.
+
+This package is the default reference, for a configuration whose layers
+are the one uniform layer `workload.LLMWorkload` models. A configuration
+whose layers differ brings its own: its file carries `"reference":
+"<name>"`, and a new module `bench/reference/<name>.py` exports the four
+names this package exports (`WORKLOAD_KEYS`, `workload(config)`,
+`train_objectives(...)`, `trace_objectives(...)`) with the same signatures
+and return shapes. It may import the frozen modules beside it and replace
+what its layers change, and changes none of them; like them, it imports
+nothing of the program. `for_config` finds it.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import math
+import sys
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
@@ -33,6 +45,36 @@ PENALTY = (0.0, C.WAFER_POWER_W)
 WORKLOAD_KEYS = ("n_layers", "d_model", "n_heads", "n_kv", "d_ff", "vocab",
                  "seq", "batch", "phase", "moe_experts", "moe_topk",
                  "gpu_budget")
+EXPORTS = ("WORKLOAD_KEYS", "workload", "train_objectives",
+           "trace_objectives")
+
+
+def for_config(config: Mapping):
+    """The reference a configuration is compared with: the module
+    `bench.reference.<name>` where its file carries `"reference": "<name>"`,
+    this package otherwise. A name that is not a plain module name under
+    `bench/reference/` exporting `EXPORTS` raises; there is no fall-back to
+    this package."""
+    name = config.get("reference")
+    if name is None:
+        return sys.modules[__name__]
+    if not (isinstance(name, str) and name.isidentifier()
+            and name.isascii()):
+        raise ValueError(f"reference {name!r}: not a plain module name "
+                         f"under bench/reference/")
+    full = f"{__name__}.{name}"
+    try:
+        mod = importlib.import_module(full)
+    except ModuleNotFoundError as e:
+        if e.name != full:
+            raise
+        raise ValueError(f"reference {name!r}: no module "
+                         f"bench/reference/{name}.py") from None
+    missing = [k for k in EXPORTS if not hasattr(mod, k)]
+    if missing:
+        raise ValueError(f"reference {name!r}: bench/reference/{name}.py "
+                         f"does not export {missing}")
+    return mod
 
 
 def workload(config: Mapping) -> LLMWorkload:

@@ -9,7 +9,14 @@ A cell (`BENCHMARK.json` `workloads`) names a configuration
 traffic mix (`bench/traffic/<traffic>.json`: a campaign spec without its
 workload and seed, the pool of campaign seeds the window runs, and the
 seeds of the warm-up campaigns). Per-layer metrics are read by
-`bench/metrics/<metric>.py`, found by the metric's name.
+`bench/metrics/<metric>.py`, found by the metric's name. The plain
+reference that decides `correct` is `bench/reference/` unless the
+configuration file names its own with `"reference": "<name>"`: a new
+module `bench/reference/<name>.py` exporting `WORKLOAD_KEYS`, `workload`,
+`train_objectives` and `trace_objectives`, which may import the frozen
+modules beside it and changes none of them (`bench.reference.for_config`).
+Set-up holds the program's workload to the configuration file on every
+key of that reference's `WORKLOAD_KEYS`.
 
 Set-up (`setup_s`): imports and the device, the compile cache, the GNN
 weights made on the device from `--seed` (cells with a GNN fidelity), and
@@ -118,13 +125,20 @@ def campaign_spec(traffic, config, seed: int):
 
 
 def check_widths(spec, config) -> None:
-    """The program's workload must be the configuration file's job."""
+    """The program's workload must be the configuration file's job, on
+    every key its reference declares (`WORKLOAD_KEYS` of
+    `bench.reference.for_config(config)`)."""
     from repro.explore.campaign import resolve_workload
 
-    from bench.reference import WORKLOAD_KEYS
+    from bench.reference import for_config
+    keys = for_config(config).WORKLOAD_KEYS
     wl = resolve_workload(spec)
-    got = {k: getattr(wl, k) for k in WORKLOAD_KEYS}
-    want = {k: config[k] for k in WORKLOAD_KEYS}
+    lacking = [k for k in keys if not hasattr(wl, k)]
+    if lacking:
+        raise SystemExit(f"the program's workload {spec.workload} has no "
+                         f"{lacking}, which its reference declares")
+    got = {k: getattr(wl, k) for k in keys}
+    want = {k: config.get(k) for k in keys}
     if got != want:
         raise SystemExit(f"the program resolves {spec.workload} to {got}, "
                          f"the configuration file says {want}")

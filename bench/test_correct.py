@@ -19,16 +19,16 @@ import pytest
 from bench import check
 from bench import run_cell as rc
 
-CELLS = ("gpt175b-train-analytical", "gpt1.7b-train-gnn",
-         "gpt175b-serve-trace")
 #: cells whose files are in bench/ but which BENCHMARK.json leaves out
 #: (PERF.md, Open questions); their check is tested all the same
 SPARE = {"gpt1.7b-train-gnn": {"config": "gpt-1.7b",
                                "traffic": "train-gnn-calibrated"}}
+#: every cell of BENCHMARK.json, then the spare ones
+CELLS = tuple(c["name"] for c in json.loads(
+    (rc.ROOT / "BENCHMARK.json").read_text())["workloads"]) + tuple(SPARE)
 
 
-@pytest.fixture
-def small(monkeypatch):
+def shrink(monkeypatch):
     """The harness without its look for a chip, each pool cut to two
     campaigns and one warm-up campaign."""
     import jax
@@ -51,6 +51,11 @@ def small(monkeypatch):
     monkeypatch.setattr(rc, "load_cell", load_small)
     monkeypatch.setattr(rc, "require_accelerator",
                         lambda chips: jax.devices()[:chips])
+
+
+@pytest.fixture
+def small(monkeypatch):
+    shrink(monkeypatch)
 
 
 def one_round(name: str, seed: int = 5):
