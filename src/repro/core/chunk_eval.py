@@ -10,6 +10,7 @@ scalar `evaluate_step` delegates to it with a length-1 batch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional
 
 import numpy as np
@@ -60,6 +61,15 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
     Recompute re-runs the forward in the backward pass (bwd 3x -> 4x,
     training only); ep shards the expert weights and adds per-layer
     dispatch/combine all-to-all over the inter-reticle fabric.
+
+    A workload with a table of layer kinds (`LLMWorkload.uniform` false)
+    passes chunk_latency_cycles, sram_bits_layer and noc_bytes_layer per
+    kind, as (n_kinds, C). Its pipeline stages are the balanced contiguous
+    split of `LLMWorkload.stage_kind_counts`, and the per-microbatch
+    stage time is the slowest stage's sum of per-layer terms: compute, the
+    two TP collectives, and the ep all-to-all on its MoE layers only
+    (compute_s / tp_s / ep_s report that stage). A uniform workload keeps
+    `n_layers // pp` layers a stage, and its bits.
     """
     tp = np.asarray(tp, np.int64)
     pp = np.asarray(pp, np.int64)
@@ -75,21 +85,40 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
     ep_arr = None if ep is None else np.maximum(np.asarray(ep, np.int64), 1)
     mb_count = mb if train else np.ones_like(mb)
     mb_tokens = np.maximum(wl.tokens_per_step() // (dp * mb_count), 1)
+    kinds = None if wl.uniform else wl.layer_kinds()
     layers_per_stage = np.maximum(wl.n_layers // pp, 1)
     chunks = pp * dp
     act_bytes = (mb_tokens * wl.d_model).astype(np.float64) * BYTES
     p_bytes = wl.params_bytes()
 
     # --- per-microbatch stage time -----------------------------------------
-    compute_s = lat * layers_per_stage / C.CLOCK_HZ * bwd_mult
-
     # TP all-reduce: 2 collectives per layer over the TP group (Megatron)
     cores_per_chunk = geom.total_cores * nw // np.maximum(chunks, 1)
     tp_vol = 2.0 * (tp - 1) / tp * act_bytes * 2.0
     tp_bw = np.where(cores_per_chunk <= geom.cores_per_reticle,
                      geom.reticle_bisection_Bps, geom.inter_reticle_bw_Bps)
-    tp_s = np.where(tp <= 1, 0.0, tp_vol / np.maximum(tp_bw, 1.0)) \
-        * layers_per_stage * bwd_mult
+    a2a_vol = None
+    if ep_arr is not None:
+        # MoE dispatch+combine all-to-all per layer (fwd, x2 directions,
+        # top-k routed copies), over the inter-reticle fabric
+        topk = max(wl.moe_topk, 1)
+        a2a_vol = np.where(ep_arr > 1,
+                           4.0 * (ep_arr - 1) / ep_arr * act_bytes * topk,
+                           0.0)
+    if kinds is None:
+        compute_s = lat * layers_per_stage / C.CLOCK_HZ * bwd_mult
+        tp_s = np.where(tp <= 1, 0.0, tp_vol / np.maximum(tp_bw, 1.0)) \
+            * layers_per_stage * bwd_mult
+    else:
+        tp_layer = np.where(tp <= 1, 0.0,
+                            tp_vol / np.maximum(tp_bw, 1.0)) * bwd_mult
+        a2a_layer = (np.zeros_like(tp_layer) if a2a_vol is None else
+                     a2a_vol / np.maximum(geom.inter_reticle_bw_Bps, 1.0)
+                     * bwd_mult)
+        compute_s, tp_s, ep_s, slow_s = slowest_stage(
+            wl, pp, [lat[i] / C.CLOCK_HZ * bwd_mult
+                     for i in range(len(kinds))],
+            tp_layer, a2a_layer, np)
 
     pp_s = np.where(
         pp <= 1, 0.0,
@@ -100,7 +129,8 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
                       / np.maximum(chunks, 1))
     w_bytes = p_bytes / np.maximum(pp, 1)
     if ep_arr is not None:
-        # expert weights shard over the ep group (dense slice replicated)
+        # expert weights shard over the ep group (dense slice replicated);
+        # a chunk holds 1/ep of them while ep <= dp (the grid's bound)
         p_exp = wl.expert_params_bytes()
         w_bytes = np.where(ep_arr > 1,
                            ((p_bytes - p_exp) + p_exp / ep_arr)
@@ -133,19 +163,15 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
     dram_s = np.where(dram_traffic <= 0, 0.0,
                       dram_traffic / np.maximum(dram_bw, 1.0))
 
-    stage_s = compute_s + tp_s + pp_s + dram_s
-    a2a_vol = None
-    ep_s = np.zeros_like(stage_s)
-    if ep_arr is not None:
-        # MoE dispatch+combine all-to-all per layer (fwd, x2 directions,
-        # top-k routed copies), over the inter-reticle fabric
-        topk = max(wl.moe_topk, 1)
-        a2a_vol = np.where(ep_arr > 1,
-                           4.0 * (ep_arr - 1) / ep_arr * act_bytes * topk,
-                           0.0)
-        ep_s = (a2a_vol / np.maximum(geom.inter_reticle_bw_Bps, 1.0)
-                * layers_per_stage * bwd_mult)
-        stage_s = stage_s + ep_s
+    if kinds is not None:
+        stage_s = slow_s + pp_s + dram_s
+    else:
+        stage_s = compute_s + tp_s + pp_s + dram_s
+        ep_s = np.zeros_like(stage_s)
+        if a2a_vol is not None:
+            ep_s = (a2a_vol / np.maximum(geom.inter_reticle_bw_Bps, 1.0)
+                    * layers_per_stage * bwd_mult)
+            stage_s = stage_s + ep_s
 
     # --- pipeline + step ----------------------------------------------------
     eff = mb_count / (mb_count + pp - 1.0)
@@ -166,15 +192,28 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
     # --- energy (action accounting, §VI-E) ----------------------------------
     E = C.ENERGY
     e_mac = wl.flops_per_step() / 2.0 * E.mac * 1e-12
-    e_sram = (np.asarray(sram_bits_layer, np.float64) * wl.n_layers
+    n_layers, n_moe = wl.n_layers, wl.n_layers
+    sram_bits_layer = np.asarray(sram_bits_layer, np.float64)
+    noc_bytes_layer = np.asarray(noc_bytes_layer, np.float64)
+    if kinds is not None:
+        # whole-model SRAM bits and NoC byte-hops: kinds weighted by count
+        # ("a layer" of the uniform expressions below is then the model)
+        n_layers = n_moe = 1
+        counts = [float(k.count) for k in kinds]
+        sram_bits_layer = kind_total(sram_bits_layer, counts)
+        noc_bytes_layer = kind_total(noc_bytes_layer, counts)
+    e_sram = (sram_bits_layer * n_layers
               * mb_count * dp * bwd_mult * E.sram_read_bit * 1e-12)
-    e_noc = (np.asarray(noc_bytes_layer, np.float64) * 8 * wl.n_layers
+    e_noc = (noc_bytes_layer * 8 * n_layers
              * mb_count * dp * bwd_mult * E.noc_bit_hop * 1e-12)
+    if kinds is not None:
+        n_layers = sum(k.count for k in kinds)
+        n_moe = sum(k.count for k in kinds if k.moe)
     ir_bytes = (2.0 * (tp - 1) / np.maximum(tp, 1) * mb_tokens * wl.d_model
-                * BYTES * 2 * wl.n_layers * mb_count * dp * bwd_mult)
+                * BYTES * 2 * n_layers * mb_count * dp * bwd_mult)
     ir_bytes = ir_bytes + p_bytes * 2 * (dp > 1)
     if a2a_vol is not None:
-        ir_bytes = ir_bytes + a2a_vol * wl.n_layers * mb_count * dp
+        ir_bytes = ir_bytes + a2a_vol * n_moe * mb_count * dp
     e_ir = ir_bytes * 8 * geom.ir_energy_pj_per_bit * 1e-12
     # DRAM energy charges the same per-step traffic as the latency term
     # above (SRAM pool sized per system — nw wafers — plus KV streaming).
@@ -212,6 +251,71 @@ def evaluate_step_batch(geom: DesignBatch, wl: LLMWorkload,
         "dram_s": dram_s, "dp_s": dp_s, "ep_s": ep_s,
         "mb_count": mb_count,
     }
+
+
+def kind_total(per_kind, counts, fp=lambda x: x):
+    """Sum over the kinds of the kind's per-layer value x its layer count,
+    in table order (`fp` as in `slowest_stage`)."""
+    tot = None
+    for v, n in zip(per_kind, counts):
+        t = fp(v * n)
+        tot = t if tot is None else tot + t
+    return tot
+
+
+@functools.lru_cache(maxsize=16)
+def stage_count_table(wl: LLMWorkload) -> np.ndarray:
+    """(n_layers + 1, n_layers, n_kinds) float64: row pp holds
+    `stage_kind_counts(pp)` in its first pp stages and zeros after them
+    (a stage with no layer adds nothing to the slowest-stage maximum)."""
+    L = wl.n_layers
+    n = len(wl.layer_kinds())
+    tab = np.zeros((L + 1, max(L, 1), n), np.float64)
+    for pp in range(1, L + 1):
+        tab[pp, :pp] = wl.stage_kind_counts(pp)
+    tab.flags.writeable = False          # shared by every caller (cache)
+    return tab
+
+
+def slowest_stage(wl: LLMWorkload, pp, compute_layer, tp_layer, a2a_layer,
+                  xp, fp=lambda x: x):
+    """The slowest pipeline stage of a table of layer kinds, per candidate.
+
+    `compute_layer` is one array a kind (seconds of one layer of that
+    kind); `tp_layer` and `a2a_layer` the seconds of one layer's two TP
+    collectives and of one MoE layer's all-to-all (0 for dense kinds).
+    A stage's time is its layers' sum of the three terms, kind by kind in
+    table order; returns (compute_s, tp_s, ep_s, stage_s) of the slowest
+    stage (the first one on a tie). `xp` is the array module; `fp` pins
+    a product's rounding where the compiled program would fuse it
+    (eval_compiled)."""
+    kinds = wl.layer_kinds()
+    cnt = xp.asarray(stage_count_table(wl))[xp.minimum(pp, wl.n_layers)]
+    per = []
+    for i, k in enumerate(kinds):
+        u = fp(compute_layer[i]) + fp(tp_layer)
+        if k.moe:
+            u = u + fp(a2a_layer)
+        per.append(u)
+    tot = None
+    for i in range(len(kinds)):
+        t = fp(cnt[..., i] * per[i][..., None])
+        tot = t if tot is None else tot + t
+    s = xp.argmax(tot, axis=-1)[..., None]
+    cs = xp.take_along_axis(cnt, s[..., None], axis=-2)[..., 0, :]
+    compute_s = tp_n = ep_s = None
+    for i, k in enumerate(kinds):
+        c = fp(cs[..., i] * compute_layer[i])
+        compute_s = c if compute_s is None else compute_s + c
+        tp_n = cs[..., i] if tp_n is None else tp_n + cs[..., i]
+        if k.moe:
+            e = fp(cs[..., i] * a2a_layer)
+            ep_s = e if ep_s is None else ep_s + e
+    tp_s = tp_n * tp_layer
+    if ep_s is None:
+        ep_s = tp_s * 0.0
+    stage_s = xp.take_along_axis(tot, s, axis=-1)[..., 0]
+    return compute_s, tp_s, ep_s, stage_s
 
 
 # NumPy oracle alias for the jitted pipeline (repro.core.eval_compiled)

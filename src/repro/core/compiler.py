@@ -417,8 +417,6 @@ def enumerate_strategies(design: WSCDesign, wl: LLMWorkload,
     sram_total = design.buffer_kb * 1024.0 * total_cores
     dram_total = design.dram_gb_per_reticle() * 1e9 * design.n_reticles() * n_wafers
     mem_budget = sram_total + dram_total
-    p_bytes = wl.params_bytes()
-    opt_mult = 6.0 if wl.phase == "train" else 1.0   # weights+grads+adam
     out: List[Strategy] = []
     pows = [2 ** i for i in range(0, 17)]
     for pp in [p for p in pows if p <= wl.n_layers]:
@@ -432,23 +430,51 @@ def enumerate_strategies(design: WSCDesign, wl: LLMWorkload,
                         continue
                     if wl.batch % (dp * (mb if wl.phase == "train" else 1)):
                         continue
-                    if memory_model == "v2":
-                        need = float(strategy_memory_need(wl, tp, pp, dp, mb))
-                    else:
-                        # frozen legacy check (see _strategy_grid)
-                        need = dp * p_bytes * opt_mult / max(pp, 1)
-                        if wl.phase != "train":
-                            need = dp * p_bytes / max(pp, 1)
-                            need += wl.kv_bytes_per_layer() * wl.n_layers
-                    if need > mem_budget:
-                        continue
-                    out.append(Strategy(tp, pp, dp, mb))
+                    for ep in _ep_values(wl, dp):
+                        if memory_model == "v2":
+                            need = float(strategy_memory_need(
+                                wl, tp, pp, dp, mb, ep=ep))
+                        else:
+                            need = _grid_need(wl, pp, dp, ep)
+                        if need > mem_budget:
+                            continue
+                        out.append(Strategy(tp, pp, dp, mb, ep=ep))
     return out or [Strategy(1, 1, 1, 1)]
 
 
 def strategy_sort_key(s: Strategy) -> Tuple:
-    """Search-order heuristic: prefer modest TP, deep pipelines last."""
+    """Search-order heuristic: prefer modest TP, deep pipelines last.
+    Ties keep the enumeration order (pp, dp, tp, mb, ep), so a strategy's
+    expert-parallel rows follow its ep = 1 row (`_strategy_grid`)."""
     return (abs(math.log2(max(s.tp, 1)) - 5), s.pp, -s.microbatches)
+
+
+def _ep_values(wl: LLMWorkload, dp: int) -> List[int]:
+    """The grid's expert-parallel degrees at `dp`: 1 for a dense
+    workload; for routed experts the powers of two up to `moe_experts`
+    with ep <= dp. The EP group is drawn from a stage's DP replicas, as
+    in DeepSeek-V3's training (arXiv:2412.19437 §3.2, which runs no TP):
+    a chunk is one (pp, dp) replica and holds all of its TP ranks, so it
+    holds 1/ep of the stage's routed experts only while ep <= dp (its TP
+    ranks share one copy between them, so TP does not widen the group)."""
+    eps = [1]
+    while wl.moe_experts and eps[-1] * 2 <= min(wl.moe_experts, dp):
+        eps.append(eps[-1] * 2)
+    return eps
+
+
+def _grid_need(wl: LLMWorkload, pp: int, dp: int, ep: int) -> float:
+    """The grid's frozen memory column (the weights-only check), with the
+    routed-expert bytes divided by `ep`: ep = 1 is the historical
+    formula, bit for bit."""
+    p_bytes = wl.params_bytes()
+    if ep > 1:
+        p_exp = wl.expert_params_bytes()
+        p_bytes = (p_bytes - p_exp) + p_exp / ep
+    if wl.phase == "train":
+        return dp * p_bytes * 6.0 / max(pp, 1)
+    return (dp * p_bytes / max(pp, 1)
+            + wl.kv_bytes_per_layer() * wl.n_layers)
 
 
 # --------------------------------------------------------------------------
@@ -461,15 +487,26 @@ _STRATEGY_GRID_CACHE: Dict[Tuple, Dict[str, np.ndarray]] = {}
 
 
 def _strategy_grid(wl) -> Dict[str, np.ndarray]:
+    """The workload's strategy grid: columns tp, pp, dp, mb, the memory
+    column `need`, `chunks`, and `order`, the rows sorted by
+    `strategy_sort_key`. A workload with routed experts adds an `ep`
+    column: each (pp, dp, tp, mb) row is followed by its rows at ep = 2,
+    4, ... up to min(moe_experts, dp) (`_ep_values`), whose `need`
+    divides only the routed-expert bytes by ep. The sort is stable, so
+    the rule is: within a tie of the key, enumeration order, ep ascending
+    innermost. A strategy's expert-parallel rows sit right after its ep = 1
+    row, which needs the most memory of them; so the first
+    `max_strategies` feasible rows of a design hold ep > 1 rows whenever
+    any ep > 1 row of theirs fits. A dense workload's grid has no `ep`
+    column and is the historical grid, byte for byte."""
     key = (wl.n_layers, wl.batch, wl.phase, wl.params_bytes(),
-           wl.kv_bytes_per_layer())
+           wl.kv_bytes_per_layer(), wl.moe_experts,
+           wl.expert_params_bytes())
     hit = _STRATEGY_GRID_CACHE.get(key)
     if hit is not None:
         return hit
-    p_bytes = wl.params_bytes()
-    opt_mult = 6.0 if wl.phase == "train" else 1.0
     pows = [2 ** i for i in range(0, 17)]
-    tps, pps, dps, mbs, needs = [], [], [], [], []
+    tps, pps, dps, mbs, eps, needs = [], [], [], [], [], []
     # Caps derive from the workload (pp <= n_layers, tp unbounded up to the
     # per-design core-count mask applied later); the memory column `need`
     # stays the frozen PR 2 formula — this grid is the grid-mode replay
@@ -479,18 +516,16 @@ def _strategy_grid(wl) -> Dict[str, np.ndarray]:
     for pp in [p for p in pows if p <= wl.n_layers]:
         for dp in [d for d in pows if d <= max(wl.batch, 1)]:
             for tp in pows:
-                if wl.phase == "train":
-                    need = dp * p_bytes * opt_mult / max(pp, 1)
-                else:
-                    need = (dp * p_bytes / max(pp, 1)
-                            + wl.kv_bytes_per_layer() * wl.n_layers)
+                ep_need = [(ep, _grid_need(wl, pp, dp, ep))
+                           for ep in _ep_values(wl, dp)]
                 for mb in (1, 2, 4, 8, 16, 32):
                     if wl.phase != "train" and mb > 1:
                         continue
                     if wl.batch % (dp * (mb if wl.phase == "train" else 1)):
                         continue
-                    tps.append(tp); pps.append(pp); dps.append(dp)
-                    mbs.append(mb); needs.append(need)
+                    for ep, need in ep_need:
+                        tps.append(tp); pps.append(pp); dps.append(dp)
+                        mbs.append(mb); eps.append(ep); needs.append(need)
     tp = np.array(tps, np.int64)
     pp = np.array(pps, np.int64)
     dp = np.array(dps, np.int64)
@@ -500,6 +535,8 @@ def _strategy_grid(wl) -> Dict[str, np.ndarray]:
     order = np.lexsort((-mb, pp, np.abs(np.log2(np.maximum(tp, 1)) - 5.0)))
     grid = {"tp": tp, "pp": pp, "dp": dp, "mb": mb, "need": need,
             "chunks": pp * dp, "order": order}
+    if wl.moe_experts:
+        grid["ep"] = np.array(eps, np.int64)
     if len(_STRATEGY_GRID_CACHE) > 64:
         _STRATEGY_GRID_CACHE.pop(next(iter(_STRATEGY_GRID_CACHE)))
     _STRATEGY_GRID_CACHE[key] = grid
@@ -508,18 +545,20 @@ def _strategy_grid(wl) -> Dict[str, np.ndarray]:
 
 def feasible_strategy_arrays(wl, total_cores: int, mem_budget: float,
                              max_strategies: int) -> np.ndarray:
-    """(k, 4) int64 array of [tp, pp, dp, microbatches], sorted by
+    """(k, 4) int64 array of [tp, pp, dp, microbatches] ((k, 5) with an
+    `ep` column for a workload with routed experts), sorted by
     `strategy_sort_key` and capped — element-wise identical to
-    sorted(enumerate_strategies(...), key=strategy_sort_key)[:cap], with the
-    same Strategy(1,1,1,1) fallback when nothing is feasible."""
+    sorted(enumerate_strategies(..., memory_model="grid"),
+    key=strategy_sort_key)[:cap], with the same Strategy(1,1,1,1)
+    fallback when nothing is feasible."""
     g = _strategy_grid(wl)
+    cols = ("tp", "pp", "dp", "mb") + (("ep",) if "ep" in g else ())
     mask = ((g["chunks"] * g["tp"] <= total_cores)
             & (g["tp"] <= total_cores) & (g["need"] <= mem_budget))
     idx = g["order"][mask[g["order"]]][:max_strategies]
     if len(idx) == 0:
-        return np.array([[1, 1, 1, 1]], np.int64)
-    return np.stack([g["tp"][idx], g["pp"][idx], g["dp"][idx],
-                     g["mb"][idx]], axis=1)
+        return np.ones((1, len(cols)), np.int64)
+    return np.stack([g[c][idx] for c in cols], axis=1)
 
 
 # NumPy oracle alias for the jitted strategy-grid selection

@@ -59,8 +59,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import telemetry as tm
 from repro.core import components as C
-from repro.core.chunk_eval import StepResult
+from repro.core.chunk_eval import StepResult, kind_total, slowest_stage
 from repro.core.compiler import Strategy, _strategy_grid
 from repro.core.design_space import DesignBatch
 from repro.core.workload import BYTES, LLMWorkload
@@ -195,6 +196,11 @@ class _EvalProgram:
         self._mb_o = np.concatenate([mb_o, np.full(pad, 1, np.int64)])
         self._need_o = np.concatenate([need_o, np.full(pad, np.inf)])
         self._chunks_o = np.concatenate([chunks_o, np.full(pad, big)])
+        # expert-parallel column (workloads with routed experts): grid
+        # mode then runs the `extras` path with recompute off
+        self._ep_o = (np.concatenate([g["ep"][order],
+                                      np.full(pad, 1, np.int64)])
+                      if "ep" in g else None)
         fb = np.flatnonzero((tp_o == 1) & (pp_o == 1) & (dp_o == 1)
                             & (mb_o == 1))
         self._fb_idx = int(fb[0])        # Strategy(1,1,1,1) always exists
@@ -207,8 +213,19 @@ class _EvalProgram:
         self._p_exp = wl.expert_params_bytes()
         self._kvtot_num = wl.kv_bytes_per_layer() * wl.n_layers
         self._e_mac = wl.flops_per_step() / 2.0 * C.ENERGY.mac * 1e-12
-        self._zc_np = tuple(np.float64(c) for c in (
-            0.0, self._bwd, C.CLOCK_HZ, wl.d_model, 1e-12, wl.n_layers))
+        zc = (0.0, self._bwd, C.CLOCK_HZ, wl.d_model, 1e-12, wl.n_layers)
+        self._kinds = None if wl.uniform else wl.layer_kinds()
+        if self._kinds is not None:
+            # a kind table scores the whole model as "one layer" in the
+            # energy terms (n_layers 1), then needs its MoE layer count
+            # and each kind's count as traced scalars
+            zc = zc[:5] + (1.0, sum(k.count for k in self._kinds if k.moe)) \
+                + tuple(k.count for k in self._kinds)
+        self._zc_np = tuple(np.float64(c) for c in zc)
+        #: design rows x strategy columns x layer kinds a call scores,
+        #: per design row
+        self.layer_cols = self.K * (1 if self._kinds is None
+                                    else len(self._kinds))
 
         self._jit = jax.jit(self._body)
         self._pfn = (jax.pmap(self._body, in_axes=(0, 0, None),
@@ -306,8 +323,12 @@ class _EvalProgram:
         sel = jnp.where(first, self._fb_idx, sel)
         selmask = selmask | first
 
+        extras = None
+        if self._ep_o is not None:
+            ep_sel = jnp.asarray(self._ep_o)[sel]
+            extras = (ep_sel, jnp.zeros(ep_sel.shape, bool))
         cand = self._eval_core(arrs, nw, zc, tp_o[sel], pp_o[sel],
-                               dp_o[sel], mb_o[sel], None)
+                               dp_o[sel], mb_o[sel], extras)
 
         # --- per-design winner (first max wins, like np.argmax) ----------
         live = cand["feasible"] & selmask
@@ -320,8 +341,9 @@ class _EvalProgram:
         out = {"any_feasible": live.any(axis=1), "sel_g": at(sel)}
         for k in ("throughput", "power_w", "step_time_s", "pipeline_eff",
                   "energy_j", "compute_s", "tp_s", "pp_s", "dram_s",
-                  "dp_s", "mb_count"):
-            out[k] = at(cand[k])
+                  "dp_s", "mb_count", "ep_s"):
+            if k in cand:
+                out[k] = at(cand[k])
         return out
 
     def _body_pinned(self, arrs, nw, zc, strat):
@@ -346,10 +368,12 @@ class _EvalProgram:
         is None (grid mode: byte-identical trace to the pre-refactor body)
         or (ep, recompute) columns, every extra term `where`-guarded so
         ep=1/recompute=False lanes keep the legacy bits (the same guard
-        discipline as `chunk_eval.evaluate_step_batch`)."""
+        discipline as `chunk_eval.evaluate_step_batch`). A table of layer
+        kinds runs the tile/NoC stage once a kind and the slowest-stage
+        step model (`chunk_eval.slowest_stage`)."""
         jnp = _jnp()
         wl = self.wl
-        z, bwd_t, clock_t, dmod_t, p12, nl_t = zc
+        z, bwd_t, clock_t, dmod_t, p12, nl_t = zc[:6]
 
         def fp(x):
             return x + z
@@ -371,23 +395,29 @@ class _EvalProgram:
         gh, gw = _grid_for_j(jnp.minimum(cores_per_chunk, 64))
         n_cores = gh * gw
 
-        # layer_ops_batch mirror: the 6 GEMMs of one layer under tp
-        D, F = wl.d_model, wl.d_ff
-        hd = D // max(wl.n_heads, 1)
-        e = wl.moe_topk if wl.moe_experts else 1
-        heads_tp = jnp.maximum(wl.n_heads // tp, 1)
-        M = mb_tokens
-        m_attn = M * heads_tp // max(wl.n_heads, 1)
-        kv_len = wl.seq
-        zi = jnp.zeros_like(M)           # int broadcast helper (NOT `z`)
-        ops = (
-            (M, zi + D, (wl.n_heads + 2 * wl.n_kv) * hd // tp),
-            (m_attn, zi + hd, zi + kv_len),
-            (m_attn, zi + kv_len, zi + hd),
-            (M, wl.n_heads * hd // tp, zi + D),
-            (M * e, zi + D, 2 * F // tp),
-            (M * e, F // tp, zi + D),
-        )
+        if self._kinds is None:
+            # layer_ops_batch mirror: the 6 GEMMs of one layer under tp
+            D, F = wl.d_model, wl.d_ff
+            hd = D // max(wl.n_heads, 1)
+            e = wl.moe_topk if wl.moe_experts else 1
+            heads_tp = jnp.maximum(wl.n_heads // tp, 1)
+            M = mb_tokens
+            m_attn = M * heads_tp // max(wl.n_heads, 1)
+            kv_len = wl.seq
+            zi = jnp.zeros_like(M)       # int broadcast helper (NOT `z`)
+            op_lists = [(
+                (M, zi + D, (wl.n_heads + 2 * wl.n_kv) * hd // tp),
+                (m_attn, zi + hd, zi + kv_len),
+                (m_attn, zi + kv_len, zi + hd),
+                (M, wl.n_heads * hd // tp, zi + D),
+                (M * e, zi + D, 2 * F // tp),
+                (M * e, F // tp, zi + D),
+            )]
+        else:
+            # one GEMM list a kind (the NumPy oracle's `kind_ops`)
+            op_lists = [tuple(o[1:4] for o in wl.kind_ops(k, tp, mb_tokens,
+                                                          xp=jnp))
+                        for k in self._kinds]
 
         # tile stage per op (evaluate_tile_batch mirror), accumulated in
         # the same sequential order as the NumPy axis-0 sums
@@ -406,17 +436,34 @@ class _EvalProgram:
         def sel3(a, b, c):
             return jnp.where(ws, a, jnp.where(os_, b, c))
 
+        bw_bytes = nbw.astype(jnp.float64) / 8.0
+        hop_fac = gh * (gw * (gw * gw - 1)) / 3.0
+        lats, srams, hops = [], [], []
+        for ops in op_lists:
+            lat_k, sram_k, hops_k = self._tile_noc(
+                ops, fp, gh_t, gw_t, gw, gh, n_cores, bw_bytes, hop_fac,
+                sel3, pr, pc, bbw, buf_bits)
+            lats.append(lat_k)
+            srams.append(sram_k)
+            hops.append(hops_k)
+        return self._step(arrs, nw, zc, fp, tp, pp, dp, mb, extras, chunks,
+                          mb_count, mb_tokens, lats, srams, hops)
+
+    def _tile_noc(self, ops, fp, gh_t, gw_t, gw, gh, n_cores, bw_bytes,
+                  hop_fac, sel3, pr, pc, bbw, buf_bits):
+        """One layer's chunk latency (cycles), SRAM bits and NoC byte-hops
+        over the GEMM chain `ops` ((M, K, N) a GEMM): the tile stage and
+        the row all-gather closed form between consecutive GEMMs."""
+        jnp = _jnp()
         cycles_sum = None
         sram_sum = None
         comm_sum = None
         hops_sum = None
 
         # NoC closed form shared terms (row_allgather_* mirrors)
-        bw_bytes = nbw.astype(jnp.float64) / 8.0
         n_transfers = len(ops) - 1
         maxflow = (jnp.float64(n_transfers) * (gw // 2) * ((gw + 1) // 2))
         eq_bw = bw_bytes / jnp.maximum(maxflow, 1.0)
-        hop_fac = gh * (gw * (gw * gw - 1)) / 3.0
 
         for oi, (Mo, Ko, No) in enumerate(ops):
             tM = jnp.maximum(jnp.maximum(Mo // gh_t, 1), 1)
@@ -451,7 +498,10 @@ class _EvalProgram:
             sram_sum = rw if sram_sum is None else sram_sum + rw
             if oi < n_transfers:         # producer feeds a transfer
                 out_b = (Mo * No).astype(jnp.float64) * BYTES
-                per_pair = out_b / n_cores
+                # fp() keeps XLA from folding the two divisions below into
+                # one, `a / b / c -> a / (b * c)`, which rounds otherwise
+                # where n_cores is not a power of two
+                per_pair = fp(out_b / n_cores)
                 comm = per_pair / jnp.maximum(eq_bw, 1e-9) + (gw - 1)
                 comm = jnp.where(gw > 1, comm, 0.0)
                 comm_sum = comm if comm_sum is None else comm_sum + comm
@@ -459,11 +509,19 @@ class _EvalProgram:
                 hop = fp(pph * hop_fac)
                 hops_sum = hop if hops_sum is None else hops_sum + hop
 
-        lat = cycles_sum + comm_sum
-        sram_bits_layer = sram_sum * n_cores
-        noc_bytes_layer = hops_sum
+        return cycles_sum + comm_sum, sram_sum * n_cores, hops_sum
 
-        # --- chunk-level step model (evaluate_step_batch mirror) ---------
+    def _step(self, arrs, nw, zc, fp, tp, pp, dp, mb, extras, chunks,
+              mb_count, mb_tokens, lats, srams, hops):
+        """The chunk-level step model (evaluate_step_batch mirror) over
+        (N, K) strategy columns, given each kind's per-layer latency,
+        SRAM bits and NoC byte-hops."""
+        jnp = _jnp()
+        wl = self.wl
+        z, bwd_t, clock_t, dmod_t, p12, nl_t = zc[:6]
+        kinds = self._kinds
+        buffer_kb = arrs["buffer_kb"]
+        total_cores = arrs["total_cores"].astype(jnp.int64)
         nw2 = nw[:, None]
         bwd = bwd_t
         ep2 = rc2 = None
@@ -473,19 +531,39 @@ class _EvalProgram:
             if self._train:
                 # recompute re-runs the forward in the backward: 3x -> 4x
                 bwd = jnp.where(rc2, jnp.float64(4.0), bwd_t)
-        layers_per_stage = jnp.maximum(wl.n_layers // pp, 1)
         act_bytes = (mb_tokens * wl.d_model).astype(jnp.float64) * BYTES
         p_bytes = self._p_bytes
 
-        compute_s = lat * layers_per_stage / clock_t * bwd
         cpc_step = total_cores[:, None] * nw2 // jnp.maximum(chunks, 1)
         tp_vol = 2.0 * (tp - 1) / tp * act_bytes * 2.0
         tp_bw = jnp.where(cpc_step <= arrs["cores_per_reticle"][:, None],
                           arrs["reticle_bisection_Bps"][:, None],
                           arrs["inter_reticle_bw_Bps"][:, None])
-        tp_s = jnp.where(tp <= 1, 0.0, tp_vol / jnp.maximum(tp_bw, 1.0)) \
-            * layers_per_stage * bwd
         ir_bw = arrs["inter_reticle_bw_Bps"][:, None]
+        a2a_vol = None
+        if ep2 is not None:
+            # MoE dispatch+combine all-to-all (chunk_eval mirror); the
+            # where-guard zeroes ep=1 lanes so stage_s + fp(0.0) keeps
+            # the legacy bits
+            topk = max(wl.moe_topk, 1)
+            a2a_vol = jnp.where(ep2 > 1,
+                                4.0 * (ep2 - 1) / ep2 * act_bytes * topk,
+                                0.0)
+        if kinds is None:
+            layers_per_stage = jnp.maximum(wl.n_layers // pp, 1)
+            lat = lats[0]
+            compute_s = lat * layers_per_stage / clock_t * bwd
+            tp_s = jnp.where(tp <= 1, 0.0,
+                             tp_vol / jnp.maximum(tp_bw, 1.0)) \
+                * layers_per_stage * bwd
+        else:
+            tp_layer = jnp.where(tp <= 1, 0.0,
+                                 tp_vol / jnp.maximum(tp_bw, 1.0)) * bwd
+            a2a_layer = (jnp.zeros_like(tp_layer) if a2a_vol is None
+                         else a2a_vol / jnp.maximum(ir_bw, 1.0) * bwd)
+            compute_s, tp_s, ep_s, slow_s = slowest_stage(
+                wl, pp, [lat / clock_t * bwd for lat in lats], tp_layer,
+                a2a_layer, jnp, fp)
         pp_s = jnp.where(pp <= 1, 0.0,
                          act_bytes / jnp.maximum(ir_bw, 1.0)) * bwd
 
@@ -526,21 +604,16 @@ class _EvalProgram:
         dram_s = jnp.where(dram_traffic <= 0, 0.0,
                            dram_traffic / jnp.maximum(dram_bw, 1.0))
 
-        _s1 = fp(compute_s) + fp(tp_s)
-        _s2 = _s1 + fp(pp_s)
-        stage_s = _s2 + fp(dram_s)
-        a2a_vol = None
-        if ep2 is not None:
-            # MoE dispatch+combine all-to-all (chunk_eval mirror); the
-            # where-guard zeroes ep=1 lanes so stage_s + fp(0.0) keeps
-            # the legacy bits
-            topk = max(wl.moe_topk, 1)
-            a2a_vol = jnp.where(ep2 > 1,
-                                4.0 * (ep2 - 1) / ep2 * act_bytes * topk,
-                                0.0)
-            ep_s = (a2a_vol / jnp.maximum(ir_bw, 1.0) * layers_per_stage
-                    * bwd)
-            stage_s = stage_s + fp(ep_s)
+        if kinds is None:
+            _s1 = fp(compute_s) + fp(tp_s)
+            _s2 = _s1 + fp(pp_s)
+            stage_s = _s2 + fp(dram_s)
+            if a2a_vol is not None:
+                ep_s = (a2a_vol / jnp.maximum(ir_bw, 1.0) * layers_per_stage
+                        * bwd)
+                stage_s = stage_s + fp(ep_s)
+        else:
+            stage_s = (slow_s + fp(pp_s)) + fp(dram_s)
         # fp() also blocks the `x / (a/b) -> x * (b/a)` divide rewrite on
         # iter_s below, which re-rounds against the NumPy association.
         eff = fp(mb_count / (mb_count + pp - 1.0))
@@ -556,6 +629,16 @@ class _EvalProgram:
         throughput = self._tokens / jnp.maximum(step_s, 1e-12)
 
         E = C.ENERGY
+        n_layers = nl_moe = wl.n_layers
+        if kinds is None:
+            sram_bits_layer, noc_bytes_layer = srams[0], hops[0]
+        else:
+            # whole-model totals, kinds weighted by their traced counts
+            # (`chunk_eval.kind_total`); nl_t is then 1
+            nl_moe, counts = zc[6], zc[7:]
+            sram_bits_layer = kind_total(srams, counts, fp)
+            noc_bytes_layer = kind_total(hops, counts, fp)
+            n_layers = sum(k.count for k in kinds)
         # `p12` (traced 1e-12) keeps the simplifier from folding the pJ
         # constants with the unit scale into one single-rounded factor.
         # pin every intermediate product: these bare mul chains get
@@ -567,11 +650,12 @@ class _EvalProgram:
                              * mb_count) * dp) * bwd) * E.noc_bit_hop)
                    * p12)
         ir_bytes = (2.0 * (tp - 1) / jnp.maximum(tp, 1) * mb_tokens
-                    * dmod_t * BYTES * 2 * wl.n_layers * mb_count * dp
+                    * dmod_t * BYTES * 2 * n_layers * mb_count * dp
                     * bwd)
         ir_bytes = fp(ir_bytes) + fp(p_bytes * 2 * (dp > 1))
         if a2a_vol is not None:
-            ir_bytes = ir_bytes + fp(fp(fp(a2a_vol * nl_t) * mb_count)
+            nl_a2a = nl_t if kinds is None else nl_moe
+            ir_bytes = ir_bytes + fp(fp(fp(a2a_vol * nl_a2a) * mb_count)
                                      * dp)
         e_ir = (ir_bytes * 8 * arrs["ir_energy_pj_per_bit"][:, None]
                 * p12)
@@ -606,7 +690,7 @@ class _EvalProgram:
             "dp_s": dp_s,
             "mb_count": mb_count,
         }
-        if extras is not None:
+        if extras is not None or kinds is not None:
             cand["ep_s"] = ep_s
         return cand
 
@@ -660,6 +744,7 @@ class _EvalProgram:
                 _LANE_STATS["n_lanes"] = max(_LANE_STATS["n_lanes"], 1)
                 _LANE_STATS["jit_calls"] += 1
                 _LANE_STATS["rows_jit"] += npad
+        tm.count("evaluate.layer_rows", npad * self.layer_cols)
         return {k: v[:n] for k, v in out.items()}
 
     def dispatch_fused(self, arrs: Dict[str, np.ndarray], nw: np.ndarray,
@@ -679,6 +764,8 @@ class _EvalProgram:
             out = self._fused_jit(ja, jn, self._zc(), js)
         _LANE_STATS["jit_calls"] += 1
         _LANE_STATS["rows_jit"] += int(js_dev.shape[0])
+        tm.count("evaluate.layer_rows",
+                 int(js_dev.shape[0]) * self.layer_cols)
         return _PendingEval(self, out)
 
     def results_from(self, out: Dict[str, np.ndarray], nw: np.ndarray
@@ -696,23 +783,27 @@ class _EvalProgram:
             g = int(out["sel_g"][i])
             eff = float(out["pipeline_eff"][i])
             mbc = float(out["mb_count"][i])
+            bd = {"compute": float(out["compute_s"][i]) * mbc / eff,
+                  "tp": float(out["tp_s"][i]) * mbc / eff,
+                  "pp": float(out["pp_s"][i]) * mbc / eff,
+                  "dram": float(out["dram_s"][i]) * mbc / eff,
+                  "dp": float(out["dp_s"][i])}
+            ep_v = float(out["ep_s"][i]) if "ep_s" in out else 0.0
+            if ep_v:
+                bd["ep"] = ep_v * mbc / eff
             sr = StepResult(
                 step_time_s=float(out["step_time_s"][i]),
                 throughput=float(out["throughput"][i]),
                 power_w=float(out["power_w"][i]),
-                pipeline_eff=eff,
-                breakdown={
-                    "compute": float(out["compute_s"][i]) * mbc / eff,
-                    "tp": float(out["tp_s"][i]) * mbc / eff,
-                    "pp": float(out["pp_s"][i]) * mbc / eff,
-                    "dram": float(out["dram_s"][i]) * mbc / eff,
-                    "dp": float(out["dp_s"][i])},
+                pipeline_eff=eff, breakdown=bd,
                 energy_j=float(out["energy_j"][i]),
                 feasible=True, reason="")
             res.append(EvalResult(
                 sr.throughput, sr.power_w,
                 Strategy(int(self._tp_o[g]), int(self._pp_o[g]),
-                         int(self._dp_o[g]), int(self._mb_o[g])),
+                         int(self._dp_o[g]), int(self._mb_o[g]),
+                         ep=1 if self._ep_o is None
+                         else int(self._ep_o[g])),
                 sr, int(nw[i]), True))
         return res
 
@@ -754,6 +845,7 @@ class _EvalProgram:
                 out = {k: np.asarray(v) for k, v in out.items()}
                 _LANE_STATS["jit_calls"] += 1
                 _LANE_STATS["rows_jit"] += npad
+        tm.count("evaluate.layer_rows", npad * self.layer_cols // self.K)
         return {k: v[:n] for k, v in out.items()}
 
     def dispatch_fused_pinned(self, arrs: Dict[str, np.ndarray],
@@ -776,6 +868,8 @@ class _EvalProgram:
             out = self._fused_pinned_jit(ja, jn, self._zc(), js, picks)
         _LANE_STATS["jit_calls"] += 1
         _LANE_STATS["rows_jit"] += int(js_dev.shape[0])
+        tm.count("evaluate.layer_rows",
+                 int(js_dev.shape[0]) * self.layer_cols // self.K)
         return _PendingPinnedEval(self, out)
 
     def results_from_pinned(self, out: Dict[str, np.ndarray],

@@ -302,6 +302,7 @@ def evaluate_design_batch(designs: Sequence[WSCDesign], wl: LLMWorkload,
             fresh = backend.evaluate_batch(geom0.take(np.asarray(todo)), wl,
                                            nw[todo], max_strategies,
                                            gnn_params)
+        _count_best_ep(fresh)
         with tm.span(layer + ".cache"):
             for i, r in zip(todo, fresh):
                 results[i] = r
@@ -350,11 +351,22 @@ def evaluate_pool_fused(pool_designs: Sequence[WSCDesign], wl: LLMWorkload,
             geom, wl, nw, js_all, max_strategies=max_strategies)
         js = [int(j) for j in js_all[:q_eff]]
         fresh = pending.finish(nw[js_all], q_eff)
+    _count_best_ep(fresh)
     with tm.span("evaluate.analytical.cache", items=q_eff):
         results = _cache_fused(
             [_cache_key(pool[j], wl, "analytical", int(nw[j]),
                         max_strategies, gnn_params) for j in js], fresh)
     return js, results
+
+
+def _count_best_ep(results: Sequence[EvalResult]) -> None:
+    """Counter `evaluate.best_ep`: designs of a grid-mode call whose best
+    feasible strategy is expert-parallel (ep > 1), from the results
+    already on the host."""
+    n = sum(r.feasible and r.strategy is not None and r.strategy.ep > 1
+            for r in results)
+    if n:
+        tm.count("evaluate.best_ep", n)
 
 
 def _cache_fused(keys, fresh) -> List[EvalResult]:

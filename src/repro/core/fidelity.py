@@ -142,12 +142,15 @@ class CandidateAxis:
     gh: np.ndarray                 # (C,) capped NoC grid (compile_chunk cap)
     gw: np.ndarray
     n_cores: np.ndarray            # (C,) gh * gw
-    tiles: Dict[str, np.ndarray]   # (n_ops, C) tile stage outputs
-    out_bytes: np.ndarray          # (n_ops, C) producer output bytes
-    sram_bits_layer: np.ndarray    # (C,)
-    noc_bytes_layer: np.ndarray    # (C,)
+    # tile stage outputs and producer output bytes, (n_ops, C): one entry
+    # per layer kind (one for a uniform workload)
+    tiles: List[Dict[str, np.ndarray]]
+    out_bytes: List[np.ndarray]
+    sram_bits_layer: np.ndarray    # (C,), or (n_kinds, C) for a kind table
+    noc_bytes_layer: np.ndarray    # (C,), or (n_kinds, C)
     # pinned-strategy (joint) mode: the original Strategy per design plus
-    # the extra knob columns; None in grid mode (ISSUE 9)
+    # the extra knob columns; in grid mode `ep` is the grid's column for a
+    # workload with routed experts, else None
     pinned: Optional[List[Strategy]] = None
     ep: Optional[np.ndarray] = None
     rc: Optional[np.ndarray] = None
@@ -165,8 +168,10 @@ def build_candidate_axis(geom: DesignBatch, wl: LLMWorkload, nw: np.ndarray,
     When `strategies` is given (joint mode, one Strategy per design) the
     grid enumeration is skipped entirely: the candidate axis is exactly one
     pinned candidate per design, with the ep/recompute extras threaded
-    through to the chunk-level model."""
+    through to the chunk-level model. A table of layer kinds runs the
+    tile stage once a kind, over that kind's GEMMs (`kind_ops`)."""
     designs = geom.designs
+    ep = None
 
     if strategies is not None:
         counts = np.ones(len(designs), np.int64)
@@ -190,6 +195,8 @@ def build_candidate_axis(geom: DesignBatch, wl: LLMWorkload, nw: np.ndarray,
         didx = np.repeat(np.arange(len(designs), dtype=np.int64), counts)
         sa = np.concatenate(strat_arrays, axis=0)
         tp, pp, dp, mb = sa[:, 0], sa[:, 1], sa[:, 2], sa[:, 3]
+        if sa.shape[1] > 4:
+            ep = sa[:, 4]
 
     cg = geom.take(didx)                     # candidate-axis geometry
     nw_c = nw[didx]
@@ -201,28 +208,40 @@ def build_candidate_axis(geom: DesignBatch, wl: LLMWorkload, nw: np.ndarray,
     gh_t, gw_t = grid_for_batch(cores_per_chunk)
     gh, gw = grid_for_batch(np.minimum(cores_per_chunk, 64))
     n_cores = gh * gw
-    ops = wl.layer_ops_batch(tp, mb_tokens)
-    tile_M = np.maximum(ops["M"] // gh_t, 1)
-    tile_N = np.maximum(ops["N"] // gw_t, 1)
-    tiles = evaluate_tile_batch(tile_M, ops["K"], tile_N,
+    if wl.uniform:
+        op_tables = [wl.layer_ops_batch(tp, mb_tokens)]
+    else:
+        op_tables = []
+        for k in wl.layer_kinds():
+            ops = wl.kind_ops(k, tp, mb_tokens)
+            op_tables.append({a: np.stack([o[i] for o in ops])
+                              for i, a in ((1, "M"), (2, "K"), (3, "N"))})
+    tiles, out_bytes, sram_bits, noc_bytes = [], [], [], []
+    for ops in op_tables:
+        tile_M = np.maximum(ops["M"] // gh_t, 1)
+        tile_N = np.maximum(ops["N"] // gw_t, 1)
+        t = evaluate_tile_batch(tile_M, ops["K"], tile_N,
                                 cg.mac[None, :], cg.buffer_kb[None, :],
                                 cg.buffer_bw[None, :],
                                 cg.dataflow_code[None, :])
+        ob = (ops["M"] * ops["N"]).astype(np.float64) * BYTES
+        tiles.append(t)
+        out_bytes.append(ob)
+        sram_bits.append((t["sram_read_bits"]
+                          + t["sram_write_bits"]).sum(axis=0) * n_cores)
+        noc_bytes.append(row_allgather_byte_hops(ob[:-1], gh, gw))
 
-    out_bytes = (ops["M"] * ops["N"]).astype(np.float64) * BYTES
-    sram_bits_layer = (tiles["sram_read_bits"]
-                       + tiles["sram_write_bits"]).sum(axis=0) * n_cores
-    noc_bytes_layer = row_allgather_byte_hops(out_bytes[:-1], gh, gw)
-
+    if strategies is not None:
+        ep = np.array([s.ep for s in strategies], np.int64)
     return CandidateAxis(
         geom=geom, cg=cg, nw=nw, nw_c=nw_c, offsets=offsets, didx=didx,
         tp=tp, pp=pp, dp=dp, mb=mb, mb_tokens=mb_tokens,
         cores_per_chunk=cores_per_chunk, gh=gh, gw=gw, n_cores=n_cores,
-        tiles=tiles, out_bytes=out_bytes, sram_bits_layer=sram_bits_layer,
-        noc_bytes_layer=noc_bytes_layer,
+        tiles=tiles, out_bytes=out_bytes,
+        sram_bits_layer=sram_bits[0] if wl.uniform else np.stack(sram_bits),
+        noc_bytes_layer=noc_bytes[0] if wl.uniform else np.stack(noc_bytes),
         pinned=list(strategies) if strategies is not None else None,
-        ep=(np.array([s.ep for s in strategies], np.int64)
-            if strategies is not None else None),
+        ep=ep,
         rc=(np.array([s.recompute for s in strategies], bool)
             if strategies is not None else None))
 
@@ -268,7 +287,8 @@ def _finish(ax: CandidateAxis, wl: LLMWorkload, lat: np.ndarray
         results.append(EvalResult(
             sr.throughput, sr.power_w,
             Strategy(int(ax.tp[j]), int(ax.pp[j]), int(ax.dp[j]),
-                     int(ax.mb[j])),
+                     int(ax.mb[j]),
+                     ep=1 if ax.ep is None else int(ax.ep[j])),
             sr, int(ax.nw[i]), True))
     return results
 
@@ -322,11 +342,12 @@ def _transfer_lanes(ax: CandidateAxis) -> _TransferLanes:
     nc_u = ax.n_cores[first].astype(np.float64)
     bw_u = ax.cg.noc_bw[first].astype(np.float64)
 
-    n_transfers = ax.out_bytes.shape[0] - 1
-    per_pair = ax.out_bytes[:-1, first] / nc_u        # (T, U)
+    out_bytes, tiles = ax.out_bytes[0], ax.tiles[0]
+    n_transfers = out_bytes.shape[0] - 1
+    per_pair = out_bytes[:-1, first] / nc_u           # (T, U)
     flits = np.maximum(np.ceil(per_pair * 8.0 / bw_u), 1.0)
-    interval = ax.tiles["out_interval_cycles"][:-1, first]
-    dur = np.maximum(ax.tiles["cycles"][:-1, first], 1.0)
+    interval = tiles["out_interval_cycles"][:-1, first]
+    dur = np.maximum(tiles["cycles"][:-1, first], 1.0)
 
     buckets: List[_GridLanes] = []
     for gw0 in np.unique(gw_u[gw_u > 1]):
@@ -430,7 +451,7 @@ def _graph_latency(ax: CandidateAxis, lane_fn) -> np.ndarray:
     comm = np.zeros(lanes.n_unique)
     for b in lanes.buckets:
         np.add.at(comm, b.u_lane, lane_fn(b))
-    return ax.tiles["cycles"].sum(axis=0) + comm[lanes.inverse]
+    return ax.tiles[0]["cycles"].sum(axis=0) + comm[lanes.inverse]
 
 
 # ---------------------------------------------------------------------------
@@ -477,9 +498,10 @@ class AnalyticalBackend:
         kept verbatim as the oracle for the jitted path)."""
         ax = build_candidate_axis(geom, wl, n_wafers, max_strategies,
                                   strategies)
-        lat = chunk_latency_cycles_closed(ax.tiles["cycles"], ax.out_bytes,
-                                          ax.gh, ax.gw, ax.cg.noc_bw)
-        return _finish(ax, wl, lat)
+        lat = [chunk_latency_cycles_closed(t["cycles"], ob, ax.gh, ax.gw,
+                                           ax.cg.noc_bw)
+               for t, ob in zip(ax.tiles, ax.out_bytes)]
+        return _finish(ax, wl, lat[0] if wl.uniform else np.stack(lat))
 
 
 class GNNBackend:
@@ -502,6 +524,7 @@ class GNNBackend:
         if gnn_params is None:
             return get_backend("analytical").evaluate_batch(
                 geom, wl, n_wafers, max_strategies, strategies=strategies)
+        wl.require_uniform("the GNN fidelity's link-graph features")
         ax = build_candidate_axis(geom, wl, n_wafers, max_strategies,
                                   strategies)
         lat = _graph_latency(
@@ -523,6 +546,7 @@ class SimBackend:
                        gnn_params: Optional[Dict] = None,
                        strategies: Optional[List[Strategy]] = None
                        ) -> List[EvalResult]:
+        wl.require_uniform("the NoC simulator's transfer lanes")
         ax = build_candidate_axis(geom, wl, n_wafers, max_strategies,
                                   strategies)
         lat = _graph_latency(ax, _sim_lane_makespans)

@@ -79,6 +79,11 @@ def _kv_transfer_bw(design: WSCDesign, granularity: str) -> float:
     return 0.5 * n_ni * C.INTER_WAFER_BW_PER_NI
 
 
+#: what a table of layer kinds is refused by: the stage scoring below sizes
+#: the KV hand-off and the stage steps from one uniform layer
+_STAGES = "heterogeneous prefill/decode stage scoring"
+
+
 def evaluate_hetero(design_prefill: WSCDesign, design_decode: WSCDesign,
                     wl_base: LLMWorkload, granularity: str,
                     prefill_ratio: float, out_tokens: int = 2048,
@@ -88,6 +93,7 @@ def evaluate_hetero(design_prefill: WSCDesign, design_decode: WSCDesign,
     stages share the wafer (resource fractions); at wafer granularity each
     stage gets whole wafers. `fidelity` is a registered backend name (or a
     FidelityBackend instance) — resolved up front so typos fail loudly."""
+    wl_base.require_uniform(_STAGES)
     fidelity = get_backend(fidelity)
     wl_p = inference_workload(wl_base, "prefill", batch=wl_base.batch,
                               seq=wl_base.seq)
@@ -169,6 +175,7 @@ def evaluate_hetero_serving(design_prefill: WSCDesign,
     prefill times on the prefill stage's resource share, per-request
     KV-cache shipping across the stage boundary, and a decode pool that only
     admits a request once its KV has landed and a slot is free."""
+    wl_base.require_uniform(_STAGES)
     fidelity = get_backend(fidelity)
     wl_p, wl_d, p_ref = serving_workloads(wl_base, mix, slots)
 
@@ -256,6 +263,7 @@ def evaluate_hetero_trace_serving_batch(
     score in the same frame as the shared-pool policies."""
     from repro.core.traces import trace_serving_workloads
 
+    wl_base.require_uniform(_STAGES)
     designs_prefill = list(designs_prefill)
     designs_decode = list(designs_decode)
     if len(designs_prefill) != len(designs_decode):

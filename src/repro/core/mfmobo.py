@@ -146,17 +146,18 @@ def _grid_seed_strategies(designs, wl, space):
          & (g["need"][None, o] <= mem[:, None]))
     found = m.any(axis=1)
     idx = o[np.argmax(m, axis=1)]
+    ep = g["ep"][idx] if "ep" in g else np.ones_like(idx)
     need_plain = strategy_memory_need(wl, g["tp"][idx], g["pp"][idx],
-                                      g["dp"][idx], g["mb"][idx])
+                                      g["dp"][idx], g["mb"][idx], ep=ep)
     need_rc = strategy_memory_need(wl, g["tp"][idx], g["pp"][idx],
-                                   g["dp"][idx], g["mb"][idx],
+                                   g["dp"][idx], g["mb"][idx], ep=ep,
                                    recompute=True)
     rc = ((wl.phase == "train") & (need_plain > mem) & (need_rc <= mem))
     enc = np.zeros((len(designs), space.n_dims))
     for i in np.flatnonzero(found):
         s = Strategy(int(g["tp"][idx[i]]), int(g["pp"][idx[i]]),
                      int(g["dp"][idx[i]]), int(g["mb"][idx[i]]),
-                     recompute=bool(rc[i]))
+                     ep=int(ep[i]), recompute=bool(rc[i]))
         enc[i] = space.encode_strategy(s)
     return enc, found
 

@@ -968,7 +968,10 @@ def trace_serving_workloads(wl_base: LLMWorkload, trace: RequestTrace,
                             slots: int
                             ) -> Tuple[LLMWorkload, LLMWorkload, int]:
     """The two per-step workloads trace serving composes — identical
-    convention to `serving.serving_workloads`, sized from the trace."""
+    convention to `serving.serving_workloads`, sized from the trace.
+    One uniform layer only: the schedule's prefill and decode costs and
+    the KV hand-off scale from it."""
+    wl_base.require_uniform("the trace scheduler's stage scoring")
     p_ref = max(1, int(round(trace.mean_prompt)))
     wl_p = dataclasses.replace(wl_base, phase="prefill", batch=1, seq=p_ref)
     wl_d = dataclasses.replace(wl_base, phase="decode", batch=slots,
@@ -1041,6 +1044,7 @@ def evaluate_trace_serving_batch(
     which applies to the pool candidates. Results keep the input order."""
     from repro.core.fidelity import get_backend
 
+    wl_base.require_uniform("the trace scheduler's stage scoring")
     backend = get_backend(fidelity)
     designs = list(designs)
     if not designs:

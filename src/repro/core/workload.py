@@ -33,7 +33,34 @@ class GEMMOp:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """One row of a workload's table of layer kinds: `count` layers that
+    share one GEMM list. `moe` marks routed-expert layers (router, routed
+    and shared SwiGLU experts), `mtp` the multi-token-prediction block
+    (its `eh_proj`, then an attention and an MoE block)."""
+    name: str
+    count: int
+    moe: bool = False
+    mtp: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
 class LLMWorkload:
+    """A modelled LLM job. The paper's rows and every `from_model_config`
+    workload are one uniform layer of six GEMMs (`uniform`). Setting any
+    of the fields after `gpu_budget` makes a table of layer kinds
+    (`layer_kinds`): leading dense layers before routed-expert layers
+    (`n_dense_layers`, expert width `d_ff_expert`, `moe_shared` shared
+    experts), latent attention (MLA: `q_lora_rank`, `kv_lora_rank`, q/k
+    heads of `qk_nope_head_dim + qk_rope_head_dim`, v heads of
+    `v_head_dim`) and `mtp_layers` multi-token-prediction blocks, which
+    run in training only. A table of layer kinds models MLA attention, so
+    it needs `q_lora_rank` and `kv_lora_rank`.
+
+    Departures from the published models, as for the uniform layer:
+    norms, RoPE, the router's softmax/sigmoid and the LM head are left out
+    of the per-layer GEMMs; routing is not node-limited, so an expert
+    all-to-all carries every one of the top-k copies."""
     name: str
     n_layers: int
     d_model: int
@@ -47,24 +74,126 @@ class LLMWorkload:
     moe_experts: int = 0
     moe_topk: int = 0
     gpu_budget: int = 1            # baseline GPU count (area matching)
+    n_dense_layers: int = 0        # leading dense layers (width d_ff)
+    d_ff_expert: int = 0           # routed / shared expert width
+    moe_shared: int = 0            # shared experts per MoE layer
+    q_lora_rank: int = 0           # MLA query compression
+    kv_lora_rank: int = 0          # MLA latent cache width
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mtp_layers: int = 0            # multi-token-prediction blocks (train)
+
+    def __post_init__(self):
+        if not self.uniform and not (self.q_lora_rank
+                                     and self.kv_lora_rank):
+            raise ValueError(
+                f"workload {self.name!r}: a table of layer kinds models MLA "
+                f"attention and needs q_lora_rank and kv_lora_rank")
 
     # ------------------------------------------------------------------
 
+    @property
+    def uniform(self) -> bool:
+        """True for the one-kind table built from the first fields: every
+        layer the same six GEMMs, as the paper's rows."""
+        return not (self.n_dense_layers or self.d_ff_expert
+                    or self.moe_shared or self.q_lora_rank
+                    or self.kv_lora_rank or self.mtp_layers)
+
+    def layer_kinds(self) -> Tuple[LayerKind, ...]:
+        """The table of layer kinds in published order. A uniform workload
+        is the one kind "layer"; otherwise "dense" (the leading dense
+        layers), "moe" (the rest, when there are routed experts) and, in
+        training, "mtp"."""
+        if self.uniform:
+            return (LayerKind("layer", self.n_layers,
+                              moe=bool(self.moe_experts)),)
+        n_dense = self.n_dense_layers if self.moe_experts else self.n_layers
+        kinds = []
+        if n_dense:
+            kinds.append(LayerKind("dense", n_dense))
+        if self.n_layers - n_dense:
+            kinds.append(LayerKind("moe", self.n_layers - n_dense, moe=True))
+        if self.mtp_layers and self.phase == "train":
+            kinds.append(LayerKind("mtp", self.mtp_layers,
+                                   moe=bool(self.moe_experts), mtp=True))
+        return tuple(kinds)
+
+    def require_uniform(self, reader: str) -> None:
+        """Refuse a table of layer kinds where `reader` models one uniform
+        layer: a silent collapse to one layer would score another job."""
+        if not self.uniform:
+            kinds = ", ".join(f"{k.count} {k.name}"
+                              for k in self.layer_kinds())
+            raise ValueError(
+                f"workload {self.name!r} has layer kinds ({kinds}); "
+                f"{reader} models one uniform layer and cannot score it")
+
+    def stage_kind_counts(self, pp: int) -> np.ndarray:
+        """(pp, n_kinds) layers of each kind per pipeline stage: a balanced
+        contiguous split of the `n_layers` in published order (stage s
+        holds layers floor(s L / pp) .. floor((s+1) L / pp) - 1), the MTP
+        blocks in the last stage beside the head."""
+        kinds = self.layer_kinds()
+        pp = max(int(pp), 1)
+        kind_of = np.repeat(np.arange(len(kinds)),
+                            [k.count if not k.mtp else 0 for k in kinds])
+        L = len(kind_of)
+        out = np.zeros((pp, len(kinds)), np.int64)
+        for s in range(pp):
+            lo, hi = s * L // pp, (s + 1) * L // pp
+            out[s] = np.bincount(kind_of[lo:hi], minlength=len(kinds))
+        for i, k in enumerate(kinds):
+            if k.mtp:
+                out[-1, i] += k.count
+        return out
+
+    def _attn_params(self) -> int:
+        D, H = self.d_model, max(self.n_heads, 1)
+        c, r, ql = self.kv_lora_rank, self.qk_rope_head_dim, self.q_lora_rank
+        hq = self.qk_nope_head_dim + r
+        return (D * ql + ql * H * hq + D * (c + r) + c * H * (self.qk_nope_head_dim
+                                           + self.v_head_dim)
+                + H * self.v_head_dim * D)
+
+    def _kind_params(self, k: LayerKind, active: bool) -> int:
+        D = self.d_model
+        p = self._attn_params() + (2 * D * D if k.mtp else 0)
+        if not k.moe:
+            return p + 3 * D * self.d_ff
+        fe = self.d_ff_expert or self.d_ff
+        routed = self.moe_topk if active else self.moe_experts
+        return (p + D * self.moe_experts
+                + (routed + self.moe_shared) * 3 * D * fe)
+
     def params_bytes(self) -> float:
         D, F, L = self.d_model, self.d_ff, self.n_layers
+        if not self.uniform:
+            return (sum(k.count * self._kind_params(k, False)
+                        for k in self.layer_kinds())
+                    + 2 * self.vocab * D) * BYTES
         per = 4 * D * D + 3 * D * F * max(self.moe_experts, 1)
         return (L * per + 2 * self.vocab * D) * BYTES
 
     def expert_params_bytes(self) -> float:
-        """Bytes of MoE expert weights (the `ep`-shardable slice of
-        `params_bytes`); 0 for dense models."""
+        """Bytes of MoE routed-expert weights (the `ep`-shardable slice of
+        `params_bytes`; shared experts and the router are replicated); 0
+        for dense models."""
         if not self.moe_experts:
             return 0.0
         D, F, L = self.d_model, self.d_ff, self.n_layers
+        if not self.uniform:
+            fe = self.d_ff_expert or F
+            n_moe = sum(k.count for k in self.layer_kinds() if k.moe)
+            return n_moe * 3 * D * fe * self.moe_experts * BYTES
         return L * 3 * D * F * self.moe_experts * BYTES
 
     def active_params(self) -> float:
         D, F, L = self.d_model, self.d_ff, self.n_layers
+        if not self.uniform:
+            return (sum(k.count * self._kind_params(k, True)
+                        for k in self.layer_kinds()) + self.vocab * D)
         e = self.moe_topk if self.moe_experts else 1
         return L * (4 * D * D + 3 * D * F * e) + self.vocab * D
 
@@ -73,11 +202,18 @@ class LLMWorkload:
             return self.batch
         return self.batch * self.seq
 
-    def layer_ops(self, tp: int = 1, mb_tokens: Optional[int] = None
-                  ) -> List[GEMMOp]:
+    def layer_ops(self, tp: int = 1, mb_tokens: Optional[int] = None,
+                  kind: Optional[LayerKind] = None) -> List[GEMMOp]:
         """One layer's GEMMs under tensor parallelism `tp` (Megatron split:
         heads/ffn sharded; two collectives per layer accounted by chunk_eval).
-        M = tokens per microbatch."""
+        M = tokens per microbatch. A table of layer kinds needs `kind`
+        (`kind_ops`); the uniform layer takes none."""
+        if not self.uniform:
+            if kind is None:
+                self.require_uniform("layer_ops without a kind")
+            M = mb_tokens if mb_tokens is not None else self.tokens_per_step()
+            return [GEMMOp(n, int(m), int(k), int(nn), w)
+                    for n, m, k, nn, w in self.kind_ops(kind, tp, M)]
         D, F = self.d_model, self.d_ff
         hd = D // max(self.n_heads, 1)
         M = mb_tokens if mb_tokens is not None else self.tokens_per_step()
@@ -104,7 +240,9 @@ class LLMWorkload:
         """Vectorized `layer_ops`: `tp`/`mb_tokens` are (C,) int arrays, the
         result is a dict of (n_ops, C) int arrays M/K/N plus the static
         `weight` flags — column c reproduces layer_ops(tp[c], mb_tokens[c])
-        exactly (integer semantics included)."""
+        exactly (integer semantics included). Uniform workloads only: a
+        table of layer kinds is read per kind (`kind_ops`)."""
+        self.require_uniform("layer_ops_batch")
         tp = np.asarray(tp, np.int64)
         M = np.asarray(mb_tokens, np.int64)
         D, F = self.d_model, self.d_ff
@@ -123,11 +261,73 @@ class LLMWorkload:
         names = ("qkv", "scores", "attnv", "attn_out", "mlp_in", "mlp_out")
         return {"M": Ms, "K": Ks, "N": Ns, "weight": weight, "names": names}
 
+    def kind_ops(self, kind: LayerKind, tp, M, xp=np):
+        """One layer of `kind` as (name, M, K, N, weight) GEMMs under `tp`,
+        M the microbatch's tokens. `tp` and `M` are ints or int arrays of
+        one shape, and `xp` the array module (NumPy, or `jax.numpy` inside
+        the compiled evaluator): integer arithmetic only, so every array
+        module gives the same numbers.
+
+        Attention, in every kind, is MLA. Under `tp` the compressions (`q_a`, `kv_a`) are replicated and everything after
+        them is split by heads. The per-head GEMMs (scores, attn-v, and in
+        decode the absorbed projections) have M x heads rows and a context
+        of `seq`. Training and prefill run MLA as published (`kv_b`
+        expands the latent into per-head k and v); decode runs the absorbed
+        form DeepSeek serves with: q_nope folded into the latent
+        (`q_absorb`), scores over the cached latent and rope key
+        (kv_lora_rank + qk_rope_head_dim), attn-v over the latent, then
+        the per-head up-projection (`v_up`) and `attn_out`. An MoE kind
+        adds the router (d_model -> moe_experts), the routed SwiGLU at M x
+        moe_topk rows and the shared SwiGLU at M x moe_shared rows; a dense
+        kind the SwiGLU of width d_ff. The MTP kind starts with `eh_proj`
+        (2 d_model -> d_model, replicated)."""
+        D, H = self.d_model, max(self.n_heads, 1)
+        M = xp.asarray(M)
+        zi = xp.zeros_like(M)
+        tp = zi + tp
+        m_h = M * xp.maximum(H // tp, 1)       # rows of the per-head GEMMs
+        kv = zi + self.seq
+        c, r = self.kv_lora_rank, self.qk_rope_head_dim
+        nope, v, ql = self.qk_nope_head_dim, self.v_head_dim, self.q_lora_rank
+        ops = [("eh_proj", M, zi + 2 * D, zi + D, True)] if kind.mtp else []
+        ops += [("q_a", M, zi + D, zi + ql, True),
+                ("q_b", M, zi + ql, H * (nope + r) // tp, True),
+                ("kv_a", M, zi + D, zi + (c + r), True)]
+        if self.phase == "decode":
+            ops += [("q_absorb", m_h, zi + nope, zi + c, True),
+                    ("scores", m_h, zi + (c + r), kv, False),
+                    ("attnv", m_h, kv, zi + c, False),
+                    ("v_up", m_h, zi + c, zi + v, True)]
+        else:
+            ops += [("kv_b", M, zi + c, H * (nope + v) // tp, True),
+                    ("scores", m_h, zi + (nope + r), kv, False),
+                    ("attnv", m_h, kv, zi + v, False)]
+        ops.append(("attn_out", M, H * v // tp, zi + D, True))
+        if not kind.moe:
+            F = self.d_ff
+            return ops + [("mlp_in", M, zi + D, 2 * F // tp, True),
+                          ("mlp_out", M, F // tp, zi + D, True)]
+        fe = self.d_ff_expert or self.d_ff
+        ops += [("router", M, zi + D, zi + self.moe_experts, True),
+                ("mlp_in", M * self.moe_topk, zi + D, 2 * fe // tp, True),
+                ("mlp_out", M * self.moe_topk, fe // tp, zi + D, True)]
+        if self.moe_shared:
+            fs = fe * self.moe_shared
+            ops += [("shared_in", M, zi + D, 2 * fs // tp, True),
+                    ("shared_out", M, fs // tp, zi + D, True)]
+        return ops
+
     def flops_per_step(self) -> float:
         mult = 3.0 if self.phase == "train" else 1.0   # fwd+bwd ~ 3x fwd
         return 2.0 * self.active_params() * self.tokens_per_step() * mult
 
     def kv_bytes_per_layer(self) -> float:
+        """Cache bytes of one layer at (batch, seq): K and V of n_kv heads,
+        or MLA's latent and rope key (kv_lora_rank + qk_rope_head_dim
+        values a token)."""
+        if self.kv_lora_rank:
+            return (self.batch * self.seq
+                    * (self.kv_lora_rank + self.qk_rope_head_dim) * BYTES)
         hd = self.d_model // max(self.n_heads, 1)
         return 2 * self.batch * self.seq * self.n_kv * hd * BYTES
 
@@ -237,6 +437,27 @@ GPT_BENCHMARKS: Tuple[LLMWorkload, ...] = (
     _gpt("GPT-18T", 18436.5, 240, 81920, 620, 60000, 15000),
     _gpt("GPT-32T", 32405.7, 270, 102400, 850, 100000, 20000),
 )
+
+
+# ---------------------------------------------------------------------------
+# workloads with a table of layer kinds, by name
+# ---------------------------------------------------------------------------
+
+#: DeepSeek-V3 pre-training. Widths: the published config
+#: (huggingface.co/deepseek-ai/DeepSeek-V3, config.json): 61 layers of MLA,
+#: the first 3 dense (SwiGLU 18432), then 58 MoE (256 routed experts of
+#: width 2048, top-8, 1 shared), one MTP block. The job: arXiv:2412.19437,
+#: 4K sequences at the held batch of 15360 (§4.2) on 2048 H800 GPUs
+#: (§3.1), the area budget.
+DEEPSEEK_V3 = LLMWorkload(
+    name="DeepSeek-V3", n_layers=61, d_model=7168, n_heads=128, n_kv=128,
+    d_ff=18432, vocab=129280, seq=4096, batch=15360, phase="train",
+    moe_experts=256, moe_topk=8, gpu_budget=2048, n_dense_layers=3,
+    d_ff_expert=2048, moe_shared=1, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    mtp_layers=1)
+
+KIND_TABLE_WORKLOADS: Tuple[LLMWorkload, ...] = (DEEPSEEK_V3,)
 
 
 def inference_workload(base: LLMWorkload, phase: str, batch: int = 32,

@@ -51,7 +51,8 @@ class ShimMesh:
 def model_config_for_workload(wl) -> ModelConfig:
     """Synthesize the runtime `ModelConfig` matching an `LLMWorkload`'s
     shape (dense or MoE decoder): the oracle and `export_train_config`
-    both need a config the model code accepts."""
+    both need a config the model code accepts. One uniform layer only."""
+    wl.require_uniform("the runtime-config oracle")
     moe = None
     if getattr(wl, "moe_experts", 0):
         moe = MoEConfig(num_experts=wl.moe_experts,

@@ -20,8 +20,8 @@ Scenarios wire the objective adapters (repro.explore.objectives):
     trace_serving  trace-driven multi-tenant serving: timed arrivals, per-
                tenant SLOs, searchable admission/routing policy (§14)
 
-Workload refs resolve against `repro.core.workload.GPT_BENCHMARKS` by name
-("GPT-175B") or against the runtime configs as "arch_id@shape_id"
+Workload refs resolve against `repro.core.workload.GPT_BENCHMARKS` and
+`KIND_TABLE_WORKLOADS` by name ("GPT-175B", "DeepSeek-V3") or against the runtime configs as "arch_id@shape_id"
 (repro.configs.get_config / get_shape via `from_model_config`), so every
 assigned architecture is a campaign target too.
 
@@ -37,7 +37,12 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 from repro.core.mfmobo import Trace
 from repro.core.pareto import pareto_mask, to_max_space
-from repro.core.workload import GPT_BENCHMARKS, LLMWorkload, RequestMix
+from repro.core.workload import (
+    GPT_BENCHMARKS,
+    KIND_TABLE_WORKLOADS,
+    LLMWorkload,
+    RequestMix,
+)
 from repro.explore.objectives import (
     ConstraintSpec,
     EvaluatorObjective,
@@ -453,12 +458,12 @@ class CampaignSpec:
 # workload resolution
 # ---------------------------------------------------------------------------
 
-_GPT_BY_NAME = {w.name: w for w in GPT_BENCHMARKS}
+_GPT_BY_NAME = {w.name: w for w in GPT_BENCHMARKS + KIND_TABLE_WORKLOADS}
 
 
 def resolve_workload(spec: CampaignSpec) -> LLMWorkload:
-    """Resolve the spec's workload ref: a paper benchmark by name
-    ("GPT-175B") or a runtime architecture as "arch_id@shape_id" (bridged
+    """Resolve the spec's workload ref: a named workload ("GPT-175B",
+    "DeepSeek-V3") or a runtime architecture as "arch_id@shape_id" (bridged
     through `from_model_config`). Overrides (batch/seq/phase) and the
     scenario's phase convention are applied on top."""
     ref = spec.workload

@@ -23,7 +23,12 @@ from repro.core import eval_compiled
 from repro.core.compiler import feasible_strategy_arrays_ref
 from repro.core.design_space import DesignBatch, decode_batch
 from repro.core.fidelity import AnalyticalBackend
-from repro.core.workload import GPT_BENCHMARKS, inference_workload
+from repro.core.workload import (
+    DEEPSEEK_V3,
+    GPT_BENCHMARKS,
+    LLMWorkload,
+    inference_workload,
+)
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
@@ -55,9 +60,23 @@ def _result_fingerprint(r):
     return out
 
 
-@pytest.mark.parametrize("wl_case", ["train", "prefill", "decode"])
-def test_compiled_matches_numpy_ref_bit_exact(wl_case):
-    wl = GPT_BENCHMARKS[0]
+#: a small table of two layer kinds: one dense layer, then MoE layers
+#: with shared experts, MLA attention at small ranks
+SMALL_MOE = LLMWorkload(
+    name="moe-2kind", n_layers=6, d_model=512, n_heads=8, n_kv=8,
+    d_ff=1536, vocab=32000, seq=1024, batch=64, phase="train",
+    moe_experts=16, moe_topk=2, gpu_budget=8, n_dense_layers=1,
+    d_ff_expert=512, moe_shared=2, q_lora_rank=192, kv_lora_rank=64,
+    qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32)
+WORKLOADS = {"GPT-1.7B": GPT_BENCHMARKS[0], "DeepSeek-V3": DEEPSEEK_V3,
+             "moe-2kind": SMALL_MOE}
+
+
+@pytest.mark.parametrize("wl_name,wl_case", [
+    pytest.param(w, c, id=c if w == "GPT-1.7B" else f"{w}-{c}")
+    for w in WORKLOADS for c in ("train", "prefill", "decode")])
+def test_compiled_matches_numpy_ref_bit_exact(wl_name, wl_case):
+    wl = WORKLOADS[wl_name]
     if wl_case != "train":
         wl = inference_workload(wl, wl_case, 8, 2048)
     designs, geom, nw = _designs(7, 16)
