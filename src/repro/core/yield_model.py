@@ -14,9 +14,11 @@ from __future__ import annotations
 
 import math
 from functools import lru_cache
-from typing import Sequence, Tuple, Union
+from typing import NamedTuple, Tuple
 
 import numpy as np
+
+from repro import telemetry as tm
 
 D0_PER_CM2 = 0.1                 # paper §VIII-A (IRDS)
 STRESS_LOSS = 0.1
@@ -105,18 +107,38 @@ def row_fail_cdf(ys: np.ndarray, max_count: int) -> np.ndarray:
     entry k = P(#failed cells in the row <= k). The polynomial-convolution
     DP is truncated at max_count + 1 coefficients: dropped mass only ever
     moves to *higher* counts, so the retained coefficients stay exact.
-    Padding cells with yield 1.0 leaves the DP bitwise unchanged, which is
-    what makes the batched grids below exact despite ragged row lengths.
+    Every row has W cells: the rectangular case of `ragged_row_fail_cdf`.
+    """
+    ys = np.asarray(ys, np.float64)
+    rows = ys.reshape(-1, ys.shape[-1])
+    cdf = ragged_row_fail_cdf(rows.T.ravel(),
+                              np.full(ys.shape[-1], len(rows)), max_count)
+    return cdf.reshape(ys.shape[:-1] + (max_count + 1,))
+
+
+def ragged_row_fail_cdf(ys: np.ndarray, col_rows: np.ndarray,
+                        max_count: int) -> np.ndarray:
+    """`row_fail_cdf` over R rows of unequal length, with no padding.
+
+    Rows run longest first and `ys` holds their cells column by column:
+    column j covers the first `col_rows[j]` rows (non-increasing in j).
+    Returns (R, max_count + 1). Stopping a row's DP at its own length is
+    bitwise the same as padding it with perfect cells (yield 1.0): such a
+    cell multiplies every coefficient by 1.0 and adds 0.0.
     """
     q = 1.0 - np.asarray(ys, np.float64)
-    pmf = np.zeros(q.shape[:-1] + (max_count + 1,))
-    pmf[..., 0] = 1.0
-    for i in range(q.shape[-1]):
-        qi = q[..., i, None]
-        shifted = np.zeros_like(pmf)
-        shifted[..., 1:] = pmf[..., :-1]
-        pmf = pmf * (1.0 - qi) + shifted * qi
-    return np.cumsum(pmf, axis=-1)
+    keep = 1.0 - q          # not `ys`: 1 - (1 - y) can differ from y by an ulp
+    pmf = np.zeros((max_count + 1, int(col_rows[0])))   # column 0: every row
+    pmf[0] = 1.0
+    a = 0
+    for n in col_rows:
+        b = a + int(n)
+        p = pmf[:, :n]
+        moved = p[:-1] * q[a:b]
+        p *= keep[a:b]
+        p[1:] += moved
+        a = b
+    return np.cumsum(pmf, axis=0).T
 
 
 def row_redundancy_yield(ys: np.ndarray, spares_per_row: int) -> float:
@@ -142,34 +164,52 @@ def reticle_yield(core_h_mm: float, core_w_mm: float, array: Tuple[int, int],
 STITCH_BOUNDARY_YIELD = 0.9995
 
 
+class RaggedGrids(NamedTuple):
+    """N designs' core-yield grids with no padding. Rows run widest design
+    first (a stable sort on W keeps each design's rows together, top to
+    bottom); cells run column by column, column j over the first
+    `col_rows[j]` rows, those wider than j (`ragged_row_fail_cdf`'s
+    layout)."""
+    ys: np.ndarray           # (sum H*W,) cell yields
+    col_rows: np.ndarray     # (max W,) rows reaching each column
+    order: np.ndarray        # (N,) the designs, widest first
+    first_row: np.ndarray    # (N,) first row of each design of `order`
+
+
 def core_yield_grids_batch(core_h_mm: np.ndarray, core_w_mm: np.ndarray,
                            arr_h: np.ndarray, arr_w: np.ndarray,
                            reticle_h_mm: np.ndarray,
                            reticle_w_mm: np.ndarray,
-                           tsv_region_mm2: np.ndarray) -> np.ndarray:
-    """`core_yield_grid` for N designs at once, padded to the batch max
-    (H, W) with yield 1.0 (a perfect cell never fails, so padding is inert
-    through the row-failure DP). Cell values match the scalar grid bitwise:
-    the scalar helpers (`murphy_yield`, math.hypot/sqrt) compute the
-    per-design bases, and the per-cell arithmetic broadcasts the identical
-    IEEE operations."""
+                           tsv_region_mm2: np.ndarray) -> RaggedGrids:
+    """`core_yield_grid` for N designs at once, over the cells of each
+    design's own (H, W) array only: nothing is padded to the batch's
+    largest array. Cell values match the scalar grid bitwise: the scalar
+    helpers (`murphy_yield`, math.hypot/sqrt) compute the per-design
+    bases, and the per-cell arithmetic applies the identical IEEE
+    operations to the same operands."""
     N = len(core_h_mm)
-    maxH = int(arr_h.max())
-    maxW = int(arr_w.max())
+    order = np.argsort(-arr_w, kind="stable")
+    heights = arr_h[order]
+    row_design = np.repeat(order, heights)                  # (R,)
+    first_row = np.cumsum(heights) - heights
+    row = np.arange(len(row_design)) - np.repeat(first_row, heights)
+    col, cell_row = np.nonzero(
+        np.arange(arr_w[order[0]])[:, None] < arr_w[row_design][None, :])
+    cell_design = row_design[cell_row]
+
     base = np.array([murphy_yield(float(h) * float(w))
                      for h, w in zip(core_h_mm, core_w_mm)])
-    ys = np.broadcast_to(base[:, None, None], (N, maxH, maxW)).copy()
-
-    ci = (np.arange(maxH)[None, :] + 0.5) * core_h_mm[:, None]   # (N, maxH)
-    cj = (np.arange(maxW)[None, :] + 0.5) * core_w_mm[:, None]   # (N, maxW)
+    ys = base[cell_design]
+    ci = (row + 0.5) * core_h_mm[row_design]                # (R,)
+    cj = (col + 0.5) * core_w_mm[cell_design]               # (cells,)
     half_diag = np.array([0.5 * math.hypot(float(h), float(w))
                           for h, w in zip(core_h_mm, core_w_mm)])
     zero = np.zeros(N)
     for hy, hx in ((zero, zero), (zero, reticle_w_mm),
                    (reticle_h_mm, zero), (reticle_h_mm, reticle_w_mm)):
-        d = np.sqrt((ci - hy[:, None])[:, :, None] ** 2
-                    + (cj - hx[:, None])[:, None, :] ** 2)
-        d = np.maximum(d - half_diag[:, None, None], 0.0)
+        d = np.sqrt(((ci - hy[row_design]) ** 2)[cell_row]
+                    + (cj - hx[cell_design]) ** 2)
+        d = np.maximum(d - half_diag[cell_design], 0.0)
         ys = ys * np.where(d < STRESS_DMAX_MM,
                            (STRESS_LOSS / STRESS_DMAX_MM) * d + 1 - STRESS_LOSS,
                            1.0)
@@ -178,21 +218,45 @@ def core_yield_grids_batch(core_h_mm: np.ndarray, core_w_mm: np.ndarray,
     if has_tsv.any():
         r_tsv = np.array([math.sqrt(float(a) / math.pi) if a > 0.0 else 0.0
                           for a in tsv_region_mm2])
-        d = np.sqrt((ci - reticle_h_mm[:, None] / 2)[:, :, None] ** 2
-                    + (cj - reticle_w_mm[:, None] / 2)[:, None, :] ** 2)
-        d = np.maximum(d - r_tsv[:, None, None], 0.0)
+        d = np.sqrt(((ci - reticle_h_mm[row_design] / 2) ** 2)[cell_row]
+                    + (cj - reticle_w_mm[cell_design] / 2) ** 2)
+        d = np.maximum(d - r_tsv[cell_design], 0.0)
         tsv_factor = np.where(d < TSV_DMAX_MM,
                               (TSV_LOSS / TSV_DMAX_MM) * d + 1 - TSV_LOSS,
                               1.0)
-        ys = np.where(has_tsv[:, None, None], ys * tsv_factor, ys)
+        ys = np.where(has_tsv[cell_design], ys * tsv_factor, ys)
 
-    ys = np.clip(ys, 0.0, 1.0)
-    # neutralize padding: cells outside each design's own (H, W) are perfect
-    row_pad = np.arange(maxH)[None, :] >= arr_h[:, None]
-    col_pad = np.arange(maxW)[None, :] >= arr_w[:, None]
-    ys[np.broadcast_to(row_pad[:, :, None], ys.shape)] = 1.0
-    ys[np.broadcast_to(col_pad[:, None, :], ys.shape)] = 1.0
-    return ys
+    return RaggedGrids(np.clip(ys, 0.0, 1.0),
+                       np.bincount(col, minlength=arr_w[order[0]]),
+                       order, first_row)
+
+
+def reticle_yields_batch(core_h_mm: np.ndarray, core_w_mm: np.ndarray,
+                         arr_h: np.ndarray, arr_w: np.ndarray,
+                         reticle_h_mm: np.ndarray, reticle_w_mm: np.ndarray,
+                         tsv_region_mm2: np.ndarray,
+                         max_spares: int = 4) -> np.ndarray:
+    """`reticle_yield` of N designs at every spares level 0..max_spares,
+    (N, max_spares + 1), from one unpadded grid build and one ragged
+    row-failure DP. Counts the grid's cells (`validate.yield_cells`) and
+    the designs' own cells (`validate.yield_cells_live`): equal, since
+    nothing is padded."""
+    arr_h = np.asarray(arr_h, np.int64)
+    arr_w = np.asarray(arr_w, np.int64)
+    if (arr_h < 1).any() or (arr_w < 1).any():
+        raise ValueError("every core array needs at least one row and column")
+    grids = core_yield_grids_batch(
+        np.asarray(core_h_mm, np.float64), np.asarray(core_w_mm, np.float64),
+        arr_h, arr_w, np.asarray(reticle_h_mm, np.float64),
+        np.asarray(reticle_w_mm, np.float64),
+        np.asarray(tsv_region_mm2, np.float64))
+    tm.count("validate.yield_cells", grids.ys.size)
+    tm.count("validate.yield_cells_live", int(np.sum(arr_h * arr_w)))
+    cdf = ragged_row_fail_cdf(grids.ys, grids.col_rows, max_spares)
+    rys = np.empty((len(arr_h), max_spares + 1))
+    # each design's rows are contiguous and in order: one product a design
+    rys[grids.order] = np.multiply.reduceat(cdf, grids.first_row, axis=0)
+    return rys
 
 
 def min_spares_for_target_batch(core_h_mm: np.ndarray, core_w_mm: np.ndarray,
@@ -205,23 +269,17 @@ def min_spares_for_target_batch(core_h_mm: np.ndarray, core_w_mm: np.ndarray,
                                 target: float = YIELD_TARGET,
                                 max_spares: int = 4
                                 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Vectorized `min_spares_for_target`: one yield-grid build + one
-    row-failure DP per batch resolves every spares level 0..max_spares for
-    every design simultaneously. Returns (spares (N,) int64 with -1 = no
-    level meets the target, wafer_yield (N,) float64)."""
-    core_h_mm = np.asarray(core_h_mm, np.float64)
-    core_w_mm = np.asarray(core_w_mm, np.float64)
-    arr_h = np.asarray(arr_h, np.int64)
-    arr_w = np.asarray(arr_w, np.int64)
+    """Vectorized `min_spares_for_target`: one unpadded yield-grid build +
+    one row-failure DP per batch (`reticle_yields_batch`) resolves every
+    spares level 0..max_spares for every design simultaneously. Returns
+    (spares (N,) int64 with -1 = no level meets the target, wafer_yield
+    (N,) float64)."""
     N = len(core_h_mm)
     if N == 0:
         return np.zeros(0, np.int64), np.zeros(0)
-    ys = core_yield_grids_batch(core_h_mm, core_w_mm, arr_h, arr_w,
-                                np.asarray(reticle_h_mm, np.float64),
-                                np.asarray(reticle_w_mm, np.float64),
-                                np.asarray(tsv_region_mm2, np.float64))
-    cdf = row_fail_cdf(ys, max_spares)              # (N, maxH, S+1)
-    rys = np.prod(cdf, axis=1)                      # (N, S+1) reticle yield
+    rys = reticle_yields_batch(core_h_mm, core_w_mm, arr_h, arr_w,
+                               reticle_h_mm, reticle_w_mm, tsv_region_mm2,
+                               max_spares)                  # (N, S+1)
     n_ret = np.asarray(n_reticles, np.int64)
     n_seams = 2 * n_ret                 # ~2 shared boundaries per reticle
     stitched = (rys ** n_ret[:, None]) * \

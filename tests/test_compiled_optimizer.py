@@ -9,6 +9,7 @@ reference it replaced:
     validate_batch                    vs  scalar validate (exact)
     row_redundancy_yield (exact DP)   vs  brute force + MC oracle
     min_spares_for_target_batch       vs  scalar min_spares_for_target
+    reticle_yields_batch (unpadded)   vs  scalar reticle_yield
 
 plus checkpoint-purity regressions: a LoopState pickle must never contain
 device arrays, and re-running the compiled acquire on warmed buckets must
@@ -23,6 +24,7 @@ import numpy as np
 import pytest
 
 import repro.core.mfmobo as M
+from repro import telemetry as tm
 from repro.core.design_space import DIMS, decode_batch, sample
 from repro.core.ehvi import ehvi_2d, ehvi_2d_ref
 from repro.core.gp import GP, bucket_size
@@ -32,6 +34,7 @@ from repro.core.validator import validate, validate_batch
 from repro.core.yield_model import (mc_row_redundancy_yield,
                                     min_spares_for_target,
                                     min_spares_for_target_batch,
+                                    reticle_yield, reticle_yields_batch,
                                     row_redundancy_yield)
 
 
@@ -217,9 +220,13 @@ def test_warm_optimizer_kernels_covers_campaign_buckets():
 # ---------------------------------------------------------------------------
 
 
-def test_validate_batch_matches_scalar_exactly():
-    rng = np.random.default_rng(9)
-    designs = decode_batch(sample(rng, 160))
+@pytest.mark.parametrize("seed,n", [(9, 160), (12, 256), (13, 256)],
+                         ids=["160-draws", "256-draws-a", "256-draws-b"])
+def test_validate_batch_matches_scalar_exactly(seed, n):
+    """256 draws is the trace cell's round: its live designs' core arrays
+    span every height and width band of 1..32."""
+    rng = np.random.default_rng(seed)
+    designs = decode_batch(sample(rng, n))
     batch = validate_batch(designs)
     for d, rb in zip(designs, batch):
         rs = validate(d)
@@ -250,25 +257,94 @@ def test_row_redundancy_yield_exact_and_matches_mc():
         assert abs(got - mc) < 0.05
 
 
-def test_min_spares_batch_matches_scalar():
-    rng = np.random.default_rng(11)
-    n = 24
+def _yield_batch(case, rng):
+    """Designs for the yield-resolution tests: core sizes, arrays,
+    reticles, TSV fields (some off), reticle counts and integrations."""
+    if case == "square-2-8":         # the original case: one small shape band
+        n = 24
+        ch = rng.uniform(1.0, 6.0, n)
+        cw = rng.uniform(1.0, 6.0, n)
+        ah = aw = rng.integers(2, 9, n)
+        nret = rng.integers(1, 30, n)
+        infosow = rng.random(n) < 0.5
+        tsv = rng.uniform(0.0, 5.0, n)
+        return ch, cw, ah, aw, ch * ah, cw * aw, tsv, nret, infosow
+    n = 40
     ch = rng.uniform(1.0, 6.0, n)
     cw = rng.uniform(1.0, 6.0, n)
-    arr = rng.integers(2, 9, n)
+    if case == "mixed-1-32":         # every band, both sides of 16/17
+        ah = rng.integers(1, 33, n)
+        aw = rng.integers(1, 33, n)
+        ah[:6] = [16, 17, 16, 17, 1, 32]
+        aw[:6] = [16, 16, 17, 17, 32, 1]
+    elif case == "one-band":         # all arrays within 1..16 on each side
+        ah = rng.integers(1, 17, n)
+        aw = rng.integers(1, 17, n)
+        ah[:2] = aw[:2] = 16
+    else:                            # "two-bands": short rows, any width
+        ah = rng.integers(1, 17, n)
+        aw = rng.integers(1, 33, n)
+        aw[:2] = [16, 17]
     nret = rng.integers(1, 30, n)
-    infosow = rng.random(n) < 0.5
-    rh, rw = ch * arr, cw * arr
-    tsv = rng.uniform(0.0, 5.0, n)
+    infosow = np.arange(n) % 2 == 0
+    tsv = np.where(np.arange(n) % 3 == 0, 0.0, rng.uniform(0.0, 5.0, n))
+    return ch, cw, ah, aw, ch * ah, cw * aw, tsv, nret, infosow
+
+
+@pytest.mark.parametrize(
+    "case", ["square-2-8", "mixed-1-32", "one-band", "two-bands"])
+def test_min_spares_batch_matches_scalar(case):
+    """The batch resolves spares and wafer yield bit for bit as a batch of
+    one per design (what the scalar validator runs), whatever mix of
+    array shapes shares the batch."""
+    ch, cw, ah, aw, rh, rw, tsv, nret, infosow = _yield_batch(
+        case, np.random.default_rng(11))
     spares_b, wy_b = min_spares_for_target_batch(
-        ch, cw, arr, arr, rh, rw, tsv, nret, infosow)
-    for i in range(n):
+        ch, cw, ah, aw, rh, rw, tsv, nret, infosow)
+    for i in range(len(ch)):
         s, wy = min_spares_for_target(
-            float(ch[i]), float(cw[i]), (int(arr[i]), int(arr[i])),
+            float(ch[i]), float(cw[i]), (int(ah[i]), int(aw[i])),
             (float(rh[i]), float(rw[i])), float(tsv[i]), int(nret[i]),
             "infosow" if infosow[i] else "die_stitching")
         assert spares_b[i] == s
         assert wy_b[i] == wy   # bitwise: scalar delegates to the batch path
+
+
+@pytest.mark.parametrize("case", ["mixed-1-32", "two-bands"])
+def test_reticle_yields_batch_matches_scalar_grid(case):
+    """Every spares level of the unpadded batch equals the scalar
+    `reticle_yield` (its own rectangular grid, `core_yield_grid`) bit for
+    bit."""
+    ch, cw, ah, aw, rh, rw, tsv, _, _ = _yield_batch(
+        case, np.random.default_rng(12))
+    rys = reticle_yields_batch(ch, cw, ah, aw, rh, rw, tsv)
+    for i in range(len(ch)):
+        for s in range(rys.shape[1]):
+            assert rys[i, s] == reticle_yield(
+                float(ch[i]), float(cw[i]), (int(ah[i]), int(aw[i])),
+                (float(rh[i]), float(rw[i])), float(tsv[i]), s)
+
+
+@pytest.mark.parametrize("case", ["mixed-1-32", "one-shape"])
+def test_yield_cell_counters(case):
+    """`validate.yield_cells` (grid cells built) against
+    `validate.yield_cells_live` (the designs' own cells): no padding, on a
+    mix of shapes as on one shape."""
+    if case == "one-shape":
+        ch, cw, _, _, _, _, tsv, nret, infosow = _yield_batch(
+            "mixed-1-32", np.random.default_rng(13))
+        ah = np.full(len(ch), 16)
+        aw = np.full(len(ch), 17)
+    else:
+        ch, cw, ah, aw, _, _, tsv, nret, infosow = _yield_batch(
+            case, np.random.default_rng(13))
+    tel = tm.Telemetry()
+    with tm.activate(tel):
+        min_spares_for_target_batch(ch, cw, ah, aw, ch * ah, cw * aw, tsv,
+                                    nret, infosow)
+    live = int(np.sum(ah * aw))
+    assert tel.counters["validate.yield_cells_live"] == live
+    assert tel.counters["validate.yield_cells"] == live
 
 
 # ---------------------------------------------------------------------------
