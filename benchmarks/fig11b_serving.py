@@ -2,8 +2,9 @@
 
 The paper's headline inference numbers come from serving workloads, but the
 per-figure benchmarks score isolated prefill/decode steps. This benchmark
-runs the request-level continuous-batching model (repro.core.serving,
-DESIGN.md §8) end to end:
+runs the request-level continuous-batching model (repro.core.serving: the
+request mix as the degenerate trace of repro.core.traces, DESIGN.md §8)
+end to end:
 
   (1) a design sweep scored on (SLO goodput, power) — the serving Pareto
       front, with the SLO calibrated from the sampled designs' median
@@ -26,9 +27,14 @@ import numpy as np
 
 from benchmarks.common import sample_valid_designs, save_artifact
 from repro.core.design_space import WSCDesign
-from repro.core.heterogeneity import evaluate_hetero_serving
+from repro.core.heterogeneity import evaluate_hetero_serving_batch
 from repro.core.pareto import pareto_front, to_max_space
-from repro.core.serving import ServingSLO, evaluate_serving_batch
+from repro.core.serving import ServingSLO
+from repro.core.traces import (
+    RequestTrace,
+    TenantClass,
+    evaluate_pool_serving_batch,
+)
 from repro.core.validator import validate
 from repro.core.workload import GPT_BENCHMARKS, RequestMix
 from repro.explore import (
@@ -68,16 +74,20 @@ def run(quick: bool = False) -> Dict:
 
     # ---- (1) design sweep + SLO calibration ----------------------------
     designs = sample_valid_designs(12 if quick else 48, seed=11)
-    probe = evaluate_serving_batch(designs, wl, mix, ServingSLO(1e9, 1e9),
-                                   slots=slots, max_strategies=8)
+
+    def score(slo):
+        trace = RequestTrace.from_mix(mix, TenantClass.from_slo(slo))
+        return evaluate_pool_serving_batch(designs, wl, trace, slots=slots,
+                                           max_strategies=8)
+
+    probe = score(ServingSLO(1e9, 1e9))
     feas = [r for r in probe if r.feasible]
     if not feas:
         raise RuntimeError("no feasible serving design in the probe pool")
     slo = ServingSLO(
         ttft_s=float(np.median([r.ttft_s for r in feas])),
         tpot_s=float(np.median([r.tpot_s for r in feas])))
-    scored = evaluate_serving_batch(designs, wl, mix, slo, slots=slots,
-                                    max_strategies=8)
+    scored = score(slo)
     rows = [{"goodput_tok_s": r.goodput_tok_s, "power_w": r.power_w,
              "ttft_s": r.ttft_s, "tpot_s": r.tpot_s,
              "slo_attainment": r.slo_attainment, "n_wafers": r.n_wafers}
@@ -112,8 +122,9 @@ def run(quick: bool = False) -> Dict:
     hetero = []
     for gran in ("core", "reticle", "wafer"):
         dp = d_decode if gran == "core" else d_prefill
-        h = evaluate_hetero_serving(dp, d_decode, wl, gran, 0.5, mix, slo,
-                                    slots=slots, n_wafers=8)
+        (h,) = evaluate_hetero_serving_batch([dp], [d_decode], wl, gran, 0.5,
+                                             mix, slo, slots=slots,
+                                             n_wafers=8)
         hetero.append({"granularity": gran,
                        "goodput_tok_s": h.goodput_tok_s,
                        "ttft_s": h.ttft_s, "tpot_s": h.tpot_s,

@@ -14,11 +14,10 @@ simulator itself as f0 through its batched backend.
 import argparse
 
 from repro.core.baselines import DOJO_LIKE, WSE2_LIKE, gpu_cluster_eval
-from repro.core.evaluator import (batched_objectives, evaluate_design,
-                                  registered_backends)
-from repro.core.mfmobo import run_mfmobo
+from repro.core.evaluator import evaluate_design, registered_backends
 from repro.core.validator import validate
 from repro.core.workload import GPT_BENCHMARKS
+from repro.explore import EvaluatorObjective, ExplorationLoop, LoopConfig
 
 
 def main():
@@ -35,7 +34,7 @@ def main():
     print(f"workload: {wl.name} training, batch {wl.batch} x seq {wl.seq}, "
           f"GPU budget {wl.gpu_budget}, f0 fidelity: {args.fidelity}")
 
-    f1 = batched_objectives(wl, "analytical")
+    f1 = EvaluatorObjective(wl, "analytical")
     on_handover = None
     if args.fidelity == "gnn":
         import jax
@@ -46,15 +45,15 @@ def main():
         cal = GNNCalibrator(init_gnn(jax.random.PRNGKey(0)), wl,
                             n_designs=3 if args.quick else 6,
                             epochs=5 if args.quick else 20)
-        f0 = cal.objectives()
+        f0 = EvaluatorObjective(wl, "gnn", params_fn=lambda: cal.params)
         on_handover = cal.on_handover
     else:
-        f0 = batched_objectives(wl, args.fidelity)
-    tr = run_mfmobo(f0, f1, d0=2, d1=3, k=3,
-                    N0=6 if args.quick else 14,
-                    N1=8 if args.quick else 18,
-                    n_candidates=64, q=2 if args.quick else 4, seed=0,
-                    on_handover=on_handover)
+        f0 = EvaluatorObjective(wl, args.fidelity)
+    cfg = LoopConfig(strategy="mfmobo", d0=2, d1=3, k=3,
+                     N0=6 if args.quick else 14,
+                     N1=8 if args.quick else 18,
+                     n_candidates=64, q=2 if args.quick else 4, seed=0)
+    tr = ExplorationLoop(cfg, f0, f1=f1, on_handover=on_handover).run()
     front = tr.pareto()
     print(f"\nexplored {len(tr.ys)} high-fidelity designs; "
           f"hypervolume {tr.hv[0]:.2f} -> {tr.hv[-1]:.2f}")

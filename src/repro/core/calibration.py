@@ -3,9 +3,10 @@
 The paper trains the f0 congestion model offline on simulator traces
 (§VI-C) and MFMOBO then trusts it for the bulk of the budget. A fixed
 checkpoint is only as good as its training distribution, so this module
-closes the loop: right before `run_mfmobo` evaluates its first GNN-fidelity
-point (`on_handover` — fired ahead of the f0 prior batch, so no recorded
-f0 objective ever comes from uncalibrated params), the calibrator
+closes the loop: right before the MFMOBO loop evaluates its first
+GNN-fidelity point (`on_handover` — fired ahead of the f0 prior batch, so
+no recorded f0 objective ever comes from uncalibrated params), the
+calibrator
 
   1. picks the Pareto neighborhood of everything evaluated so far —
      the nondominated designs first, then the points closest to the front
@@ -18,8 +19,9 @@ f0 objective ever comes from uncalibrated params), the calibrator
      validation split, early-stopping on validation loss (`train_gnn`'s
      patience machinery).
 
-The calibrator's objective function reads `self.params` at call time, so
-the fine-tuned parameters take effect for every f0 evaluation after the
+The f0 objective reads the calibrator's `params` at call time
+(`EvaluatorObjective(..., params_fn=lambda: cal.params)`), so the
+fine-tuned parameters take effect for every f0 evaluation after the
 handover — and the evaluator's params-version token gives the new pytree
 its own cache namespace automatically.
 """
@@ -106,10 +108,14 @@ class CalibrationRecord:
 
 class GNNCalibrator:
     """Holds the live GNN parameters for the f0 objective and fine-tunes
-    them at the fidelity handover. Use:
+    them at the fidelity handover. The f0 objective reads them at call
+    time, so post-handover evaluations use the fine-tuned pytree (and its
+    fresh cache namespace):
 
         cal = GNNCalibrator(params, wl)
-        tr = run_mfmobo(cal.objectives(), f1, on_handover=cal.on_handover)
+        f0 = EvaluatorObjective(wl, "gnn", params_fn=lambda: cal.params)
+        tr = ExplorationLoop(LoopConfig(strategy="mfmobo"), f0, f1=f1,
+                             on_handover=cal.on_handover).run()
     """
 
     def __init__(self, params: Dict, wl: LLMWorkload, *,
@@ -125,15 +131,6 @@ class GNNCalibrator:
         self.patience = patience
         self.seed = seed
         self.records: List[CalibrationRecord] = []
-
-    def objectives(self):
-        """Batch-aware f0 objective reading the latest calibrated params —
-        an `EvaluatorObjective` whose `params_fn` dereferences this
-        calibrator at call time, so post-handover evaluations automatically
-        use the fine-tuned pytree (and its fresh cache namespace)."""
-        from repro.explore.objectives import EvaluatorObjective
-        return EvaluatorObjective(self.wl, "gnn",
-                                  params_fn=lambda: self.params)
 
     def on_handover(self, designs: Sequence[WSCDesign],
                     ys: Sequence[Tuple[float, float]]) -> None:

@@ -462,6 +462,7 @@ class StrategySpace:
     def for_workload(cls, wl, total_cores: int) -> "StrategySpace":
         """Derive the axis caps from the workload and the largest system
         under search (`compiler.derived_strategy_caps`)."""
+        # compiler imports this module
         from repro.core.compiler import derived_strategy_caps
         caps = derived_strategy_caps(wl, total_cores)
         train = wl.phase == "train"
@@ -506,7 +507,7 @@ class StrategySpace:
 
     def decode_strategy(self, u_s: np.ndarray):
         """(7,) strategy columns -> compiler.Strategy."""
-        from repro.core.compiler import Strategy
+        from repro.core.compiler import Strategy  # compiler imports this
         a = self.decode_arrays(np.asarray(u_s)[None, :])
         return Strategy(int(a["tp"][0]), int(a["pp"][0]), int(a["dp"][0]),
                         int(a["mb"][0]), ep=int(a["ep"][0]),
@@ -564,7 +565,7 @@ def decode_joint_batch(U: np.ndarray, space: StrategySpace,
                        ) -> List[JointDesign]:
     """Vectorized joint decode: architecture columns through `decode_batch`,
     strategy columns through the space codec."""
-    from repro.core.compiler import Strategy
+    from repro.core.compiler import Strategy  # compiler imports this
     U = np.atleast_2d(np.asarray(U, np.float64))
     designs = decode_batch(U[:, :len(DIMS)], max_core_dim, max_ret_dim)
     a = space.decode_arrays(U[:, len(DIMS):])

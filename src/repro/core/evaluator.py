@@ -34,6 +34,7 @@ import numpy as np
 
 from repro import telemetry as tm
 from repro.core import components as C
+from repro.core import eval_compiled
 from repro.core.evalcache import (
     DiskSegmentEvalCache,
     EvalCacheBackend,
@@ -335,8 +336,6 @@ def evaluate_pool_fused(pool_designs: Sequence[WSCDesign], wl: LLMWorkload,
     trade: re-scoring q rows in-program is cheaper than a host round-trip
     to decide whether to score them. Values are interchangeable because
     the compiled pipeline is bit-identical to the reference."""
-    from repro.core import eval_compiled
-
     pool = list(pool_designs)
     with tm.span("evaluate.analytical.prepare", items=len(pool)):
         geom = DesignBatch.from_designs(pool)
@@ -441,7 +440,6 @@ def evaluate_pool_fused_joint(pool_points, wl: LLMWorkload,
     is (design, strategy) points, and the fused program gathers both the
     geometry rows and the pinned strategy columns by the device-resident
     pick indices. Same get-per-pick / batched set_many cache protocol."""
-    from repro.core import eval_compiled
 
     points = list(pool_points)
     designs = [p.design for p in points]
@@ -498,54 +496,12 @@ def evaluate_objectives_batch(designs: Sequence[WSCDesign], wl: LLMWorkload,
     return out
 
 
-def evaluate_serving_batch(designs: Sequence[WSCDesign],
-                           wl_base: LLMWorkload, mix, slo, **kw):
-    """Request-level serving metrics (TTFT / TPOT / SLO goodput) for N
-    designs through the fidelity registry — the serving counterpart of
-    `evaluate_design_batch`. Thin forwarder to `repro.core.serving`
-    (imported lazily: serving composes this module's batched per-step
-    evaluations, so a top-level import would be circular)."""
-    from repro.core import serving
-    return serving.evaluate_serving_batch(designs, wl_base, mix, slo, **kw)
-
-
-def evaluate_trace_serving_batch(designs, wl_base: LLMWorkload, trace,
-                                 **kw):
-    """Trace-driven multi-tenant serving metrics (per-tenant SLO goodput,
-    worst-window goodput, admission/routing policies) for N designs — the
-    timed-arrival counterpart of `evaluate_serving_batch`. Thin forwarder
-    to `repro.core.traces` (lazy import, same layering as serving)."""
-    from repro.core import traces
-    return traces.evaluate_trace_serving_batch(designs, wl_base, trace,
-                                               **kw)
-
-
-def serving_objectives(wl_base: LLMWorkload, mix, slo, **kw):
-    """Batch-aware (SLO goodput, power) explorer objective — forwarder to
-    `repro.core.serving.serving_objectives` (lazy import, see above)."""
-    from repro.core import serving
-    return serving.serving_objectives(wl_base, mix, slo, **kw)
-
-
-def batched_objectives(wl: LLMWorkload, fidelity: Fidelity = "analytical",
-                       gnn_params: Optional[Dict] = None):
-    """Batch-aware (throughput, power-per-wafer) objective for the
-    explorer. Subsumed by the campaign Objectives protocol — this is now a
-    thin constructor for `repro.explore.objectives.EvaluatorObjective`
-    (lazy import: repro.explore layers on top of this module). `fidelity`
-    may be a registered name or a FidelityBackend instance."""
-    from repro.explore.objectives import EvaluatorObjective
-    return EvaluatorObjective(wl, fidelity, gnn_params=gnn_params)
-
-
 __all__ = [
-    "EvalResult", "Fidelity", "batched_objectives", "clear_eval_cache",
-    "configure_eval_cache", "eval_cache_stats", "evaluate_design",
-    "evaluate_design_batch", "evaluate_joint_batch", "evaluate_objectives",
+    "EvalResult", "Fidelity", "clear_eval_cache", "configure_eval_cache",
+    "eval_cache_stats", "evaluate_design", "evaluate_design_batch",
+    "evaluate_joint_batch", "evaluate_objectives",
     "evaluate_objectives_batch", "evaluate_pool_fused",
-    "evaluate_pool_fused_joint", "evaluate_serving_batch",
-    "evaluate_trace_serving_batch",
-    "get_backend", "get_eval_cache_backend", "gnn_params_digest",
-    "gnn_params_token", "registered_backends", "serving_objectives",
+    "evaluate_pool_fused_joint", "get_backend", "get_eval_cache_backend",
+    "gnn_params_digest", "gnn_params_token", "registered_backends",
     "set_eval_cache_backend", "wafers_for_budget",
 ]

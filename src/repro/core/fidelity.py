@@ -479,6 +479,7 @@ class AnalyticalBackend:
                        gnn_params: Optional[Dict] = None,
                        strategies: Optional[List[Strategy]] = None
                        ) -> List[EvalResult]:
+        # eval_compiled imports this module (EvalResult)
         from repro.core import eval_compiled
         if eval_compiled.enabled():
             if strategies is not None:
@@ -557,20 +558,3 @@ register_backend(AnalyticalBackend())
 register_backend(GNNBackend())
 register_backend(SimBackend())
 
-
-def evaluate_serving_batch(designs, wl, mix, slo, **kw):
-    """Request-level serving evaluation (TTFT / TPOT / SLO goodput) against
-    any registered backend — every fidelity that can score per-step
-    prefill/decode workloads can score a serving workload. Forwarder to
-    `repro.core.serving` (lazy import: serving builds on this registry)."""
-    from repro.core.serving import evaluate_serving_batch as _impl
-    return _impl(designs, wl, mix, slo, **kw)
-
-
-def evaluate_trace_serving_batch(designs, wl, trace, **kw):
-    """Trace-driven, multi-tenant serving evaluation (timed arrivals,
-    per-tenant SLOs, admission/routing policies) against any registered
-    backend — the timed counterpart of `evaluate_serving_batch`. Forwarder
-    to `repro.core.traces` (lazy import: traces builds on this registry)."""
-    from repro.core.traces import evaluate_trace_serving_batch as _impl
-    return _impl(designs, wl, trace, **kw)

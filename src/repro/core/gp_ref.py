@@ -16,6 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.ehvi import ehvi_2d_ref
+from repro.core.pareto import pareto_front
+
 
 def _matern52(x1, x2, ls, sf):
     d = jnp.sqrt(jnp.maximum(
@@ -115,9 +118,6 @@ def acquire_batch_ref(models: Tuple[NumpyGP, NumpyGP], cand_x: np.ndarray,
                       q: int = 1) -> List[int]:
     """Greedy q-EHVI with rank-1 fantasization — the pre-compilation
     `_acquire_batch` loop, kept as the oracle for the scanned JAX version."""
-    from repro.core.ehvi import ehvi_2d_ref
-    from repro.core.pareto import pareto_front
-
     g_t, g_p = models
     fantasy_pts = np.asarray(evaluated, float).reshape(-1, 2)
     chosen: List[int] = []

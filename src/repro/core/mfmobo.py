@@ -12,40 +12,42 @@ notes CA simulation is kept out of the loop for cost), GP surrogates per
 Each iteration proposes a batch of q candidates by greedy q-EHVI with
 fantasized observations (DESIGN.md §5): pick the EHVI argmax, condition the
 GPs on its posterior mean (GP.condition_on), extend the fantasy front, and
-repeat — then evaluate the whole batch in one call. Objectives follow the
-`repro.explore.objectives.Objective` protocol (`eval_many(designs)`);
-legacy callables — scalar (design -> (throughput, power)) functions or
-batch-aware functions marked `.batched = True` — are coerced at entry by
-`as_objective`. With q=1 the loop is the paper's serial Algorithm 1.
+repeat — then evaluate the whole batch in one call. With q=1 the loop is
+the paper's serial Algorithm 1.
 
 This module keeps the algorithmic primitives (Trace, GP fitting in the
-log-objective space, greedy q-EHVI acquisition, valid-candidate sampling);
-the loop itself lives in `repro.explore.runner.ExplorationLoop` — a
-resumable state machine that campaigns (repro.explore.campaign) checkpoint
-and resume. `run_mfmobo` / `run_mobo` / `run_random` are thin wrappers
-over that loop with their historical signatures and rng-consumption order
-(traces are bit-identical to the pre-campaign implementations).
+log-objective space, greedy q-EHVI acquisition, valid-candidate sampling).
+The loop itself, with the Fig. 8 baselines (random search and
+single-fidelity MOBO), lives one layer up in
+`repro.explore.runner.ExplorationLoop` — a resumable state machine that
+campaigns (repro.explore.campaign) checkpoint and resume:
 
-Baselines for Fig. 8: random search and single-fidelity MOBO.
+    ExplorationLoop(LoopConfig(strategy="mfmobo", N0=..., N1=..., q=...),
+                    f0, f1=f1, on_handover=...).run()   # -> Trace
 """
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry as tm
-from repro.core.design_space import WSCDesign, decode_batch, sample
+from repro.core import eval_compiled
+from repro.core.compiler import (Strategy, _strategy_grid,
+                                 strategy_memory_need)
+from repro.core.design_space import (DIMS, DesignBatch, JointDesign,
+                                     WSCDesign, decode_batch,
+                                     decode_joint_batch, sample,
+                                     sample_joint)
 from repro.core.ehvi import ehvi_padded
+from repro.core.evaluator import wafers_for_budget
 from repro.core.gp import GP, _predict_jit, _rank1_jit, bucket_size
 from repro.core.pareto import pareto_front, to_max_space
-from repro.core.validator import validate_batch
-
-EvalFn = Callable[[WSCDesign], Tuple[float, float]]   # -> (throughput, power)
+from repro.core.validator import validate_batch, validate_joint_batch
 
 
 @dataclasses.dataclass
@@ -75,15 +77,6 @@ class Trace:
             n = sc.get("hits", 0) + sc.get("misses", 0)
             out[stage] = sc.get("hits", 0) / n if n else 0.0
         return out
-
-
-def _eval_many(f: EvalFn, designs: Sequence[WSCDesign]
-               ) -> List[Tuple[float, float]]:
-    """Legacy shim: objective coercion (including the old `.batched`
-    attribute sniff) now lives in `repro.explore.objectives.as_objective`;
-    the exploration loop calls `Objective.eval_many` directly."""
-    from repro.explore.objectives import as_objective
-    return as_objective(f).eval_many(list(designs))
 
 
 def _valid_candidates(rng: np.random.Generator, n: int,
@@ -129,11 +122,6 @@ def _grid_seed_strategies(designs, wl, space):
     validator would reject the plain row with "strategy_memory", so the
     fallback keeps the seed alive and hands q-EHVI a live recompute
     signal."""
-    from repro.core.compiler import (Strategy, _strategy_grid,
-                                     strategy_memory_need)
-    from repro.core.design_space import DesignBatch
-    from repro.core.evaluator import wafers_for_budget
-
     g = _strategy_grid(wl)
     db = DesignBatch.from_designs(list(designs))
     nw = np.array([wafers_for_budget(d, wl) for d in designs], np.float64)
@@ -171,10 +159,6 @@ def _valid_candidates_joint(rng: np.random.Generator, n: int, space, wl,
     feasible row), validate architecture + strategy together
     (`validate_joint_batch`, `repro.dist` oracle included), and return
     (encoded points, JointDesigns with spares resolved)."""
-    from repro.core.design_space import (DIMS, JointDesign,
-                                         decode_joint_batch, sample_joint)
-    from repro.core.validator import validate_joint_batch
-
     nd = len(DIMS)
     xs, pts = [], []
     n_drawn = 0
@@ -349,7 +333,6 @@ def warm_optimizer_kernels(n_obs_max: int, n_candidates: int = 256,
     same per-(bucket, workload-shape) memoization and `force=` semantics):
     the design-axis buckets up to `n_designs_max` (defaults to the q
     bucket) plus the fused gather program for the `n_candidates` pool."""
-    from repro.core.design_space import DIMS
     d = len(DIMS) if dim is None else dim
     rng = np.random.default_rng(0)
     qpad = bucket_size(max(1, min(q, n_candidates)), minimum=4)
@@ -371,7 +354,6 @@ def warm_optimizer_kernels(n_obs_max: int, n_candidates: int = 256,
         cand = rng.random((n_candidates, d))
         _acquire_batch(models, cand, ev, hv_ref(1e4), q=q)
     if workload is not None:
-        from repro.core import eval_compiled
         warmed += eval_compiled.warm_evaluator_kernels(
             workload, n_designs_max=max(int(n_designs_max), qpad),
             max_strategies=max_strategies, pool_sizes=(n_candidates,),
@@ -390,54 +372,3 @@ def hv_ref(peak_power: float) -> np.ndarray:
     """Hypervolume reference point (throughput 0, peak power)."""
     return np.array([0.0, -np.log(max(peak_power, 1.0))])
 
-
-# legacy underscore aliases (pre-existing tests import these)
-_obj_space = obj_space
-_hv_ref = hv_ref
-
-
-def run_mfmobo(f0: EvalFn, f1: EvalFn, *, d0: int = 3, d1: int = 3,
-               k: int = 5, N0: int = 20, N1: int = 30,
-               peak_power: float = 15000.0, n_candidates: int = 256,
-               q: int = 1, seed: int = 0,
-               on_handover: Optional[Callable[
-                   [List[WSCDesign], List[Tuple[float, float]]], None]] = None
-               ) -> Trace:
-    """Paper Algorithm 1 (+ q-batching, DESIGN.md §5). `on_handover`, if
-    given, fires once immediately before the FIRST f0 evaluation (the d0
-    prior batch), with every f1-evaluated design and its objectives — the
-    hook the online GNN calibration loop (calibration.py) uses to fine-tune
-    f0 on simulator traces from the current Pareto neighborhood, so every
-    recorded f0 objective (priors included — they seed the trace, the front
-    and M0's training set permanently) comes from calibrated params.
-
-    Thin wrapper over `repro.explore.runner.ExplorationLoop` (DESIGN.md
-    §9); use a `repro.explore.Campaign` instead when the run should be
-    serializable / checkpointable / resumable."""
-    from repro.explore.runner import ExplorationLoop, LoopConfig
-    cfg = LoopConfig(strategy="mfmobo", N0=N0, N1=N1, d0=d0, d1=d1, k=k,
-                     q=q, n_candidates=n_candidates, peak_power=peak_power,
-                     seed=seed)
-    return ExplorationLoop(cfg, f0, f1=f1, on_handover=on_handover).run()
-
-
-def run_mobo(f0: EvalFn, *, d0: int = 6, N: int = 20,
-             peak_power: float = 15000.0, n_candidates: int = 256,
-             q: int = 1, seed: int = 0) -> Trace:
-    """Single-fidelity MOBO baseline (paper Fig. 8)."""
-    from repro.explore.runner import ExplorationLoop, LoopConfig
-    cfg = LoopConfig(strategy="mobo", N0=N, d0=d0, q=q,
-                     n_candidates=n_candidates, peak_power=peak_power,
-                     seed=seed)
-    return ExplorationLoop(cfg, f0).run()
-
-
-def run_random(f0: EvalFn, *, N: int = 20, peak_power: float = 15000.0,
-               seed: int = 0) -> Trace:
-    from repro.explore.runner import ExplorationLoop, LoopConfig
-    # q=N: evaluate the whole sampled pool in one batch call, exactly like
-    # the pre-campaign implementation (campaigns chunk by q instead, for
-    # checkpoint granularity)
-    cfg = LoopConfig(strategy="random", N0=N, q=N, peak_power=peak_power,
-                     seed=seed)
-    return ExplorationLoop(cfg, f0).run()

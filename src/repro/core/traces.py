@@ -36,7 +36,10 @@ searchable object (DESIGN.md §14):
     (`explore.objectives.TraceServingObjective`, scenario
     ``"trace_serving"``). `PolicyDesign` pairs a design with a policy so
     the policy axis rides the search encoding next to the 13
-    architecture dims.
+    architecture dims. Its pool policies run through
+    `evaluate_pool_serving_batch`, which also scores the request-mix
+    scenario (``"serving"``) as the degenerate trace: one serving
+    evaluator for both.
 
 The schedule semantics mirror `repro.serve.engine.ServeEngine` with timed
 submission (`submit_at`) and the same policies; `serve.engine.replay_trace`
@@ -53,9 +56,10 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro import telemetry as tm
-from repro.core.design_space import WSCDesign
-from repro.core.fidelity import FidelityBackend
-from repro.core.serving import ServingSLO
+from repro.core.design_space import WSCDesign, decode_batch, sample
+from repro.core.evaluator import evaluate_design_batch
+from repro.core.fidelity import FidelityBackend, get_backend
+from repro.core.validator import validate_batch
 from repro.core.workload import LLMWorkload, RequestMix
 
 Fidelity = Union[str, FidelityBackend]
@@ -92,8 +96,16 @@ class TenantClass:
         if self.ttft_s <= 0 or self.tpot_s <= 0:
             raise ValueError(f"tenant {self.name!r} SLO bounds must be > 0")
 
-    def slo(self) -> ServingSLO:
-        return ServingSLO(ttft_s=self.ttft_s, tpot_s=self.tpot_s)
+    @classmethod
+    def from_slo(cls, slo) -> "TenantClass":
+        """The one tenant of a request mix scored as the degenerate trace,
+        bound by a `serving.ServingSLO`. TTFT and TPOT are positive, so a
+        bound that is not > 0 (which `ServingSLO` takes and this class
+        refuses) and the smallest positive float admit the same requests:
+        none."""
+        tiny = float(np.nextafter(0.0, 1.0))
+        return cls("default", ttft_s=slo.ttft_s if slo.ttft_s > 0 else tiny,
+                   tpot_s=slo.tpot_s if slo.tpot_s > 0 else tiny)
 
     def to_dict(self) -> Dict:
         return dataclasses.asdict(self)
@@ -911,9 +923,6 @@ def sample_policy_candidates(rng: np.random.Generator, n: int,
     to an admission policy: returns ((n, 14) encoded points, PolicyDesigns)
     — campaigns with a searched policy axis install this as the
     exploration loop's candidate_fn."""
-    from repro.core.design_space import decode_batch, sample
-    from repro.core.validator import validate_batch
-
     policies = tuple(policies)
     if not policies or any(p not in POLICIES for p in policies):
         raise ValueError(f"policies must be a nonempty subset of {POLICIES} "
@@ -964,18 +973,17 @@ class TraceServingResult:
     reason: str = ""
 
 
-def trace_serving_workloads(wl_base: LLMWorkload, trace: RequestTrace,
-                            slots: int
-                            ) -> Tuple[LLMWorkload, LLMWorkload, int]:
-    """The two per-step workloads trace serving composes — identical
-    convention to `serving.serving_workloads`, sized from the trace.
-    One uniform layer only: the schedule's prefill and decode costs and
-    the KV hand-off scale from it."""
-    wl_base.require_uniform("the trace scheduler's stage scoring")
-    p_ref = max(1, int(round(trace.mean_prompt)))
+def serving_workloads(wl_base: LLMWorkload,
+                      requests: Union[RequestMix, RequestTrace], slots: int
+                      ) -> Tuple[LLMWorkload, LLMWorkload, int]:
+    """The two per-step workloads serving composes, sized from a request
+    mix or a trace: a single-prompt prefill at the mean prompt length (the
+    engine prefills one request at a time) and a `slots`-wide decode step
+    at the mid-generation context."""
+    p_ref = max(1, int(round(requests.mean_prompt)))
     wl_p = dataclasses.replace(wl_base, phase="prefill", batch=1, seq=p_ref)
     wl_d = dataclasses.replace(wl_base, phase="decode", batch=slots,
-                               seq=trace.context_len())
+                               seq=requests.context_len())
     return wl_p, wl_d, p_ref
 
 
@@ -1024,6 +1032,16 @@ def _schedule_cached(trace: RequestTrace, slots: int,
     return _SCHED_CACHE[key]
 
 
+def _split_policies(designs, policy: str
+                    ) -> Tuple[List[WSCDesign], List[str]]:
+    """Each candidate's design and policy: a `PolicyDesign` carries its
+    own, a plain design is scored under `policy`."""
+    raw = [d.design if isinstance(d, PolicyDesign) else d for d in designs]
+    pols = [d.policy if isinstance(d, PolicyDesign) else policy
+            for d in designs]
+    return raw, pols
+
+
 def evaluate_trace_serving_batch(
         designs: Sequence[Union[WSCDesign, PolicyDesign]],
         wl_base: LLMWorkload, trace: RequestTrace, *, slots: int = 8,
@@ -1033,31 +1051,24 @@ def evaluate_trace_serving_batch(
         max_strategies: int = 24) -> List[TraceServingResult]:
     """Trace-driven serving metrics for N candidates. Candidates are
     `WSCDesign`s (scored under `policy`) or `PolicyDesign`s (each scored
-    under its own policy — the searched axis). Pool policies share one
-    design-independent `trace_schedule` per policy and broadcast
-    `trace_serving_metrics` over the candidate axis. "Disaggregated"
-    candidates go, all in one call, through
+    under its own policy — the searched axis). Pool candidates go to
+    `evaluate_pool_serving_batch`. "Disaggregated" candidates go, all in
+    one call, through
     `heterogeneity.evaluate_hetero_trace_serving_batch`'s coupled
     prefill/decode-split model (reticle granularity, `prefill_ratio`):
-    their stages are scored by the batched evaluator at the scalar path's
-    cap of `TRACE_STAGE_MAX_STRATEGIES` (24), not at `max_strategies`,
-    which applies to the pool candidates. Results keep the input order."""
-    from repro.core.fidelity import get_backend
+    their stages are scored by the batched evaluator at
+    `heterogeneity.STAGE_MAX_STRATEGIES` (24), not at `max_strategies`,
+    which applies to the pool candidates. Results keep the input order.
 
+    One uniform layer only: the trace scheduler sizes its stage costs and
+    the KV hand-off from it (the request-mix scenario, which enters at
+    `evaluate_pool_serving_batch`, takes a table of layer kinds)."""
     wl_base.require_uniform("the trace scheduler's stage scoring")
     backend = get_backend(fidelity)
     designs = list(designs)
     if not designs:
         return []
-    raw: List[WSCDesign] = []
-    pols: List[str] = []
-    for d in designs:
-        if isinstance(d, PolicyDesign):
-            raw.append(d.design)
-            pols.append(d.policy)
-        else:
-            raw.append(d)
-            pols.append(policy)
+    raw, pols = _split_policies(designs, policy)
     for p in pols:
         if p not in POLICIES:
             raise ValueError(f"policy {p!r} not in {POLICIES}")
@@ -1067,6 +1078,7 @@ def evaluate_trace_serving_batch(
     # ---- disaggregated candidates: batched stages, coupled split model --
     dis = [i for i, p in enumerate(pols) if p == "disaggregated"]
     if dis:
+        # heterogeneity imports this module (results, metrics, workloads)
         from repro.core.heterogeneity import (
             evaluate_hetero_trace_serving_batch,
         )
@@ -1079,27 +1091,56 @@ def evaluate_trace_serving_batch(
         for i, r in zip(dis, rs):
             results[i] = r
 
-    # ---- pool candidates: shared schedule per policy, broadcast math ---
     pool = [i for i, p in enumerate(pols) if p != "disaggregated"]
-    if not pool:
-        return results                      # type: ignore[return-value]
-    from repro.core.evaluator import evaluate_design_batch
-    with tm.span("evaluate.trace.pool", items=len(pool)):
-        wl_p, wl_d, p_ref = trace_serving_workloads(wl_base, trace, slots)
-        with tm.span("evaluate.trace.steps", items=len(pool)):
+    if pool:
+        rs = evaluate_pool_serving_batch(
+            [designs[i] for i in pool], wl_base, trace, slots=slots,
+            policy=policy, window_steps=window_steps, fidelity=backend,
+            gnn_params=gnn_params, n_wafers=n_wafers,
+            max_strategies=max_strategies)
+        for i, r in zip(pool, rs):
+            results[i] = r
+    return results                          # type: ignore[return-value]
+
+
+def evaluate_pool_serving_batch(
+        designs: Sequence[Union[WSCDesign, PolicyDesign]],
+        wl_base: LLMWorkload, trace: RequestTrace, *, slots: int = 8,
+        policy: str = "fifo", window_steps: int = 64,
+        fidelity: Fidelity = "analytical",
+        gnn_params: Optional[Dict] = None, n_wafers=None,
+        max_strategies: int = 24) -> List[TraceServingResult]:
+    """Shared-decode-pool serving metrics for N candidates under the pool
+    policies: two registry-batched per-step evaluations (prefill, decode),
+    one design-independent `trace_schedule` per policy, and
+    `trace_serving_metrics` broadcast over the candidate axis. The
+    request-mix scenario is its degenerate case: `RequestTrace.from_mix`
+    under one `TenantClass.from_slo` tenant and "fifo" (DESIGN.md §8).
+    Results keep the input order."""
+    backend = get_backend(fidelity)
+    designs = list(designs)
+    if not designs:
+        return []
+    raw, pols = _split_policies(designs, policy)
+    for p in pols:
+        if p not in POOL_POLICIES:
+            raise ValueError(f"policy {p!r} not in {POOL_POLICIES}")
+    results: List[Optional[TraceServingResult]] = [None] * len(designs)
+    with tm.span("evaluate.trace.pool", items=len(designs)):
+        wl_p, wl_d, p_ref = serving_workloads(wl_base, trace, slots)
+        with tm.span("evaluate.trace.steps", items=len(designs)):
             kw = dict(fidelity=backend, gnn_params=gnn_params,
                       n_wafers=n_wafers, max_strategies=max_strategies)
-            rps = evaluate_design_batch([raw[i] for i in pool], wl_p, **kw)
-            rds = evaluate_design_batch([raw[i] for i in pool], wl_d, **kw)
-        for pol in sorted({pols[i] for i in pool}):
-            grp = [j for j, i in enumerate(pool) if pols[i] == pol]
+            rps = evaluate_design_batch(raw, wl_p, **kw)
+            rds = evaluate_design_batch(raw, wl_d, **kw)
+        for pol in sorted(set(pols)):
+            grp = [j for j, p in enumerate(pols) if p == pol]
             feas = [j for j in grp if rps[j].feasible and rds[j].feasible]
             for j in grp:
                 if j not in feas:
                     reason = ("prefill_" if not rps[j].feasible else
                               "decode_") + "infeasible"
-                    results[pool[j]] = _infeasible(pol, rps[j].n_wafers,
-                                                   reason)
+                    results[j] = _infeasible(pol, rps[j].n_wafers, reason)
             if not feas:
                 continue
             with tm.span("evaluate.trace.schedule"):
@@ -1118,7 +1159,7 @@ def evaluate_trace_serving_batch(
             energy = e_p * ctx_sum / p_ref + e_d * sched.n_decode_steps
             power = energy / np.maximum(m["total_time"], 1e-12)
             for c, j in enumerate(feas):
-                results[pool[j]] = TraceServingResult(
+                results[j] = TraceServingResult(
                     feasible=True, policy=pol,
                     goodput_tok_s=float(m["goodput"][c]),
                     interactive_goodput_tok_s=float(
@@ -1153,9 +1194,9 @@ def evaluate_trace_serving(design, wl_base: LLMWorkload,
 __all__ = [
     "DEFAULT_TENANT", "POLICIES", "POOL_POLICIES", "PolicyDesign",
     "RequestTrace", "TenantClass", "TraceSchedule", "TraceServingResult",
-    "diurnal_trace", "evaluate_trace_serving",
-    "evaluate_trace_serving_batch", "poisson_trace",
-    "sample_policy_candidates", "spike_trace", "synth_trace",
-    "trace_disaggregated_metrics", "trace_schedule",
-    "trace_serving_metrics", "trace_serving_workloads",
+    "diurnal_trace", "evaluate_pool_serving_batch",
+    "evaluate_trace_serving", "evaluate_trace_serving_batch",
+    "poisson_trace", "sample_policy_candidates", "serving_workloads",
+    "spike_trace", "synth_trace", "trace_disaggregated_metrics",
+    "trace_schedule", "trace_serving_metrics",
 ]

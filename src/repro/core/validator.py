@@ -15,7 +15,9 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import components as C
+from repro.core.compiler import strategy_memory_need
 from repro.core.design_space import DesignBatch, WSCDesign
+from repro.core.evaluator import wafers_for_budget
 from repro.core.yield_model import (YIELD_TARGET, min_spares_for_target,
                                     min_spares_for_target_batch)
 
@@ -201,8 +203,6 @@ def validate_joint_batch(points, wl, peak_power_w: float = C.WAFER_POWER_W,
         return []
     import numpy as _np
 
-    from repro.core.compiler import strategy_memory_need
-
     arch = validate_batch([p.design for p in points],
                           peak_power_w=peak_power_w)
 
@@ -221,7 +221,6 @@ def validate_joint_batch(points, wl, peak_power_w: float = C.WAFER_POWER_W,
     resolved = [ar.design if ar.ok else p.design
                 for p, ar in zip(points, arch)]
     if n_wafers is None:
-        from repro.core.evaluator import wafers_for_budget
         nw = _np.array([wafers_for_budget(d, wl) for d in resolved],
                        _np.int64)
     else:

@@ -403,6 +403,7 @@ class RequestMix:
         single tenant — the degenerate case `trace_schedule` reduces to
         `continuous_batch_schedule` on. Lazy import: traces layers on top
         of this module."""
+        # traces imports this module
         from repro.core.traces import DEFAULT_TENANT, RequestTrace
         return RequestTrace.from_mix(
             self, DEFAULT_TENANT if tenant is None else tenant)

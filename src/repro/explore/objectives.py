@@ -4,18 +4,15 @@ A campaign's objective pair and constraint set are *data* — `ObjectiveSpec`
 (metric name, direction, GP/HV-space transform) and `ConstraintSpec`
 (metric, op, bound) serialize with the rest of a `CampaignSpec` — and the
 `Objective` classes here are the batch-aware adapters that turn those specs
-into the callable the exploration loop evaluates. They subsume the old
-free-function objective builders (`evaluator.batched_objectives`,
-`serving.serving_objectives`, `GNNCalibrator.objectives()`), which are now
-thin constructors delegating here.
+into the callable the exploration loop evaluates; `repro.core` builds none
+of them (it sits below this package).
 
 The exploration loop (repro.explore.runner) operates on the `Objective`
-protocol only: `eval_many(designs) -> [(y0, y1), ...]`. Legacy callables —
+protocol only: `eval_many(designs) -> [(y0, y1), ...]`. Plain callables —
 scalar ``f(design) -> (t, p)`` functions and ``.batched``-marked batch
-functions — are coerced at the boundary by `as_objective`; the attribute
-sniffing that used to live in `mfmobo._eval_many` is retired to that single
-compat shim. Every `Objective` still *exposes* ``batched = True`` so older
-external sniffers keep working.
+functions — are coerced at the boundary by `as_objective`, the one place
+that attribute sniff lives. Every `Objective` still *exposes*
+``batched = True`` so older external sniffers keep working.
 
 Constraint semantics: a candidate whose metrics violate any constraint (or
 whose evaluation is infeasible) maps to the penalty point — by default
@@ -36,6 +33,13 @@ from repro import telemetry as tm
 from repro.core import components as C
 from repro.core.design_space import WSCDesign
 from repro.core.fidelity import FidelityBackend, get_backend
+from repro.core.heterogeneity import evaluate_hetero_serving_batch
+from repro.core.traces import (
+    RequestTrace,
+    TenantClass,
+    evaluate_pool_serving_batch,
+    evaluate_trace_serving_batch,
+)
 from repro.core.workload import LLMWorkload
 
 DIRECTIONS = ("max", "min")
@@ -233,9 +237,9 @@ class Objective:
 
 class EvaluatorObjective(Objective):
     """Train / inference objective: registry-batched `evaluate_design_batch`
-    over the candidate set. Subsumes `evaluator.batched_objectives` and —
-    with `params_fn` reading live parameters at call time —
-    `GNNCalibrator.objectives()`."""
+    over the candidate set. With `params_fn` it reads live GNN parameters
+    at call time (``params_fn=lambda: calibrator.params`` follows
+    `GNNCalibrator`'s fine-tuning)."""
 
     def __init__(self, wl: LLMWorkload,
                  fidelity: Union[str, FidelityBackend] = "analytical",
@@ -320,10 +324,13 @@ class EvaluatorObjective(Objective):
 
 
 class ServingObjective(Objective):
-    """Serving objective: request-level continuous-batching metrics (TTFT /
-    TPOT / SLO goodput, DESIGN.md §8) through `evaluate_serving_batch`.
-    Subsumes `serving.serving_objectives`; SLO constraints (`ttft`, `tpot`,
-    `slo_attainment`) compose naturally."""
+    """Serving objective on one arrival batch: request-level continuous
+    batching metrics (TTFT / TPOT / SLO goodput, DESIGN.md §8). The mix is
+    scored as the degenerate trace — every request at step 0 under one
+    tenant bound by `slo`, "fifo" — through
+    `traces.evaluate_pool_serving_batch`, which, unlike the trace
+    scenario, takes a table of layer kinds. SLO constraints (`ttft`,
+    `tpot`, `slo_attainment`) compose naturally."""
 
     def __init__(self, wl: LLMWorkload, mix, slo, *, slots: int = 8,
                  fidelity: Union[str, FidelityBackend] = "analytical",
@@ -338,6 +345,7 @@ class ServingObjective(Objective):
         self.wl = wl
         self.mix = mix
         self.slo = slo
+        self.trace = RequestTrace.from_mix(mix, TenantClass.from_slo(slo))
         self.slots = slots
         self.backend = get_backend(fidelity)
         self.fidelity = self.backend.name
@@ -349,9 +357,8 @@ class ServingObjective(Objective):
         return self._params_fn() if self._params_fn else self._gnn_params
 
     def metrics(self, designs: List[WSCDesign]) -> List[Dict[str, float]]:
-        from repro.core.serving import evaluate_serving_batch
-        rs = evaluate_serving_batch(
-            designs, self.wl, self.mix, self.slo, slots=self.slots,
+        rs = evaluate_pool_serving_batch(
+            designs, self.wl, self.trace, slots=self.slots, policy="fifo",
             fidelity=self.backend, gnn_params=self.gnn_params(),
             max_strategies=self.max_strategies)
         return [{
@@ -370,8 +377,9 @@ class ServingObjective(Objective):
 class HeteroServingObjective(Objective):
     """Heterogeneous (prefill/decode disaggregation) serving objective: each
     candidate design is scored as both stages of a split at the configured
-    granularity / prefill ratio, under the coupled request model
-    (`heterogeneity.evaluate_hetero_serving`)."""
+    granularity / prefill ratio, under the coupled request model, one
+    batched call a batch (`heterogeneity.evaluate_hetero_serving_batch`).
+    Its stages are scored at `heterogeneity.STAGE_MAX_STRATEGIES`."""
 
     def __init__(self, wl: LLMWorkload, mix, slo, *, granularity: str,
                  prefill_ratio: float = 0.5, slots: int = 8,
@@ -399,26 +407,21 @@ class HeteroServingObjective(Objective):
         return self._params_fn() if self._params_fn else self._gnn_params
 
     def metrics(self, designs: List[WSCDesign]) -> List[Dict[str, float]]:
-        from repro.core.heterogeneity import evaluate_hetero_serving
-        out = []
-        for d in designs:
-            r = evaluate_hetero_serving(
-                d, d, self.wl, self.granularity, self.prefill_ratio,
-                self.mix, self.slo, slots=self.slots,
-                n_wafers=self.n_wafers, fidelity=self.backend,
-                gnn_params=self.gnn_params())
-            out.append({
-                "goodput": r.goodput_tok_s,
-                "throughput": r.throughput_tok_s,
-                "ttft": r.ttft_s, "tpot": r.tpot_s,
-                "slo_attainment": r.slo_attainment,
-                "power": r.power_w,
-                "power_per_wafer": r.power_w / max(self.n_wafers, 1),
-                "n_wafers": float(self.n_wafers),
-                "kv_transfer_s": r.kv_transfer_s,
-                "feasible": r.feasible and np.isfinite(r.power_w),
-            })
-        return out
+        rs = evaluate_hetero_serving_batch(
+            designs, designs, self.wl, self.granularity, self.prefill_ratio,
+            self.mix, self.slo, slots=self.slots, n_wafers=self.n_wafers,
+            fidelity=self.backend, gnn_params=self.gnn_params())
+        return [{
+            "goodput": r.goodput_tok_s,
+            "throughput": r.throughput_tok_s,
+            "ttft": r.ttft_s, "tpot": r.tpot_s,
+            "slo_attainment": r.slo_attainment,
+            "power": r.power_w,
+            "power_per_wafer": r.power_w / max(self.n_wafers, 1),
+            "n_wafers": float(self.n_wafers),
+            "kv_transfer_s": r.kv_transfer_s,
+            "feasible": r.feasible and np.isfinite(r.power_w),
+        } for r in rs]
 
 
 class TraceServingObjective(Objective):
@@ -462,7 +465,6 @@ class TraceServingObjective(Objective):
         return self._params_fn() if self._params_fn else self._gnn_params
 
     def metrics(self, designs: List[WSCDesign]) -> List[Dict[str, float]]:
-        from repro.core.traces import evaluate_trace_serving_batch
         rs = evaluate_trace_serving_batch(
             designs, self.wl, self.trace, slots=self.slots,
             policy=self.policy, window_steps=self.window_steps,

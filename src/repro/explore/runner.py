@@ -15,10 +15,11 @@ boundary resumes bit-identically: the continuation consumes the identical
 rng stream and refits the identical models, so a resumed trace equals the
 uninterrupted one at a fixed seed (pinned by tests/test_campaign.py).
 
-`repro.core.mfmobo.run_mfmobo/run_mobo/run_random` are thin wrappers over
-this loop (same signatures, same rng-consumption order, hence bit-identical
-traces vs their pre-refactor selves). Objectives are `Objective` protocol
-instances (repro.explore.objectives); legacy callables are coerced at entry.
+Run it directly as ``ExplorationLoop(LoopConfig(strategy=..., ...), f0,
+f1=f1, on_handover=...).run()`` (a `Trace`), or through a `Campaign` when
+the run should be serializable and resumable. Objectives are `Objective`
+protocol instances (repro.explore.objectives); plain callables are coerced
+at entry (`as_objective`).
 
 Per-evaluation bookkeeping: every batch evaluated at a fidelity stage
 ("f0"/"f1") runs under `attribute_cache_traffic`, so the trace records

@@ -19,6 +19,7 @@ from repro.explore import (
     CampaignSpec,
     ConstraintSpec,
     EvaluatorObjective,
+    ExplorationLoop,
     FidelitySchedule,
     LoopConfig,
     ObjectiveSpec,
@@ -321,25 +322,23 @@ def synthetic_fns():
 def test_budget_never_overshoots_with_q():
     """Regression (ISSUE 5): with q > 1 and budgets not divisible by q,
     the final batch is clamped so traces honor N0/N1 exactly."""
-    from repro.core.mfmobo import run_mfmobo, run_mobo
-
     f = synthetic_fns()
-    tr = run_mobo(f, d0=3, N=10, q=4, n_candidates=24, seed=0)
+    tr = ExplorationLoop(LoopConfig(strategy="mobo", N0=10, d0=3, q=4,
+                                    n_candidates=24, seed=0), f).run()
     assert len(tr.ys) == 10 and tr.n_evals == 10
-    tr = run_mfmobo(f, f, d0=2, d1=2, k=2, N0=5, N1=6, q=4,
-                    n_candidates=24, seed=0)
+    tr = ExplorationLoop(LoopConfig(strategy="mfmobo", N0=5, N1=6, d0=2,
+                                    d1=2, k=2, q=4, n_candidates=24,
+                                    seed=0), f, f1=f).run()
     assert len(tr.ys) == 5          # exactly the f0 budget
     assert tr.n_evals == 11         # N0 + N1, not a q-multiple overshoot
 
 
 def test_priors_exceeding_budget_raise():
-    from repro.core.mfmobo import run_mfmobo, run_mobo
-
     f = synthetic_fns()
     with pytest.raises(ValueError, match="priors"):
-        run_mobo(f, d0=8, N=4)
+        ExplorationLoop(LoopConfig(strategy="mobo", N0=4, d0=8), f)
     with pytest.raises(ValueError, match="priors"):
-        run_mfmobo(f, f, d0=9, N0=4)
+        ExplorationLoop(LoopConfig(strategy="mfmobo", N0=4, d0=9), f, f1=f)
     with pytest.raises(ValueError, match="unknown strategy"):
         LoopConfig(strategy="anneal").validate()
 

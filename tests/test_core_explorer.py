@@ -85,8 +85,8 @@ def test_gp_condition_on_fantasy_update():
 def test_mobo_batched_proposals_with_batch_eval_fn():
     """q>1 proposals + a batch-aware objective: the loop evaluates whole
     batches in one call and still only spends the evaluation budget."""
-    from repro.core.mfmobo import run_mobo
     from repro.core.design_space import encode_batch
+    from repro.explore.runner import ExplorationLoop, LoopConfig
 
     calls = {"n": 0, "sizes": []}
 
@@ -98,7 +98,8 @@ def test_mobo_batched_proposals_with_batch_eval_fn():
                  float(5e3 * (0.5 + u[1] ** 2))) for u in U]
     f.batched = True
 
-    tr = run_mobo(f, d0=3, N=9, n_candidates=32, q=3, seed=0)
+    tr = ExplorationLoop(LoopConfig(strategy="mobo", N0=9, d0=3, q=3,
+                                    n_candidates=32, seed=0), f).run()
     assert len(tr.ys) == 9
     assert tr.hv[-1] >= tr.hv[0]
     assert max(calls["sizes"]) == 3             # proposals arrive as batches
@@ -108,8 +109,8 @@ def test_mobo_batched_proposals_with_batch_eval_fn():
 def test_mfmobo_loop_improves_hypervolume():
     """MFMOBO on a cheap synthetic 2-objective problem over the WSC space:
     maximize (throughput-proxy, -power-proxy) from the encoded vector."""
-    from repro.core.mfmobo import run_mfmobo, run_random
     from repro.core.design_space import encode
+    from repro.explore.runner import ExplorationLoop, LoopConfig
 
     def f_hi(design):
         u = encode(design)
@@ -121,9 +122,12 @@ def test_mfmobo_loop_improves_hypervolume():
         t, p = f_hi(design)
         return t * 1.1, p * 0.95               # biased-but-correlated
 
-    tr = run_mfmobo(f_hi, f_lo, d0=2, d1=2, k=2, N0=7, N1=7,
-                    n_candidates=48, seed=0)
+    tr = ExplorationLoop(LoopConfig(strategy="mfmobo", N0=7, N1=7, d0=2,
+                                    d1=2, k=2, n_candidates=48, seed=0),
+                         f_hi, f1=f_lo).run()
     assert len(tr.hv) >= 5
     assert tr.hv[-1] >= tr.hv[0]               # monotone non-decreasing
-    rnd = run_random(f_hi, N=7, seed=0)
+    # q=N: the whole sampled pool in one evaluation call
+    rnd = ExplorationLoop(LoopConfig(strategy="random", N0=7, q=7, seed=0),
+                          f_hi).run()
     assert tr.hv[-1] >= 0.8 * rnd.hv[-1]       # sanity: not catastrophically worse

@@ -358,9 +358,13 @@ def _refusal_cases():
     from repro.core import heterogeneity, traces
     from repro.core.compiler import compile_chunk
     from repro.core.evaluator import evaluate_design_batch
+    from repro.core.serving import ServingSLO
+    from repro.core.workload import RequestMix
     from repro.dist.oracle import model_config_for_workload
+    from repro.explore.objectives import TraceServingObjective
     d = _one_design()
     tr = traces.synth_trace("poisson", 4, seed=0)
+    mix = RequestMix.uniform(4, 256, 8)
     fake_gnn = {"layers": []}
     return {
         "gnn_features": lambda: evaluate_design_batch(
@@ -369,8 +373,14 @@ def _refusal_cases():
                                                    fidelity="sim"),
         "trace_serving": lambda: traces.evaluate_trace_serving_batch(
             [d], DEEPSEEK_V3, tr),
-        "trace_workloads": lambda: traces.trace_serving_workloads(
-            DEEPSEEK_V3, tr, 4),
+        # the trace scenario sizes its stage workloads from one uniform
+        # layer (the shared `serving_workloads` itself also serves the
+        # request-mix scenario, which takes kind tables)
+        "trace_workloads": lambda: TraceServingObjective(
+            DEEPSEEK_V3, tr, slots=4).metrics([d]),
+        "hetero_serving": lambda: heterogeneity
+        .evaluate_hetero_serving_batch([d], [d], DEEPSEEK_V3, "reticle",
+                                       0.5, mix, ServingSLO(5.0, 0.05)),
         "hetero_trace": lambda: heterogeneity
         .evaluate_hetero_trace_serving_batch([d], [d], DEEPSEEK_V3,
                                              "reticle", 0.5, tr),
@@ -384,8 +394,8 @@ def _refusal_cases():
 
 
 READERS = ("gnn_features", "sim_lanes", "trace_serving", "trace_workloads",
-           "hetero_trace", "hetero", "compile_chunk", "layer_ops_batch",
-           "runtime_oracle")
+           "hetero_serving", "hetero_trace", "hetero", "compile_chunk",
+           "layer_ops_batch", "runtime_oracle")
 
 
 @pytest.mark.parametrize("reader", READERS)

@@ -194,13 +194,14 @@ def test_pareto_neighborhood_prefers_front():
 
 def test_calibrator_on_handover_finetunes_params():
     from repro.core.calibration import GNNCalibrator
+    from repro.explore.objectives import EvaluatorObjective
     wl = GPT_BENCHMARKS[0]
     designs = [validate(WSCDesign()).design,
                validate(WSCDesign(mac_num=256)).design]
     ys = [(100.0, 5000.0), (120.0, 6000.0)]
     p0 = init_gnn(jax.random.PRNGKey(1))
     cal = GNNCalibrator(p0, wl, n_designs=1, epochs=2, patience=None)
-    f0 = cal.objectives()
+    f0 = EvaluatorObjective(wl, "gnn", params_fn=lambda: cal.params)
     assert getattr(f0, "batched", False) and f0.fidelity == "gnn"
     cal.on_handover(designs, ys)
     assert len(cal.records) == 1

@@ -284,15 +284,16 @@ def test_async_resume_mid_flight_matches_uninterrupted(tmp_path):
 
 def test_sync_mode_untouched_by_async_fields():
     # async_depth=0 must consume the identical rng stream as the loop did
-    # before async mode existed: pin against the thin legacy wrapper
-    from repro.core.mfmobo import run_mfmobo
+    # before async mode existed: pin against a bare synchronous loop
+    from repro.explore.runner import ExplorationLoop, LoopConfig
 
     def f(d):
         return (float(d.mac_num) / 2.0, 1500.0)
 
     spec = quick_spec(async_depth=0)
-    tr = run_mfmobo(f, f, d0=2, d1=2, k=2, N0=5, N1=6, q=2,
-                    n_candidates=16, seed=7)
+    tr = ExplorationLoop(LoopConfig(strategy="mfmobo", N0=5, N1=6, d0=2,
+                                    d1=2, k=2, q=2, n_candidates=16,
+                                    seed=7), f, f1=f).run()
     res = Campaign(spec).run()      # different objective, same rng stream
     assert len(res.trace.ys) == len(tr.ys)
 

@@ -5,6 +5,7 @@ package that was never committed (repro.dist originally shipped that way):
 a missing module now fails here instead of crashing collection elsewhere
 or lying dormant until launch time.
 """
+import ast
 import importlib
 import pathlib
 
@@ -42,3 +43,30 @@ def test_module_list_nonempty():
 @pytest.mark.parametrize("mod", MODULES)
 def test_module_imports(mod):
     importlib.import_module(mod)
+
+
+def _imported_modules(py: pathlib.Path, package: str):
+    """(line, dotted name) of everything `py` imports, at any level of
+    the file, relative imports resolved against `package`."""
+    for node in ast.walk(ast.parse(py.read_text())):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                yield node.lineno, a.name
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:
+                parts = package.split(".")[:len(package.split("."))
+                                           - node.level + 1]
+                base = ".".join(parts + ([base] if base else []))
+            for a in node.names:
+                yield node.lineno, f"{base}.{a.name}"
+
+
+def test_core_never_imports_explore():
+    """`repro.core` sits below `repro.explore`: no module under core
+    imports it, at module level or inside a function."""
+    bad = [f"{py.name}:{line} {name}"
+           for py in sorted((_ROOT / "core").rglob("*.py"))
+           for line, name in _imported_modules(py, "repro.core")
+           if name == "repro.explore" or name.startswith("repro.explore.")]
+    assert not bad, bad

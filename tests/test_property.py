@@ -245,16 +245,16 @@ def test_gnn_forward_batch_matches_scalar_forward(seed, n_graphs):
 @settings(**SETTINGS)
 def test_qehvi_q1_matches_scalar_ehvi_argmax(seed):
     """Greedy q-EHVI with q=1 is exactly the scalar EHVI acquisition."""
-    from repro.core.mfmobo import (_acquire_batch, _fit_models, _hv_ref,
-                                   _obj_space)
+    from repro.core.mfmobo import (_acquire_batch, _fit_models, hv_ref,
+                                   obj_space)
 
     rng = np.random.default_rng(seed)
     X = rng.random((12, 5))
     Y = np.stack([1e5 * (1 + X[:, 1] + 0.3 * rng.random(12)),
                   5e3 * (0.5 + X[:, 3])], 1)
     models = _fit_models(X, Y)
-    ev = _obj_space([tuple(r) for r in Y])
-    ref = _hv_ref(15000.0)
+    ev = obj_space([tuple(r) for r in Y])
+    ref = hv_ref(15000.0)
     cand = rng.random((32, 5))
     # scalar reference: argmax of the plain EHVI scores
     from repro.core.pareto import pareto_front

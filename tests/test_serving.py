@@ -1,4 +1,5 @@
-"""Request-level serving model (repro.core.serving) + ISSUE 4 bugfix
+"""Request-level serving on one arrival batch (repro.core.serving: the
+request mix scored as the degenerate trace of repro.core.traces) + bugfix
 regressions: per-request decode temperature, prefill KV length under
 sharding, wafer-granularity area accounting, DRAM-energy consistency."""
 import dataclasses
@@ -9,17 +10,21 @@ import pytest
 from repro.core.design_space import DesignBatch, WSCDesign
 from repro.core.chunk_eval import evaluate_step_batch
 from repro.core.heterogeneity import (
-    evaluate_hetero_serving,
+    evaluate_hetero_serving_batch,
     wafer_split,
 )
 from repro.core.serving import (
     ServingSLO,
     continuous_batch_schedule,
     disaggregated_metrics,
-    evaluate_serving,
-    evaluate_serving_batch,
-    serving_metrics,
-    serving_objectives,
+)
+from repro.core.traces import (
+    RequestTrace,
+    TenantClass,
+    evaluate_pool_serving_batch,
+    evaluate_trace_serving,
+    trace_schedule,
+    trace_serving_metrics,
 )
 from repro.core.validator import validate
 from repro.core.workload import (
@@ -78,35 +83,45 @@ def test_request_mix_validation():
 
 
 # ---------------------------------------------------------------------------
-# wall-clock metrics (synthetic step times)
+# wall-clock metrics (synthetic step times): the mix's degenerate trace
 # ---------------------------------------------------------------------------
 
 
-def _mix_and_sched():
-    mix = RequestMix.uniform(6, prompt_len=100, out_len=5)
-    return mix, continuous_batch_schedule(mix, slots=3)
+def _mix_trace(mix, slo):
+    return RequestTrace.from_mix(mix, TenantClass.from_slo(slo))
+
+
+def _mix_metrics(mix, slots, slo, t_prefill_ref, prompt_ref, t_decode):
+    """A mix's wall-clock metrics: its degenerate trace under fifo."""
+    t = _mix_trace(mix, slo)
+    return trace_serving_metrics(trace_schedule(t, slots, "fifo"), t,
+                                 t_prefill_ref, prompt_ref, t_decode)
+
+
+def _mix():
+    return RequestMix.uniform(6, prompt_len=100, out_len=5)
 
 
 def test_slo_non_binding_goodput_equals_throughput():
-    mix, sched = _mix_and_sched()
-    m = serving_metrics(sched, mix, ServingSLO(1e9, 1e9),
+    mix = _mix()
+    m = _mix_metrics(mix, 3, ServingSLO(1e9, 1e9),
                         np.array([0.1]), 100, np.array([0.01]))
     assert m["slo_attainment"][0] == 1.0
     assert m["goodput"][0] == pytest.approx(m["throughput"][0])
 
 
 def test_slo_binding_zero_goodput():
-    mix, sched = _mix_and_sched()
-    m = serving_metrics(sched, mix, ServingSLO(1e-6, 1e-6),
+    mix = _mix()
+    m = _mix_metrics(mix, 3, ServingSLO(1e-6, 1e-6),
                         np.array([0.1]), 100, np.array([0.01]))
     assert m["slo_attainment"][0] == 0.0
     assert m["goodput"][0] == 0.0 and m["throughput"][0] > 0
 
 
 def test_ttft_waves_and_prefill_stall():
-    mix, sched = _mix_and_sched()
+    mix = _mix()
     t_p, t_d = 0.5, 0.01
-    m = serving_metrics(sched, mix, ServingSLO(1e9, 1e9),
+    m = _mix_metrics(mix, 3, ServingSLO(1e9, 1e9),
                         np.array([t_p]), 100, np.array([t_d]))
     ttft, tpot = m["ttft"][0], m["tpot"][0]
     # wave 2 waits for wave 1's decode + all prior prefills
@@ -122,8 +137,7 @@ def test_ttft_waves_and_prefill_stall():
 
 def test_prefill_time_scales_with_prompt_length():
     mix = RequestMix((100, 200), (4, 4))
-    sched = continuous_batch_schedule(mix, slots=2)
-    m = serving_metrics(sched, mix, ServingSLO(1e9, 1e9),
+    m = _mix_metrics(mix, 2, ServingSLO(1e9, 1e9),
                         np.array([1.0]), 100, np.array([0.0]))
     # both admitted at step 0: TTFT = cumulative prefill, second is 1+2
     assert m["ttft"][0][0] == pytest.approx(1.0)
@@ -131,8 +145,8 @@ def test_prefill_time_scales_with_prompt_length():
 
 
 def test_candidate_axis_broadcast():
-    mix, sched = _mix_and_sched()
-    m = serving_metrics(sched, mix, ServingSLO(1e9, 1e9),
+    mix = _mix()
+    m = _mix_metrics(mix, 3, ServingSLO(1e9, 1e9),
                         np.array([0.1, 0.2]), 100, np.array([0.01, 0.02]))
     assert m["ttft"].shape == (2, mix.n_requests)
     assert m["goodput"].shape == (2,)
@@ -151,16 +165,17 @@ def test_evaluate_serving_batch_gpt175b():
     d = validate(STACKED).design
     mix = RequestMix.uniform(8, prompt_len=2048, out_len=32)
     slo = ServingSLO(ttft_s=60.0, tpot_s=1.0)
-    r = evaluate_serving_batch([d], wl, mix, slo, slots=4,
-                               max_strategies=8)[0]
+    trace = _mix_trace(mix, slo)
+    r = evaluate_pool_serving_batch([d], wl, trace, slots=4,
+                                    max_strategies=8)[0]
     assert r.feasible
     assert r.goodput_tok_s <= r.throughput_tok_s + 1e-9
     assert 0.0 < r.ttft_s <= r.ttft_max_s
     assert 0.0 < r.tpot_s <= r.tpot_max_s
     assert np.isfinite(r.power_w) and r.power_w > 0
     assert r.n_decode_steps == continuous_batch_schedule(mix, 4).n_decode_steps
-    # scalar wrapper agrees
-    r2 = evaluate_serving(d, wl, mix, slo, slots=4, max_strategies=8)
+    # the trace scenario's scalar entry agrees
+    r2 = evaluate_trace_serving(d, wl, trace, slots=4, max_strategies=8)
     assert r2.goodput_tok_s == pytest.approx(r.goodput_tok_s)
 
 
@@ -169,14 +184,16 @@ def test_evaluate_serving_unknown_fidelity_raises():
     d = validate(WSCDesign()).design
     mix = RequestMix.uniform(2, 128, 4)
     with pytest.raises(ValueError, match="registered"):
-        evaluate_serving_batch([d], wl, mix, ServingSLO(1, 1),
-                               fidelity="bogus")
+        evaluate_pool_serving_batch([d], wl,
+                                    _mix_trace(mix, ServingSLO(1, 1)),
+                                    fidelity="bogus")
 
 
 def test_serving_objectives_batch_aware():
+    from repro.explore.objectives import ServingObjective
     wl = GPT_BENCHMARKS[0]
     mix = RequestMix.uniform(4, 512, 8)
-    f = serving_objectives(wl, mix, ServingSLO(60.0, 1.0), slots=2)
+    f = ServingObjective(wl, mix, ServingSLO(60.0, 1.0), slots=2)
     assert f.batched and f.fidelity == "analytical"
     ds = [validate(WSCDesign()).design, validate(STACKED).design]
     ys = f(ds)
@@ -184,17 +201,6 @@ def test_serving_objectives_batch_aware():
     assert all(len(y) == 2 and y[1] > 0 for y in ys)
     y0 = f(ds[0])
     assert y0[0] == pytest.approx(ys[0][0])
-
-
-def test_forwarders_agree():
-    from repro.core import evaluator, fidelity
-    wl = GPT_BENCHMARKS[0]
-    d = validate(WSCDesign()).design
-    mix = RequestMix.uniform(3, 256, 4)
-    slo = ServingSLO(30.0, 1.0)
-    a = evaluator.evaluate_serving_batch([d], wl, mix, slo, slots=2)[0]
-    b = fidelity.evaluate_serving_batch([d], wl, mix, slo, slots=2)[0]
-    assert a.goodput_tok_s == pytest.approx(b.goodput_tok_s)
 
 
 # ---------------------------------------------------------------------------
@@ -232,8 +238,8 @@ def test_evaluate_hetero_serving_runs_all_granularities():
     mix = RequestMix.uniform(6, 1024, 16)
     slo = ServingSLO(30.0, 1.0)
     for gran in ("core", "reticle", "wafer"):
-        h = evaluate_hetero_serving(d, d, wl, gran, 0.5, mix, slo,
-                                    slots=4, n_wafers=4)
+        (h,) = evaluate_hetero_serving_batch([d], [d], wl, gran, 0.5, mix,
+                                             slo, slots=4, n_wafers=4)
         assert h.feasible and h.throughput_tok_s > 0
         assert h.goodput_tok_s <= h.throughput_tok_s + 1e-9
         assert h.ttft_s > 0 and h.tpot_s > 0

@@ -291,21 +291,16 @@ def _scalar_fed_disaggregated(d, wl, t, slots, window_steps, prefill_ratio,
     24 strategies: the path disaggregated designs took before their stages
     were batched."""
     from repro.core.evaluator import evaluate_design
-    from repro.core.heterogeneity import _trace_coupled, wafer_split
-    from repro.core.traces import trace_serving_workloads
-    wl_p, wl_d, p_ref = trace_serving_workloads(wl, t, slots)
-    if granularity == "wafer":
-        nw_p, nw_d = wafer_split(n_wafers, prefill_ratio)
-        scale_p = scale_d = 1.0
-    else:
-        nw_p = nw_d = n_wafers
-        scale_p, scale_d = prefill_ratio, 1.0 - prefill_ratio
-    rp = evaluate_design(d, wl_p, "analytical", n_wafers=nw_p,
+    from repro.core.heterogeneity import _trace_coupled, stage_split
+    from repro.core.traces import serving_workloads
+    wl_p, wl_d, p_ref = serving_workloads(wl, t, slots)
+    split = stage_split(granularity, prefill_ratio, n_wafers)
+    rp = evaluate_design(d, wl_p, "analytical", n_wafers=split.nw_p,
                          max_strategies=24)
-    rd = evaluate_design(d, wl_d, "analytical", n_wafers=nw_d,
+    rd = evaluate_design(d, wl_d, "analytical", n_wafers=split.nw_d,
                          max_strategies=24)
-    return _trace_coupled(rp, rd, d, wl, granularity, scale_p, scale_d, t,
-                          slots, window_steps, p_ref)
+    return _trace_coupled(rp, rd, d, wl, granularity, split, t, slots,
+                          window_steps, p_ref)
 
 
 def test_disaggregated_batch_matches_scalar_stages_bitwise():
